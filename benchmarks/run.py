@@ -12,6 +12,8 @@ import json
 import os
 import time
 
+from repro.compile_cache import use_compile_cache
+
 BENCHES = {}
 
 
@@ -41,6 +43,7 @@ def main() -> None:
                     help="full sweeps (quick subsets by default)")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    use_compile_cache()
     _lazy()
 
     results = {}

@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.models import lstm_am
 from repro.serving import (
     AsyncSpartusServer, BatchedSpartusEngine, EngineConfig,
@@ -709,6 +710,7 @@ def main() -> int:
                     help="write the report as JSON (--sweep defaults to "
                          "BENCH_serving.json)")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.sweep:
         if args.check or args.sharded or args.quant:

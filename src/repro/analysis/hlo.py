@@ -38,7 +38,8 @@ COLLECTIVE_TOKENS: Tuple[str, ...] = (
 HOST_TRANSFER_TOKENS: Tuple[str, ...] = (
     "outfeed",
     "infeed",
-    "xla_python_cpu_callback",
+    "xla_python_cpu_callback",      # jax < 0.5 custom-call target
+    "xla_ffi_python_cpu_callback",  # the FFI target jax 0.9 emits
     "host_callback",
     "SendToHost",
     "RecvFromHost",
@@ -79,6 +80,28 @@ def collective_lines(hlo_text: str) -> List[str]:
 def count_collectives(hlo_text: str) -> int:
     """Number of HLO lines mentioning a collective (the PR-5 pin)."""
     return len(collective_lines(hlo_text))
+
+
+# Where in the Python source each op was traced: ``metadata={op_name=...
+# stack_frame_id=N}`` on an instruction, plus the module's source tables
+# (one header line each, entries up to the next blank line).  Two programs
+# traced from different call sites differ only in these.
+_METADATA_RE = re.compile(r",?\s*metadata=\{[^{}]*\}")
+_SOURCE_TABLES = ("FileNames", "FunctionNames", "FileLocations",
+                  "StackFrames")
+
+
+def strip_metadata(hlo_text: str) -> str:
+    """``hlo_text`` without source-location metadata."""
+    out, in_table = [], False
+    for line in hlo_text.splitlines():
+        if line in _SOURCE_TABLES:
+            in_table = True
+        elif in_table and not line.strip():
+            in_table = False
+        elif not in_table:
+            out.append(_METADATA_RE.sub("", line))
+    return "\n".join(out)
 
 
 def host_transfer_lines(hlo_text: str) -> List[str]:

@@ -1,13 +1,14 @@
 """Pallas TPU kernel: fused delta encoding (the Spartus DPE, Fig. 6).
 
-Computes eqs. (4)-(5) in one pass over the state vector: thresholded delta,
-reference-state update, and per-block nonzero counts (the NZV occupancy
-used for capacity selection and balance-ratio statistics).
+Computes eqs. (4)-(5) in one pass over every slot's state vector:
+thresholded delta, reference-state update, and the per-slot count of
+fired entries (the NZV occupancy used for capacity selection and
+sparsity telemetry).
 
-TPU mapping: the state vector is viewed as [R, 128] (lane-aligned); the
-grid walks row-blocks of 8 sublanes, so each step owns one (8, 128) VMEM
-tile — the elementwise threshold/select runs entirely on the VPU.  The
-per-block count is a scalar write to SMEM-resident output.
+TPU mapping: the state is a ``[B, F]`` slab with F padded to whole
+128-lane rows; the grid walks blocks of slot rows (one sublane tile), so
+each step owns a ``[rows, F]`` VMEM block and the threshold/select runs
+entirely on the VPU.  θ is one f32 scalar in SMEM.
 """
 from __future__ import annotations
 
@@ -17,9 +18,20 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-BLOCK_ROWS = 8
+from repro.kernels.lowering import pallas_call
+
 LANES = 128
+SUBLANE_BYTES = 32  # one sublane tile: 8 rows of f32, 16 of bf16
+
+
+def slot_rows(n_slots: int, dtype) -> int:
+    """Slot rows per grid step: one sublane tile when it divides the pool,
+    else the whole pool in one block (a block dim equal to the array dim
+    is always a legal Mosaic tile)."""
+    rows = SUBLANE_BYTES // jnp.dtype(dtype).itemsize
+    return rows if n_slots % rows == 0 else n_slots
 
 
 def _delta_encode_kernel(
@@ -37,55 +49,50 @@ def _delta_encode_kernel(
         qmax = 2.0 ** (act_bits - 1) - 1
         x = jnp.clip(jnp.round(x / scale), -qmax - 1, qmax) * scale
     raw = x - xh
-    fired = jnp.abs(raw) > theta_ref[0]
+    fired = jnp.abs(raw).astype(jnp.float32) > theta_ref[0]
     delta_ref[...] = jnp.where(fired, raw, jnp.zeros_like(raw))
     xh_out_ref[...] = jnp.where(fired, x, xh)
-    nnz_ref[0] = jnp.sum(fired.astype(jnp.int32))
+    nnz_ref[...] = jnp.sum(fired.astype(jnp.int32), axis=1, keepdims=True)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("interpret", "act_bits", "act_frac_bits"))
+@functools.partial(jax.jit, static_argnames=("act_bits", "act_frac_bits"))
 def delta_encode_pallas(
-    x: jax.Array, x_hat: jax.Array, theta: jax.Array, *,
-    interpret: bool = True,
+    x: jax.Array, x_hat: jax.Array, theta, *,
     act_bits: Optional[int] = None, act_frac_bits: int = 8,
 ):
-    """x, x_hat: [F] with F % (8*128) == 0 (callers pad; see ops.py).
+    """x, x_hat: [B, F] (any F; padded to whole lane rows here).
 
-    Returns (delta [F], new_x_hat [F], nnz_per_block [F/1024] int32).
-    With ``act_bits`` the threshold comparison runs on the Qm.n grid
-    (see ops.delta_encode); theta is snapped here, x inside the kernel.
+    Returns (delta [B, F], new_x_hat [B, F], nnz [B] int32).  With
+    ``act_bits`` the threshold comparison runs on the Qm.n grid (see
+    ops.delta_encode); theta is snapped here, x inside the kernel.
     """
-    f = x.shape[0]
-    assert f % (BLOCK_ROWS * LANES) == 0, f"F={f} must be padded to 1024"
-    rows = f // LANES
-    n_blocks = rows // BLOCK_ROWS
-    x2 = x.reshape(rows, LANES)
-    xh2 = x_hat.reshape(rows, LANES)
+    b, f = x.shape
+    pad = (-f) % LANES
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad)))
+        x_hat = jnp.pad(x_hat, ((0, 0), (0, pad)))
+    fp = f + pad
+    rows = slot_rows(b, x.dtype)
+    # θ rounded to the state dtype first, so the f32 comparison in SMEM
+    # decides exactly as a comparison in that dtype would.
     theta_arr = jnp.asarray(theta, x.dtype).reshape(1)
     if act_bits is not None:
         from repro.core.quantization import quantize_act
         theta_arr = quantize_act(theta_arr, act_bits, act_frac_bits)
+    block = pl.BlockSpec((rows, fp), lambda i: (i, 0))
 
-    delta, new_xh, nnz = pl.pallas_call(
+    delta, new_xh, nnz = pallas_call(
         functools.partial(_delta_encode_kernel, act_bits=act_bits,
                           act_frac_bits=act_frac_bits),
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b: (0,)),                     # theta
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda b: (b, 0)),    # x
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda b: (b, 0)),    # x_hat
-        ],
-        out_specs=[
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda b: (b, 0)),
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda b: (b, 0)),
-            pl.BlockSpec((1,), lambda b: (b,)),
-        ],
+        grid=(b // rows,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), block, block],
+        out_specs=[block, block, pl.BlockSpec((rows, 1), lambda i: (i, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((rows, LANES), x.dtype),
-            jax.ShapeDtypeStruct((rows, LANES), x.dtype),
-            jax.ShapeDtypeStruct((n_blocks,), jnp.int32),
+            jax.ShapeDtypeStruct((b, fp), x.dtype),
+            jax.ShapeDtypeStruct((b, fp), x.dtype),
+            jax.ShapeDtypeStruct((b, 1), jnp.int32),
         ],
-        interpret=interpret,
-    )(theta_arr, x2, xh2)
-    return delta.reshape(f), new_xh.reshape(f), nnz
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+    )(theta_arr.astype(jnp.float32), x, x_hat)
+    return delta[:, :f], new_xh[:, :f], nnz[:, 0]

@@ -1,9 +1,10 @@
 """jit'd public wrappers for the Pallas kernels + XLA fallback paths.
 
-``use_pallas`` selects the Pallas implementation (interpret=True on CPU,
-compiled on TPU); the default XLA path implements identical math with
-gather/einsum and is what the dry-run lowers (TPU Pallas cannot compile on
-the CPU backend — DESIGN.md §6).
+``use_pallas`` selects the Pallas implementation (compiled by Mosaic on a
+TPU, interpreted only where the program is lowered for the CPU — see
+kernels/lowering.py); the default XLA path implements identical math with
+gather/einsum.  The Pallas kernels are batched over a leading slot
+dimension; the single-session wrappers call them with one slot.
 
 Also hosts ``select_active_columns`` — the fixed-capacity NZI list builder
 (the static-shape translation of the Spartus DPE's NZV/NZI streams).
@@ -19,28 +20,15 @@ import jax.numpy as jnp
 from repro.analysis.contracts import hotpath_contract
 from repro.kernels.delta_encode import delta_encode_pallas
 from repro.kernels.lstm_pointwise import lstm_pointwise_pallas
-from repro.kernels.stsp_spmv import (
-    stsp_spmv_pallas,
-    stsp_spmv_scatter_batch_pallas,
-)
+from repro.kernels.stsp_spmv import stsp_spmv_scatter_batch_pallas
 from repro.kernels import ref as _ref
 
-PAD_ALIGN = 1024  # delta_encode tile: 8 sublanes x 128 lanes
 
-
-def _pad_to(x: jax.Array, align: int) -> Tuple[jax.Array, int]:
-    f = x.shape[0]
-    pad = (-f) % align
-    if pad:
-        x = jnp.pad(x, (0, pad))
-    return x, f
-
-
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret",
-                                             "act_bits", "act_frac_bits"))
+@functools.partial(jax.jit, static_argnames=("use_pallas", "act_bits",
+                                             "act_frac_bits"))
 def delta_encode(
     x: jax.Array, x_hat: jax.Array, theta,
-    *, use_pallas: bool = False, interpret: bool = True,
+    *, use_pallas: bool = False,
     act_bits: int | None = None, act_frac_bits: int = 8,
 ):
     """Eqs. (4)-(5). x, x_hat: [F] any length (padded internally).
@@ -54,28 +42,19 @@ def delta_encode(
     before."""
     if not use_pallas:
         return _ref.delta_encode_ref(x, x_hat, theta, act_bits, act_frac_bits)
-    xp, f = _pad_to(x, PAD_ALIGN)
-    xhp, _ = _pad_to(x_hat, PAD_ALIGN)
     delta, new_xh, nnz = delta_encode_pallas(
-        xp, xhp, theta, interpret=interpret,
+        x[None], x_hat[None], theta,
         act_bits=act_bits, act_frac_bits=act_frac_bits)
-    return delta[:f], new_xh[:f], jnp.sum(nnz)
+    return delta[0], new_xh[0], nnz[0]
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def lstm_pointwise(
-    dm: jax.Array, c: jax.Array, *, use_pallas: bool = False, interpret: bool = True
-):
+@functools.partial(jax.jit, static_argnames=("use_pallas",))
+def lstm_pointwise(dm: jax.Array, c: jax.Array, *, use_pallas: bool = False):
     """HPE gate math. dm: [4, H], c: [H] -> (h, c')."""
     if not use_pallas:
         return _ref.lstm_pointwise_ref(dm, c)
-    h_dim = c.shape[0]
-    pad = (-h_dim) % 512
-    if pad:
-        dm = jnp.pad(dm, ((0, 0), (0, pad)))
-        c = jnp.pad(c, (0, pad))
-    h, c_new = lstm_pointwise_pallas(dm, c, interpret=interpret)
-    return h[:h_dim], c_new[:h_dim]
+    h, c_new = lstm_pointwise_pallas(dm[None], c[None])
+    return h[0], c_new[0]
 
 
 @functools.partial(jax.jit, static_argnames=("capacity",))
@@ -130,7 +109,7 @@ def stsp_spmv_xla(
     return _ref.stsp_spmv_scatter_ref(val, lidx, idx, ds_vals, s)
 
 
-@functools.partial(jax.jit, static_argnames=("s", "use_pallas", "interpret"))
+@functools.partial(jax.jit, static_argnames=("s", "use_pallas"))
 def stsp_spmv(
     val: jax.Array,
     lidx: jax.Array,
@@ -139,7 +118,6 @@ def stsp_spmv(
     *,
     s: int,
     use_pallas: bool = False,
-    interpret: bool = True,
     scale: jax.Array | None = None,
 ) -> jax.Array:
     """y [H] = sum_k ds_vals[k] * W_cbcsc[:, idx[k]]  (fp32).
@@ -151,7 +129,8 @@ def stsp_spmv(
     if not use_pallas:
         y = stsp_spmv_xla(val, lidx, idx, ds_vals, s)
     else:
-        y = stsp_spmv_pallas(val, lidx, idx, ds_vals, s=s, interpret=interpret)
+        y = stsp_spmv_scatter_batch_pallas(val, lidx, idx[None], ds_vals[None],
+                                           s=s)[0]
     if scale is not None:
         y = y * scale
     return y
@@ -160,25 +139,27 @@ def stsp_spmv(
 # -- batched (slot-dimension) entry points ---------------------------------
 #
 # The serving scheduler advances a whole pool of independent streaming
-# sessions per frame (serving/batched_engine.py).  These wrappers vmap the
-# scalar-session kernels over a leading slot dimension B so one jitted call
-# covers the entire pool; weights broadcast (in_axes=None), per-slot state
-# maps.  Numerics per row are identical to the unbatched calls (vmap only
-# changes the iteration structure), which is what makes the batched engine
+# sessions per frame (serving/batched_engine.py).  One jitted call covers
+# the entire pool: the XLA routes vmap the single-session math over a
+# leading slot dimension B (weights broadcast, per-slot state maps), and
+# the Pallas kernels carry B in their grid.  Numerics per row are identical
+# to the unbatched calls, which is what makes the batched engine
 # bit-comparable to `SpartusEngine`.
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret",
-                                             "act_bits", "act_frac_bits"))
+@functools.partial(jax.jit, static_argnames=("use_pallas", "act_bits",
+                                             "act_frac_bits"))
 def delta_encode_batch(
     x: jax.Array, x_hat: jax.Array, theta,
-    *, use_pallas: bool = False, interpret: bool = True,
+    *, use_pallas: bool = False,
     act_bits: int | None = None, act_frac_bits: int = 8,
 ):
     """Batched eqs. (4)-(5).  x, x_hat: [B, F] -> (delta [B, F],
     new_x_hat [B, F], nnz [B] int32).  ``act_bits`` as in delta_encode."""
-    fn = functools.partial(delta_encode, use_pallas=use_pallas,
-                           interpret=interpret, act_bits=act_bits,
+    if use_pallas:
+        return delta_encode_pallas(x, x_hat, theta, act_bits=act_bits,
+                                   act_frac_bits=act_frac_bits)
+    fn = functools.partial(_ref.delta_encode_ref, act_bits=act_bits,
                            act_frac_bits=act_frac_bits)
     return jax.vmap(fn, in_axes=(0, 0, None))(x, x_hat, theta)
 
@@ -206,7 +187,7 @@ def spmv_use_dense_gather(s: int, gamma: float) -> bool:
 
 
 @hotpath_contract("stsp_spmv_batch")
-@functools.partial(jax.jit, static_argnames=("s", "use_pallas", "interpret"))
+@functools.partial(jax.jit, static_argnames=("s", "use_pallas"))
 def stsp_spmv_batch(
     val: jax.Array,
     lidx: jax.Array,
@@ -215,7 +196,6 @@ def stsp_spmv_batch(
     *,
     s: int,
     use_pallas: bool = False,
-    interpret: bool = True,
     w_dense: jax.Array | None = None,
     scale: jax.Array | None = None,
 ) -> jax.Array:
@@ -239,25 +219,23 @@ def stsp_spmv_batch(
     if w_dense is not None:
         y = delta_spmv_dense_gather_batch(w_dense, idx, ds_vals)
     elif use_pallas:
-        y = stsp_spmv_scatter_batch_pallas(val, lidx, idx, ds_vals, s=s,
-                                           interpret=interpret)
+        y = stsp_spmv_scatter_batch_pallas(val, lidx, idx, ds_vals, s=s)
     else:
-        fn = functools.partial(stsp_spmv, s=s, use_pallas=False,
-                               interpret=interpret)
+        fn = functools.partial(stsp_spmv, s=s, use_pallas=False)
         y = jax.vmap(fn, in_axes=(None, None, 0, 0))(val, lidx, idx, ds_vals)
     if scale is not None:
         y = y * scale
     return y
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
+@functools.partial(jax.jit, static_argnames=("use_pallas",))
 def lstm_pointwise_batch(
-    dm: jax.Array, c: jax.Array, *, use_pallas: bool = False, interpret: bool = True
+    dm: jax.Array, c: jax.Array, *, use_pallas: bool = False
 ):
     """Batched HPE gate math.  dm: [B, 4, H], c: [B, H] -> (h, c') [B, H]."""
-    fn = functools.partial(lstm_pointwise, use_pallas=use_pallas,
-                           interpret=interpret)
-    return jax.vmap(fn)(dm, c)
+    if use_pallas:
+        return lstm_pointwise_pallas(dm, c)
+    return jax.vmap(_ref.lstm_pointwise_ref)(dm, c)
 
 
 @hotpath_contract("gather_frames", op_budget={"gather": 1})

@@ -1,9 +1,9 @@
 """Pallas TPU kernel: spatio-temporal sparse matrix-vector product — the
 heart of the Spartus accelerator (Fig. 2/4/9), adapted for TPU.
 
-Semantics (one DeltaLSTM/DeltaLinear step):
+Semantics (one DeltaLSTM/DeltaLinear step, for every slot b of a pool):
 
-    y[H] = sum_{k < K} ds_vals[k] * W[:, idx[k]]
+    y[b, H] = sum_{k < K} ds_vals[b, k] * W[:, idx[b, k]]
 
 where W is stored in CBCSC (core/cbcsc.py): ``val/lidx [Q, M, BLEN]``,
 row r = lidx*M + pe.  Only the K *active* columns (nonzero deltas) are
@@ -11,27 +11,26 @@ touched — temporal sparsity — and only BLEN nonzeros per subcolumn are
 stored/fetched — spatial sparsity.
 
 TPU adaptation of the FPGA dataflow (DESIGN.md §2):
-  * NZI list -> scalar-prefetched index vector: the grid's DMA engine
-    fetches exactly the CBCSC slabs of active columns from HBM
-    (``index_map`` reads ``idx_ref[k]``) — this is the "CTRL generates
-    physical WMEM addresses from NZIs" step of Sec. IV-A;
-  * per-PE LUTRAM scatter -> S-wide one-hot contraction in VMEM: each PE's
-    BLEN (value, lidx) pairs expand to its S-length subcolumn on the VPU;
-    with S = 8..32 this costs S*(1-gamma) multiplies per dense-equivalent
-    element (< 1 at the paper's gamma) and stays sublane-aligned;
-  * MAC-array partial sums -> an [S, M] fp32 VMEM accumulator, revisited
-    across the K grid steps ("arbitrary" dimension semantics) and written
-    once at k = K-1.
+  * NZI list -> scalar-prefetched ``[B, K]`` index table in SMEM: the
+    grid's DMA engine fetches exactly the CBCSC slabs of active columns
+    from HBM (``index_map`` reads ``idx_ref[b, k]``) — the "CTRL
+    generates physical WMEM addresses from NZIs" step of Sec. IV-A.  The
+    matching NZV values ride along as a second SMEM table, and a pool
+    whose tables outgrow SMEM is split into slot groups;
+  * per-PE LUTRAM write -> BLEN unrolled selects into an ``[S, M]`` fp32
+    VMEM accumulator: pair j of every PE adds ``ds*val[:, j]`` to the row
+    its ``lidx[:, j]`` names (a compare against a sublane iota), which is
+    the scatter-add Mosaic cannot lower, written as BLEN vector passes;
+  * MAC-array partial sums -> the accumulator block is revisited across
+    the K grid steps of a slot ("arbitrary" dimension semantics) and
+    written back once per slot; slots are independent ("parallel").
 
 Workload balance: CBCSC guarantees every "PE" (lane) sees exactly BLEN
 pairs per active column — the same argument as the paper's Sec. III-C,
 with the memory-interface arbitration replaced by a fixed-shape DMA.
 
-The XLA fallback (ops.stsp_spmv_xla) implements the identical math with
-gather + scatter-add for non-TPU backends; batched serving uses either
-``stsp_spmv_scatter_batch_pallas`` below (one pallas_call over grid (B, K),
-scatter-add into each slot's [S, M] accumulator) or the pack-time dense
-mirror (ops.delta_spmv_dense_gather_batch) when S*(1-gamma) >= 1.
+The XLA routes (ops.stsp_spmv_xla, the pack-time dense mirror
+ops.delta_spmv_dense_topk_batch) implement the identical math.
 """
 from __future__ import annotations
 
@@ -42,144 +41,72 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names it TPUCompilerParams; 0.5+ renamed to CompilerParams.
-_CompilerParams = getattr(pltpu, "TPUCompilerParams", None) or getattr(
-    pltpu, "CompilerParams")
+from repro.kernels.lowering import pallas_call
+
+# Bytes of SMEM one call's scalar-prefetched NZI/NZV tables may take.  All
+# of SMEM is 1 MiB on a v5e, and Mosaic keeps its own scalars there: a
+# 64-slot pool with every column in its NZI list (two [64, 2048] 4-byte
+# tables, 1 MiB) does not fit, so the pool is served in slot groups.
+SMEM_TABLE_BYTES = 256 * 1024
 
 
-def _stsp_kernel(idx_ref, ds_ref, val_ref, lidx_ref, y_ref, *, s: int, k_total: int):
-    k = pl.program_id(0)
-
-    @pl.when(k == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    val = val_ref[0]                       # [M, BLEN] this column's slab
-    lidx = lidx_ref[0]                     # [M, BLEN]
-    ds = ds_ref[0]                         # scalar delta value
-
-    # one-hot expand each PE's subcolumn: [M, BLEN, S] -> contribution [S, M]
-    onehot = (lidx[..., None] == jax.lax.broadcasted_iota(jnp.int32, (1, 1, s), 2))
-    contrib = jnp.einsum(
-        "mb,mbs->sm",
-        val.astype(jnp.float32),
-        onehot.astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    )
-    y_ref[...] += ds.astype(jnp.float32) * contrib
-
-
-def _stsp_scatter_batch_kernel(idx_ref, ds_ref, val_ref, lidx_ref, y_ref, *, s: int):
-    """Batched scatter variant: one (slot, active-column) pair per grid step.
-
-    Instead of expanding each PE's BLEN (value, lidx) pairs into an S-wide
-    one-hot and contracting (O(S) work per nonzero), the accumulator tile is
-    indexed *directly* with ``lidx`` — a scatter-add into the [S, M] VMEM
-    block, O(1) per nonzero.  This is the literal per-PE LUTRAM write of the
-    FPGA MAC array (Sec. IV-A) rather than its one-hot algebraic encoding.
-    """
-    k = pl.program_id(1)
+def _stsp_scatter_batch_kernel(idx_ref, ds_ref, val_ref, lidx_ref, y_ref):
+    """One (slot, active-column) pair per grid step."""
+    b, k = pl.program_id(0), pl.program_id(1)
 
     @pl.when(k == 0)
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    val = val_ref[0]                       # [M, BLEN] this column's slab
-    lidx = lidx_ref[0]                     # [M, BLEN]
-    ds = ds_ref[0, 0]                      # scalar delta value
-    m, blen = val.shape
-    pe = jax.lax.broadcasted_iota(jnp.int32, (m, blen), 0)
-    contrib = (
-        jnp.zeros((s, m), jnp.float32)
-        .at[lidx, pe]
-        .add(ds.astype(jnp.float32) * val.astype(jnp.float32))
-    )
-    y_ref[0] += contrib
+    _, s, m = y_ref.shape
+    val = ds_ref[b, k] * val_ref[0].astype(jnp.float32)   # [M, BLEN]
+    lidx = lidx_ref[0].astype(jnp.int32)                   # [M, BLEN]
+    row = jax.lax.broadcasted_iota(jnp.int32, (s, m), 0)
+    acc = y_ref[0]
+    for j in range(val.shape[1]):
+        acc += jnp.where(row == lidx[:, j][None, :], val[:, j][None, :], 0.0)
+    y_ref[0] = acc
 
 
-@functools.partial(jax.jit, static_argnames=("s", "interpret"))
+@functools.partial(jax.jit, static_argnames=("s",))
 def stsp_spmv_scatter_batch_pallas(
     val: jax.Array,      # [Q, M, BLEN]
-    lidx: jax.Array,     # [Q, M, BLEN] int32
+    lidx: jax.Array,     # [Q, M, BLEN] int32 (or int8-packed)
     idx: jax.Array,      # [B, K] int32 active columns per slot (pad: any id)
     ds_vals: jax.Array,  # [B, K] float (pad: 0.0)
     *,
     s: int,
-    interpret: bool = True,
 ) -> jax.Array:
-    """Batched STSP SpMxSpV: y [B, H] = sum_k ds[b, k] * W[:, idx[b, k]].
+    """Batched STSP SpMxSpV: y [B, S*M] = sum_k ds[b, k] * W[:, idx[b, k]].
 
-    One pallas_call for the whole pool: grid (B, K), slots parallel, the K
-    active columns of each slot revisiting that slot's [S, M] accumulator
-    ("arbitrary" semantics).  The scalar-prefetched [B, K] NZI table steers
-    the DMA so only active columns' CBCSC slabs are fetched from HBM —
-    the weight-fetch economy of the paper's NZI dataflow, kept intact under
-    batching (no one-hot materialisation, no [K, M, BLEN, S] temporaries).
+    One pallas_call per group of slots whose NZI/NZV tables fit
+    SMEM_TABLE_BYTES (the whole pool when they do): grid (slots, K), slots
+    parallel, the K active columns of each slot revisiting that slot's
+    [S, M] accumulator.  Only active columns' CBCSC slabs are fetched from
+    HBM — the weight-fetch economy of the paper's NZI dataflow, kept intact
+    under batching (no one-hot materialisation, no [K, M, BLEN, S]
+    temporaries).
     """
-    q, m, blen = val.shape
+    _, m, blen = val.shape
     b, k_total = idx.shape
-
-    kernel = functools.partial(_stsp_scatter_batch_kernel, s=s)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, k_total),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bb, kk, idx_ref: (bb, kk)),   # ds_vals
-            pl.BlockSpec((1, m, blen),
-                         lambda bb, kk, idx_ref: (idx_ref[bb, kk], 0, 0)),
-            pl.BlockSpec((1, m, blen),
-                         lambda bb, kk, idx_ref: (idx_ref[bb, kk], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, s, m), lambda bb, kk, idx_ref: (bb, 0, 0)),
-    )
-    y = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, s, m), jnp.float32),
-        interpret=interpret,
-        compiler_params=(
-            _CompilerParams(dimension_semantics=("parallel", "arbitrary"))
-            if not interpret
-            else None
-        ),
-    )(idx, ds_vals, val, lidx)
-    return y.reshape(b, s * m)
-
-
-@functools.partial(jax.jit, static_argnames=("s", "interpret"))
-def stsp_spmv_pallas(
-    val: jax.Array,      # [Q, M, BLEN]
-    lidx: jax.Array,     # [Q, M, BLEN] int32
-    idx: jax.Array,      # [K] int32 active columns (pad: any valid id)
-    ds_vals: jax.Array,  # [K] float (pad: 0.0)
-    *,
-    s: int,
-    interpret: bool = True,
-) -> jax.Array:
-    """Returns y [H] = [S*M] in fp32.  K is static (capacity-padded)."""
-    q, m, blen = val.shape
-    k_total = idx.shape[0]
-
-    kernel = functools.partial(_stsp_kernel, s=s, k_total=k_total)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(k_total,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda k, idx_ref: (k,)),               # ds_vals
-            pl.BlockSpec((1, m, blen), lambda k, idx_ref: (idx_ref[k], 0, 0)),
-            pl.BlockSpec((1, m, blen), lambda k, idx_ref: (idx_ref[k], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((s, m), lambda k, idx_ref: (0, 0)),
-    )
-    y = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, m), jnp.float32),
-        interpret=interpret,
-        compiler_params=(
-            _CompilerParams(dimension_semantics=("arbitrary",))
-            if not interpret
-            else None
-        ),
-    )(idx, ds_vals, val, lidx)
-    return y.reshape(s * m)
+    idx, ds_vals = idx.astype(jnp.int32), ds_vals.astype(jnp.float32)
+    group = max(1, SMEM_TABLE_BYTES // (8 * k_total))
+    slab = pl.BlockSpec((1, m, blen),
+                        lambda bb, kk, idx_ref, ds_ref: (idx_ref[bb, kk], 0, 0))
+    ys = []
+    for lo in range(0, b, group):
+        n = min(group, b - lo)
+        ys.append(pallas_call(
+            _stsp_scatter_batch_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(n, k_total),
+                in_specs=[slab, slab],
+                out_specs=pl.BlockSpec(
+                    (1, s, m), lambda bb, kk, idx_ref, ds_ref: (bb, 0, 0)),
+            ),
+            out_shape=jax.ShapeDtypeStruct((n, s, m), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+        )(idx[lo:lo + n], ds_vals[lo:lo + n], val, lidx))
+    return jnp.concatenate(ys).reshape(b, s * m)

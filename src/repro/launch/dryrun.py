@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from repro.configs import REGISTRY, get_arch
 from repro.distributed.sharding import batch_specs, cache_specs, param_specs
-from repro.launch.mesh import compat_make_mesh, make_production_mesh, mesh_context
+from repro.launch.mesh import AUTO, make_production_mesh
 from repro.launch import roofline as RL
 from repro.launch.steps import (
     abstract_cache, abstract_opt_state, abstract_params, make_prefill_step,
@@ -163,12 +163,12 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool,
     from repro import perf
     mo = perf.current().mesh_override
     if mo is not None:
-        mesh = compat_make_mesh(mo[0], mo[1])
+        mesh = jax.make_mesh(mo[0], mo[1], axis_types=(AUTO,) * len(mo[1]))
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     n_dev = mesh.size
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         # 1. production compile: sharding + memory proof
         lowered, params_abs = _lower_cell(cfg, cell, mesh)
         t_lower = time.time() - t0

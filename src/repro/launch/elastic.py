@@ -21,7 +21,7 @@ from typing import Tuple
 import jax
 
 from repro.distributed.sharding import param_specs
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import AUTO
 
 
 def best_mesh_for(n_devices: int):
@@ -31,7 +31,8 @@ def best_mesh_for(n_devices: int):
     while n_devices % model:
         model //= 2
     data = n_devices // model
-    return compat_make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AUTO, AUTO))
 
 
 def reshard(tree, mesh, cfg=None):

@@ -15,33 +15,19 @@ from typing import Tuple
 import jax
 
 
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across the 0.4 -> 0.5+ API change: newer releases take
-    (and want) ``axis_types``; 0.4.x has neither the kwarg nor AxisType."""
-    try:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
-
-
-def mesh_context(mesh):
-    """Context manager activating ``mesh``: jax.set_mesh on 0.5+, the Mesh
-    object itself (a context manager) on 0.4.x."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+AUTO = jax.sharding.AxisType.Auto
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AUTO,) * len(axes))
 
 
 def make_host_mesh(n_data: int = 2, n_model: int = 2):
     """Small mesh over host devices (tests; needs XLA_FLAGS device count)."""
-    return compat_make_mesh((n_data, n_model), ("data", "model"))
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=(AUTO, AUTO))
 
 
 def make_data_mesh(n_data: int = 1):
@@ -60,7 +46,7 @@ def make_data_mesh(n_data: int = 1):
             f"device(s) are visible; set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={n_data} (before "
             f"importing jax) to emulate host devices")
-    return compat_make_mesh((n_data,), ("data",))
+    return jax.make_mesh((n_data,), ("data",), axis_types=(AUTO,))
 
 
 def data_axes(mesh) -> Tuple[str, ...]:

@@ -84,6 +84,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_arch
 from repro.models import api
 from repro.serving.faults import Backoff, ProtocolError, error_payload
@@ -632,6 +633,7 @@ def main():
                          "answers a retriable typed error with a "
                          "retry_after_ms hint")
     args = ap.parse_args()
+    use_compile_cache()
     if args.async_mode:
         if not args.spartus:
             ap.error("--async requires --spartus")

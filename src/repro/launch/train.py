@@ -28,7 +28,6 @@ from repro.core import alpha_at, cbtd_prune_tree
 from repro.data.lm import LMConfig, LMDataset
 from repro.distributed.sharding import param_specs
 from repro.launch.elastic import best_mesh_for
-from repro.launch.mesh import mesh_context
 from repro.launch.steps import make_train_step
 from repro.models import api
 from repro.training.checkpoint import CheckpointManager
@@ -105,7 +104,7 @@ def main():
                        out_shardings=(p_sh, o_sh, None),
                        donate_argnums=(0, 1))
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         t0 = time.time()
         for step in range(step0, args.steps):
             tokens, targets = next(data)

@@ -7,7 +7,7 @@ module is its server-grade twin: the per-layer state of every session in
 a fixed-capacity pool is stored as stacked device slabs
 (`BatchedLayerState`, shapes `[B, ...]`), and `step_batch` runs
 
-    IPU   delta_encode_batch            (vmap over slots)
+    IPU   delta_encode_batch            (slots batched)
     CTRL  select_active_columns_batch   (scatter route; the dense-mirror
     MACs  stsp_spmv_batch                route fuses both into
                                          delta_spmv_dense_topk_batch)
@@ -41,9 +41,11 @@ live sessions — `SessionPool.stream_partials` / the async front-end).
 Both are dispatched before the next `step_chunk` and fetched one chunk
 later, overlapped with the in-flight dispatch.
 
-Per-slot numerics are identical to `SpartusEngine`: the batched kernels
-are vmaps of the very same ops, so a session's logits do not depend on
-what the other slots are doing (verified in tests/test_serving_pool.py).
+Per-slot numerics are identical to `SpartusEngine`: every batched op
+computes each slot's row with the very same per-session math (a vmap on
+the XLA routes, a slot grid axis in the Pallas kernels), so a session's
+logits do not depend on what the other slots are doing (verified in
+tests/test_serving_pool.py).
 """
 from __future__ import annotations
 

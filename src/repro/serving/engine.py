@@ -11,8 +11,9 @@ The engine exports any trained LSTM AM (models/lstm_am.py) into packed
 CBCSC + int8 form, runs batched streaming sessions, and records the
 per-step NZI occupancies that drive the hwsim performance model.
 
-``use_pallas`` switches the kernel implementations (interpret mode on
-CPU, compiled Pallas on TPU); the XLA path is numerically identical.
+``use_pallas`` switches to the Pallas kernels (compiled by Mosaic on a
+TPU, interpreted only where lowered for the CPU: kernels/lowering.py);
+the XLA path is numerically identical.
 
 `SpartusEngine` is deliberately slow and simple — a Python loop per
 frame with host syncs for telemetry — because it is the parity oracle:

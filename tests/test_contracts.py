@@ -458,10 +458,12 @@ def test_op_histogram_counts_fusion_bodies_and_folds_versions():
 def test_collective_and_host_transfer_tokens_match_legacy_pins():
     """The analyzer's token lists are the SAME strings the PR-5/PR-6
     test pins greped for — migrating the tests must not have changed
-    what counts as a violation."""
+    what counts as a violation — plus the FFI callback target that
+    jax 0.9 emits for the same host callback."""
     assert hlo.COLLECTIVE_TOKENS == (
         "all-reduce", "all-gather", "collective-permute", "all-to-all",
         "reduce-scatter")
     assert hlo.HOST_TRANSFER_TOKENS == (
-        "outfeed", "infeed", "xla_python_cpu_callback", "host_callback",
+        "outfeed", "infeed", "xla_python_cpu_callback",
+        "xla_ffi_python_cpu_callback", "host_callback",
         "SendToHost", "RecvFromHost")

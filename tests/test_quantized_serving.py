@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.analysis import hlo
 from repro.analysis.cases import lower_pool_chunk
 from repro.core.quantization import QuantConfig
 from repro.models import lstm_am
@@ -237,8 +238,8 @@ def test_quant_disabled_is_bit_identical_to_fp32(model, fengines, workload):
     for a, b in zip(base, off):
         assert a.req_id == b.req_id
         np.testing.assert_array_equal(a.logits, b.logits)
-    assert lower_pool_chunk(eb_off, feats[:4]) == \
-        lower_pool_chunk(ebf, feats[:4])
+    assert hlo.strip_metadata(lower_pool_chunk(eb_off, feats[:4])) == \
+        hlo.strip_metadata(lower_pool_chunk(ebf, feats[:4]))
 
 
 # -- the compiled module: int8 operands, no baked fp32 mirror ----------------

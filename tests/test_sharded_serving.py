@@ -85,15 +85,8 @@ def workload(engines):
 # -- spec logic (no devices) --------------------------------------------------
 
 
-def _abstract_mesh(shape, axes):
-    try:  # jax < 0.5: a tuple of (name, size) pairs
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
-    except (TypeError, ValueError):  # jax >= 0.5: (axis_sizes, axis_names)
-        return jax.sharding.AbstractMesh(shape, axes)
-
-
-MESH4 = _abstract_mesh((4,), ("data",))
-MESH1 = _abstract_mesh((1,), ("data",))
+MESH4 = jax.sharding.AbstractMesh((4,), ("data",))
+MESH1 = jax.sharding.AbstractMesh((1,), ("data",))
 
 
 def test_slot_spec_divisible_shards_dim():

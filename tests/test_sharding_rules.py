@@ -9,15 +9,8 @@ from repro.configs import get_arch
 from repro.distributed.sharding import batch_spec, cache_spec, param_spec
 from repro import perf
 
-def _abstract_mesh(shape, axes):
-    try:  # jax < 0.5: a tuple of (name, size) pairs
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
-    except (TypeError, ValueError):  # jax >= 0.5: (axis_sizes, axis_names)
-        return jax.sharding.AbstractMesh(shape, axes)
-
-
-MESH = _abstract_mesh((16, 16), ("data", "model"))
-MESH3 = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
+MESH3 = jax.sharding.AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_attention_tp_when_heads_divide():
