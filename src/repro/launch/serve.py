@@ -69,7 +69,8 @@ operators scraping the live pool (docs/observability.md):
 Unknown commands answer ``{"error": "..."}`` in-band; the connection
 stays up.  `--stats-interval S` additionally logs a one-line pool-health
 summary every S seconds, and `--trace PATH` records the driver's phase
-spans (admission-wave upload, dispatch, snapshot D2H fetch, delivery
+spans (client pump, admission-wave upload, dispatch, retirement
+snapshot, snapshot D2H fetch with its device wait and copy, delivery
 pump, pacing idle) to a Chrome trace-event JSON on shutdown — load it in
 Perfetto or chrome://tracing.
 """

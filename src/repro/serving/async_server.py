@@ -217,9 +217,10 @@ class AsyncSpartusServer:
         a `PoolObservability` (serving/metrics.py): the pool folds every
         chunk boundary into its registry/ring buffer, and the driver
         amends each boundary's sample with loop-side signals (lagging
-        consumers, partial-queue depth, connected streams) and traces the
-        delivery/pacing phases.  Thread-safe with ``offload_ticks`` (the
-        registry and ring lock internally).  ``None`` = fully off.
+        consumers, partial-queue depth, connected streams, seconds in
+        the client pump, delivery and pacing spans).  Thread-safe with
+        ``offload_ticks`` (the registry and ring lock internally).
+        ``None`` = fully off.
     overload_policy:
         what happens when the admission queue (``max_pending``) is full:
         ``"wait"`` (default) blocks the caller until a slot frees — the
@@ -267,6 +268,10 @@ class AsyncSpartusServer:
         self.obs = observability
         self._tracer = (observability.tracer if observability is not None
                         else NULL_TRACER)
+        # host seconds of the loop-side spans since the last dispatched
+        # boundary, folded into that boundary's sample (None = not kept)
+        self._loop_phases: Optional[Dict[str, float]] = (
+            {} if observability is not None else None)
         self._engine = engine
         # the watchdog rebuilds the pool from these exact kwargs (modulo
         # max_frames, which tracks the live pool's grown buffer bucket):
@@ -712,7 +717,8 @@ class AsyncSpartusServer:
             # dead pool forever.
             pool = self.pool
             self._wake.clear()
-            self._pump()
+            with self._tracer.span("client_pump", self._loop_phases):
+                self._pump()
             self._service_lagging()
             self._reap_idle()
             if not self._has_work():
@@ -747,11 +753,11 @@ class AsyncSpartusServer:
                 finished, adv = self._recover(exc)
             self.now += max(adv, 1)
             self._steps += adv
-            with self._tracer.span("delivery_pump"):
+            with self._tracer.span("delivery_pump", self._loop_phases):
                 self._deliver(self.pool.take_partials(), finished)
             if self.obs is not None:
                 self._fold_loop_side(dispatched=adv > 0)
-            with self._tracer.span("pacing_idle"):
+            with self._tracer.span("pacing_idle", self._loop_phases):
                 if self.target_chunk_s > 0.0:
                     # wall-clock-paced boundaries: one chunk per period;
                     # the sleep is where client coroutines get the loop.
@@ -834,7 +840,8 @@ class AsyncSpartusServer:
         kwargs["max_frames"] = old.pool_config()["max_frames"]
         new = SessionPool(self._engine, self.capacity, **kwargs)
         new.n_dispatches = old.n_dispatches          # stats continuity
-        new._overlap_fracs = list(old._overlap_fracs)
+        new._overlap_sum = old._overlap_sum
+        new._overlap_n = old._overlap_n
         restored = []
         for snap in snaps:
             try:
@@ -875,7 +882,9 @@ class AsyncSpartusServer:
         """Fold the driver-loop-side signals the pool cannot see: lagging
         consumers, the deepest partial queue, connected streams.  When
         this iteration dispatched a chunk, also amend the boundary sample
-        the pool just appended — host bookkeeping only, no device work."""
+        the pool just appended, with the host seconds of the client pumps,
+        deliveries and pacing since the previous one — host bookkeeping
+        only, no device work."""
         obs = self.obs
         lagging = len(self._lagging)
         depth = max((cs.handle._partials.qsize()
@@ -887,6 +896,8 @@ class AsyncSpartusServer:
             obs.timeseries.update_last({
                 "lagging": lagging,
                 "partial_queue_depth_max": depth,
+                **{key: self._loop_phases.pop(key, 0.0) for key in
+                   ("client_pump_s", "delivery_pump_s", "pacing_idle_s")},
             })
 
     @property

@@ -94,6 +94,11 @@ def _fresh_layer_state(layer: PackedLayer, n_slots: int) -> BatchedLayerState:
     )
 
 
+def snapshot_out(out_buf: jax.Array) -> jax.Array:
+    """Device-side copy of the chunk output buffer (jitted per engine)."""
+    return out_buf.copy()
+
+
 class BatchedSpartusEngine(PackedSpartusModel):
     """Weight-resident multi-session engine: one CBCSC weight set, B
     independent streaming sessions multiplexed across it."""
@@ -116,7 +121,7 @@ class BatchedSpartusEngine(PackedSpartusModel):
         # Both are dispatched BEFORE the next step_chunk donates the
         # buffer away, detaching the rows device-side; the host fetch
         # happens one chunk later, overlapped with the next dispatch.
-        self._snapshot_out = jax.jit(lambda out: out.copy())
+        self._snapshot_out = jax.jit(snapshot_out)
         self._snapshot_chunk = jax.jit(ops.gather_rows,
                                        static_argnames=("n",))
         # observability: [3] device reduction of the telemetry slabs
@@ -124,8 +129,12 @@ class BatchedSpartusEngine(PackedSpartusModel):
         # the accumulators the chunk just produced, and is dispatched at
         # one boundary / fetched at the next, same detach-now/fetch-
         # later cadence as the output-buffer snapshots above.
-        self._tel_totals = jax.jit(
-            lambda t: tele.fold_totals(t, self.n_cols))
+        n_cols = self.n_cols
+
+        def telemetry_totals(t: tele.TelemetryState) -> jax.Array:
+            return tele.fold_totals(t, n_cols)
+
+        self._tel_totals = jax.jit(telemetry_totals)
 
     # -- state management ----------------------------------------------------
 
@@ -189,44 +198,52 @@ class BatchedSpartusEngine(PackedSpartusModel):
                     mirror = jax.lax.optimization_barrier(mirror)
                 else:
                     val, lidx = jax.lax.optimization_barrier((val, lidx))
-            s = jnp.concatenate([h, st.h], axis=-1)           # [B, D+H]
-            delta, s_hat, nnz = ops.delta_encode_batch(
-                s, st.s_hat, cfg.theta, use_pallas=cfg.use_pallas, **act_kw
-            )
-            if mirror is not None:
-                # dense-mirror route: capacity enforced in the dense
-                # domain (no NZI list, no scatter) — bit-identical to the
-                # select + dense-gather chain, measurably faster on CPU.
-                y, dropped = ops.delta_spmv_dense_topk_batch(
-                    mirror, delta, layer.capacity, scale=wscale)
-            else:
-                idx, vals, dropped = ops.select_active_columns_batch(
-                    delta, layer.capacity
+            with jax.named_scope("delta_encode"):
+                s = jnp.concatenate([h, st.h], axis=-1)       # [B, D+H]
+                delta, s_hat, nnz = ops.delta_encode_batch(
+                    s, st.s_hat, cfg.theta, use_pallas=cfg.use_pallas,
+                    **act_kw)
+            with jax.named_scope("matvec"):
+                if mirror is not None:
+                    # dense-mirror route: capacity enforced in the dense
+                    # domain (no NZI list, no scatter) — bit-identical to
+                    # the select + dense-gather chain, measurably faster
+                    # on CPU.
+                    y, dropped = ops.delta_spmv_dense_topk_batch(
+                        mirror, delta, layer.capacity, scale=wscale)
+                else:
+                    idx, vals, dropped = ops.select_active_columns_batch(
+                        delta, layer.capacity
+                    )
+                    y = ops.stsp_spmv_batch(
+                        val, lidx, idx, vals,
+                        s=layer.enc.s, use_pallas=cfg.use_pallas,
+                        scale=wscale,
+                    )
+                dm = st.dm + y.astype(st.dm.dtype)
+            with jax.named_scope("gates"):
+                h_new, c_new = ops.lstm_pointwise_batch(
+                    dm.reshape(n_slots, 4, layer.hidden_dim), st.c,
+                    use_pallas=cfg.use_pallas,
                 )
-                y = ops.stsp_spmv_batch(
-                    val, lidx, idx, vals,
-                    s=layer.enc.s, use_pallas=cfg.use_pallas, scale=wscale,
-                )
-            dm = st.dm + y.astype(st.dm.dtype)
-            h_new, c_new = ops.lstm_pointwise_batch(
-                dm.reshape(n_slots, 4, layer.hidden_dim), st.c,
-                use_pallas=cfg.use_pallas,
-            )
-            am = active[:, None]
-            new_layers.append(BatchedLayerState(
-                s_hat=jnp.where(am, s_hat, st.s_hat),
-                c=jnp.where(am, c_new, st.c),
-                h=jnp.where(am, h_new, st.h),
-                dm=jnp.where(am, dm, st.dm),
-            ))
+            with jax.named_scope("state_update"):
+                am = active[:, None]
+                new_layers.append(BatchedLayerState(
+                    s_hat=jnp.where(am, s_hat, st.s_hat),
+                    c=jnp.where(am, c_new, st.c),
+                    h=jnp.where(am, h_new, st.h),
+                    dm=jnp.where(am, dm, st.dm),
+                ))
             nnz_layers.append(nnz)
             dropped_layers.append(dropped)
             h = h_new
-        tel = tele.accumulate_layers(
-            state.telemetry, jnp.stack(nnz_layers),
-            jnp.stack(dropped_layers), active)
-        h = jax.nn.relu(h @ self.fcl["w"].T + self.fcl["b"])
-        logits = h @ self.logit["w"].T + self.logit["b"]
+        with jax.named_scope("telemetry"):
+            tel = tele.accumulate_layers(
+                state.telemetry, jnp.stack(nnz_layers),
+                jnp.stack(dropped_layers), active)
+        with jax.named_scope("head"):
+            h = jax.nn.relu(h @ self.fcl["w"].T + self.fcl["b"])
+            logits = h @ self.logit["w"].T + self.logit["b"]
         return PoolState(tuple(new_layers), tel, cursor), logits
 
     def _step_impl(
@@ -268,8 +285,11 @@ class BatchedSpartusEngine(PackedSpartusModel):
         # iteration's logits (static-offset writes), and ONE vmapped
         # dynamic-slice banks the whole [C, B, n_classes] block into the
         # per-slot output buffers at the chunk-start cursors; rows past a
-        # session's length are scratch no reader consumes.
-        state = self._apply_reset(state, reset, reset_cursor=True)
+        # session's length are scratch no reader consumes.  The named
+        # scopes (reset, step_scan, bank_rows, and per layer in
+        # _step_core) name the device work in a profiler trace.
+        with jax.named_scope("reset"):
+            state = self._apply_reset(state, reset, reset_cursor=True)
         start = state.cursor
 
         def body(st, _):
@@ -279,8 +299,10 @@ class BatchedSpartusEngine(PackedSpartusModel):
                 st, x, act, st.cursor + act.astype(st.cursor.dtype))
             return new_st, logits
 
-        state, ys = jax.lax.scan(body, state, None, length=n_frames)
-        return state, ops.bank_rows(out_buf, ys, start)
+        with jax.named_scope("step_scan"):
+            state, ys = jax.lax.scan(body, state, None, length=n_frames)
+        with jax.named_scope("bank_rows"):
+            return state, ops.bank_rows(out_buf, ys, start)
 
     def step_batch(
         self, state: PoolState, x: jax.Array, active: jax.Array,

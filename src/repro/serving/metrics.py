@@ -20,11 +20,15 @@ statistics.  This module is that data plane, in three pieces:
   wall time, host overlap, admissions/retirements per chunk, per-shard
   loads, lagging sessions, partial-queue depths, and the *incremental*
   temporal sparsity of just that window.
-* **`Tracer`** — Chrome-trace-event span instrumentation of the tick
-  loop's phases (admission-wave upload, dispatch, snapshot D2H fetch,
-  delivery pump, pacing idle), loadable in Perfetto / `chrome://tracing`.
-  Disabled tracing costs one attribute read and a no-op context manager
-  per phase (`NULL_TRACER`), so the hot path never pays for it.
+* **`Tracer`** — span instrumentation of the tick loop's phases
+  (admission-wave upload, dispatch, retirement snapshot, snapshot D2H
+  fetch split into the device wait and the copy, client pump, delivery
+  pump, pacing idle).  Every span is a ``jax.profiler.TraceAnnotation``
+  named ``spartus.<phase>``, so a profiler trace puts the device's idle
+  gaps under the program's own phases; an enabled tracer also keeps the
+  spans as Chrome trace events, loadable in Perfetto /
+  `chrome://tracing`.  With no profiler session running a span costs
+  about a microsecond.
 
 `PoolObservability` bundles the three and owns the **boundary-fold
 design rule** (the `TelemetryState` rule extended): every hot-path
@@ -48,6 +52,7 @@ from collections import deque
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
@@ -57,6 +62,20 @@ __all__ = [
 
 #: default bound on the per-chunk time-series ring buffer (samples).
 DEFAULT_TIMESERIES_LEN = 4096
+
+#: prefix of every span's name on the profiler clock.
+SPAN_PREFIX = "spartus."
+
+#: fields every boundary sample carries besides the fixed ones: host
+#: seconds per pool phase span, and what crossed between host and device
+#: since the previous boundary (rows and bytes fetched, rows of them
+#: delivered; real frames uploaded, frame slots and bytes sent).
+BOUNDARY_FIELDS = (
+    "admission_upload_s", "retire_snapshot_s", "snapshot_fetch_s",
+    "fetch_wait_s", "fetch_copy_s",
+    "fetch_rows", "fetch_rows_kept", "fetch_bytes",
+    "upload_frames", "upload_frame_slots", "upload_bytes",
+)
 
 #: default histogram buckets (seconds) for dispatch/chunk wall times:
 #: roughly log-spaced from 100 us to 3 s, covering CPU dev boxes through
@@ -353,47 +372,52 @@ class TimeSeries:
         return [dict(s) for s in samples]
 
 
-class _NullSpan:
-    """Reusable no-op context manager: disabled tracing allocates
-    nothing per phase."""
-
-    __slots__ = ()
-
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, *exc) -> None:
-        return None
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class _Span:
-    __slots__ = ("_tracer", "_name", "_t0")
+    """One phase: a profiler annotation, its host seconds added to
+    ``into[<phase>_s]`` when given, and a Chrome event when the tracer
+    records.  ``t0``/``t1`` are its ``perf_counter`` ends."""
 
-    def __init__(self, tracer: "Tracer", name: str):
+    __slots__ = ("_tracer", "_name", "_into", "_ann", "t0", "t1")
+
+    def __init__(self, tracer: "Tracer", name: str,
+                 into: Optional[Dict[str, float]]):
         self._tracer = tracer
         self._name = name
+        self._into = into
+        self._ann = TraceAnnotation(SPAN_PREFIX + name)
 
     def __enter__(self) -> "_Span":
-        self._t0 = time.perf_counter()
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        self._tracer._complete(self._name, self._t0, time.perf_counter())
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        if self._into is not None:
+            key = self._name + "_s"
+            self._into[key] = self._into.get(key, 0.0) + self.seconds
+        if self._tracer.enabled:
+            self._tracer._complete(self._name, self.t0, self.t1)
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
 
 
 class Tracer:
-    """Chrome trace-event recorder for the driver's tick-loop phases.
+    """Span instrumentation of the driver's tick-loop phases.
 
-    ``with tracer.span("dispatch"): ...`` records one complete ("ph":
-    "X") event; ``to_json()`` / ``dump(path)`` emit the
-    ``{"traceEvents": [...]}`` JSON that Perfetto and chrome://tracing
-    load directly.  Events are bounded (``max_events``, oldest dropped)
-    so an always-on tracer cannot grow without bound.  A disabled tracer
-    (``enabled=False``, or the shared `NULL_TRACER`) returns a no-op
-    span: the instrumentation sites cost one attribute check.
+    ``with tracer.span("dispatch"): ...`` always enters the profiler
+    annotation ``spartus.dispatch`` (a host event in a
+    ``jax.profiler`` trace, on the device trace's clock), and with
+    ``into=d`` adds the span's host seconds to ``d["dispatch_s"]``.  An
+    enabled tracer also records one complete ("ph": "X") Chrome event;
+    ``to_json()`` / ``dump(path)`` emit the ``{"traceEvents": [...]}``
+    JSON that Perfetto and chrome://tracing load directly.  Events are
+    bounded (``max_events``, oldest dropped) so an always-on tracer
+    cannot grow without bound.  A disabled tracer (``enabled=False``, or
+    the shared `NULL_TRACER`) records no Chrome event.
     """
 
     _guarded_by_ = {"_events": "_lock"}
@@ -408,10 +432,9 @@ class Tracer:
         self._events: deque = deque(maxlen=max_events)
         self._epoch = time.perf_counter()
 
-    def span(self, name: str):
-        if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name)
+    def span(self, name: str,
+             into: Optional[Dict[str, float]] = None) -> _Span:
+        return _Span(self, name, into)
 
     def _complete(self, name: str, t0: float, t1: float) -> None:
         ev = {
@@ -420,18 +443,6 @@ class Tracer:
             "ts": (t0 - self._epoch) * 1e6,
             "dur": (t1 - t0) * 1e6,
         }
-        with self._lock:
-            self._events.append(ev)
-
-    def instant(self, name: str, args: Optional[Dict[str, Any]] = None
-                ) -> None:
-        if not self.enabled:
-            return
-        ev = {"name": name, "ph": "i", "s": "g", "pid": 1,
-              "tid": threading.get_ident() & 0xFFFF,
-              "ts": (time.perf_counter() - self._epoch) * 1e6}
-        if args:
-            ev["args"] = args
         with self._lock:
             self._events.append(ev)
 
@@ -455,8 +466,8 @@ class Tracer:
 
 
 #: the shared disabled tracer: pool/driver phase sites call
-#: ``tracer.span(...)`` unconditionally; against NULL_TRACER that is one
-#: attribute read and a shared no-op context manager.
+#: ``tracer.span(...)`` unconditionally; against NULL_TRACER a span is
+#: the profiler annotation and two clock reads, with no Chrome event.
 NULL_TRACER = Tracer(enabled=False, max_events=1)
 
 
@@ -590,6 +601,10 @@ class PoolObservability:
         self._pending_totals: Optional[Any] = None   # device [3] array
         self._last_totals = np.zeros((3,), np.float64)
         self._shard_gauges: Dict[int, Gauge] = {}
+        # what the pool's spans and fetch / upload counts add up between
+        # two folds (BOUNDARY_FIELDS); the next fold_chunk moves it into
+        # its sample, so work done between dispatches (a flush) is kept.
+        self.boundary: Dict[str, float] = {}
 
     # -- source hooks (host-side bookkeeping the pool already does) ---------
 
@@ -697,9 +712,10 @@ class PoolObservability:
         series.  Every argument is a host value the pool computed anyway;
         ``telemetry_totals`` is the (device, un-fetched) [3] reduction of
         the `[L, B]` accumulators after this chunk — it is only *fetched*
-        at the next boundary.  Returns the appended sample (the async
-        driver amends it with loop-side fields via
-        ``timeseries.update_last``)."""
+        at the next boundary.  The sample also takes (and this empties)
+        ``self.boundary``, zero for each of `BOUNDARY_FIELDS` it lacks.
+        Returns the appended sample (the async driver amends it with
+        loop-side fields via ``timeseries.update_last``)."""
         self._chunk_seq += 1
         sp_inc, ovf_inc, steps_inc = self._diff_totals(telemetry_totals)
         self.c_dispatches.inc()
@@ -743,7 +759,10 @@ class PoolObservability:
             "temporal_sparsity_inc": sp_inc,
             "overflow_rate_inc": ovf_inc,
             "samples_inc": steps_inc,
+            **dict.fromkeys(BOUNDARY_FIELDS, 0),
+            **self.boundary,
         }
+        self.boundary.clear()
         self.timeseries.append(sample)
         if self.timeseries.n_dropped > dropped_before:
             self.c_ts_dropped.inc(self.timeseries.n_dropped - dropped_before)

@@ -524,10 +524,12 @@ class SessionPool:
         self._staged: List[Tuple[int, np.ndarray]] = []
         self._staged_appends: List[Tuple[int, int, np.ndarray]] = []
         # observability: buffer growths (should be 0 when pre-sized),
-        # dispatches issued, and per-chunk host-overlap fractions:
+        # dispatches issued, and the running sum and count of per-chunk
+        # host-overlap fractions:
         self.n_frame_grows = 0
         self.n_dispatches = 0
-        self._overlap_fracs: List[float] = []
+        self._overlap_sum = 0.0
+        self._overlap_n = 0
         # live observability (metrics.PoolObservability): all sources are
         # folded at dispatch boundaries only, on host values the pool
         # already computed — the one device-derived signal (incremental
@@ -535,10 +537,14 @@ class SessionPool:
         # boundary later, so observability never syncs on the in-flight
         # chunk and never changes the compiled step (pinned in
         # tests/test_observability.py).  None = fully off; the tracer
-        # falls back to the shared no-op NULL_TRACER.
+        # falls back to NULL_TRACER, whose spans are profiler annotations
+        # only.  ``_boundary`` collects span seconds and fetch / upload
+        # counts for the next boundary sample (None = not collected).
         self.obs = observability
         self._tracer = (observability.tracer if observability is not None
                         else NULL_TRACER)
+        self._boundary = (observability.boundary
+                          if observability is not None else None)
         self._adm_since_fold = 0
         # Guards the dispatch-and-rebind of ``self.state`` against readers
         # on other threads (the async server's ``stats()`` / the admin
@@ -571,6 +577,12 @@ class SessionPool:
                     for leaf in jax.tree_util.tree_leaves(self.state):
                         leaf.delete()
             raise
+
+    def _tally(self, **counts: int) -> None:
+        """Add host-known counts to the next boundary sample."""
+        if self._boundary is not None:
+            for key, n in counts.items():
+                self._boundary[key] = self._boundary.get(key, 0) + n
 
     def _dev1d(self, arr: np.ndarray) -> jax.Array:
         """Place a per-slot host vector (active/reset masks, chunk-start
@@ -925,6 +937,9 @@ class SessionPool:
                     slots[i] = k
                     ts[i] = feats.shape[0]
                 self._staged.clear()
+                self._tally(upload_frames=int(ts.sum()),
+                            upload_frame_slots=rb * self._t_buf,
+                            upload_bytes=rows.nbytes)
                 self._frames, self._lengths = _device_upload(
                     self._frames, self._lengths, jax.device_put(rows),
                     slots, ts)
@@ -941,6 +956,9 @@ class SessionPool:
                     starts[i] = start
                     ts[i] = start + feats.shape[0]
                 self._staged_appends.clear()
+                self._tally(upload_frames=int((ts - starts).sum()),
+                            upload_frame_slots=rb * a_pad,
+                            upload_bytes=rows.nbytes)
                 self._frames, self._lengths = _device_append(
                     self._frames, self._lengths, jax.device_put(rows), slots,
                     starts, ts)
@@ -972,19 +990,20 @@ class SessionPool:
         active, reset = self._masks()
         if not active.any():
             return []
-        with self._tracer.span("admission_upload"):
+        with self._tracer.span("admission_upload", self._boundary):
             self._flush_uploads()
         self._fire("dispatch")
 
-        t0 = time.perf_counter()
-        with self._tracer.span("dispatch"), self._state_lock:
+        with self._tracer.span("dispatch") as disp, self._state_lock:
             self.state, logits = self.engine.step_frames(
                 self.state, self._frames, self._dev1d(active),
                 self._dev1d(reset))
         self.n_dispatches += 1
-        t_dispatched = time.perf_counter()
-        with self._tracer.span("snapshot_fetch"):
+        with self._tracer.span("snapshot_fetch", self._boundary):
             logits_np = np.asarray(logits)      # ONE device->host fetch/tick
+        self._tally(fetch_rows=logits_np.shape[0],
+                    fetch_rows_kept=int(active.sum()),
+                    fetch_bytes=logits_np.nbytes)
 
         finished: List[RequestResult] = []
         for k, sess in enumerate(self._slots):
@@ -1009,8 +1028,8 @@ class SessionPool:
             self.obs.fold_results(finished)
             self._fold_boundary(
                 n_active=int(active.sum()), frames=int(active.sum()),
-                dispatch_s=t_dispatched - t0,
-                chunk_s=time.perf_counter() - t0,
+                dispatch_s=disp.seconds,
+                chunk_s=time.perf_counter() - disp.t0,
                 overlap=0.0, retirements=len(finished))
         return finished
 
@@ -1063,17 +1082,15 @@ class SessionPool:
         n = self._chunk_len()
         starts = np.array([0 if s is None else s.cursor
                            for s in self._slots], np.int32)
-        with self._tracer.span("admission_upload"):
+        with self._tracer.span("admission_upload", self._boundary):
             self._flush_uploads()
         self._fire("dispatch")
 
-        t0 = time.perf_counter()
-        with self._tracer.span("dispatch"), self._state_lock:
+        with self._tracer.span("dispatch") as disp, self._state_lock:
             self.state, self._out = self.engine.step_chunk(
                 self.state, self._frames, self._lengths, self._dev1d(active),
                 self._dev1d(reset), self._out, n_frames=n)
         self.n_dispatches += 1
-        t_dispatched = time.perf_counter()
 
         # ---- everything below overlaps the in-flight device chunk ----
         retiring: List[_Session] = []
@@ -1099,7 +1116,8 @@ class SessionPool:
         newly: List[_PendingChunk] = []
         newly_partials: List[_PendingPartials] = []
         if retiring or partial_entries:
-            with self._state_lock:
+            with self._tracer.span("retire_snapshot", self._boundary), \
+                    self._state_lock:
                 if retiring:
                     # snapshot the output buffer NOW, in one device op: it
                     # is dispatched against this chunk's output before the
@@ -1118,26 +1136,26 @@ class SessionPool:
                         rows=self.engine.snapshot_chunk(self._out,
                                                         self._dev1d(starts),
                                                         n_frames=n)))
-        with self._tracer.span("snapshot_fetch"):
+        with self._tracer.span("snapshot_fetch", self._boundary) as fetch:
             finished = self._resolve()       # syncs on the PREVIOUS chunk
-        t_end = time.perf_counter()
         with self._state_lock:
             self._pending.extend(newly)
             self._pending_partials.extend(newly_partials)
 
-        wall = t_end - t0
+        wall = fetch.t1 - disp.t0
         overlap = 0.0
         if wall > 0:
             # fraction of this call's wall time spent doing useful host
             # work AFTER the dispatch returned — retirement bookkeeping,
             # the snapshot dispatch, and the previous chunk's logits
             # fetch — all concurrent with the device executing this chunk.
-            overlap = (t_end - t_dispatched) / wall
-            self._overlap_fracs.append(overlap)
+            overlap = (fetch.t1 - disp.t1) / wall
+            self._overlap_sum += overlap
+            self._overlap_n += 1
         if self.obs is not None:
             self._fold_boundary(
                 n_active=int(active.sum()), frames=frames_this,
-                dispatch_s=t_dispatched - t0, chunk_s=wall,
+                dispatch_s=disp.seconds, chunk_s=wall,
                 overlap=overlap, retirements=len(finished))
         return finished
 
@@ -1154,7 +1172,8 @@ class SessionPool:
                 slots.append(k)
                 self._free(k)
         if retiring:
-            with self._state_lock:
+            with self._tracer.span("retire_snapshot", self._boundary), \
+                    self._state_lock:
                 self._pending.append(_PendingChunk(
                     sessions=retiring, slots=slots,
                     rows=self.engine.snapshot_out(self._out)))
@@ -1165,7 +1184,8 @@ class SessionPool:
         if self.chunk_frames:
             self._reap_cancelled()
             self._queue_done_retirements()
-        return self._resolve()
+        with self._tracer.span("snapshot_fetch", self._boundary):
+            return self._resolve()
 
     def tick(self, now: int) -> Tuple[List[RequestResult], int]:
         """Non-blocking driver entry: at most one dispatch, in either mode.
@@ -1213,7 +1233,8 @@ class SessionPool:
         if not pend:
             return
         for p in pend:
-            rows = np.asarray(p.rows)          # ONE fetch per chunk
+            rows = self._fetch(p.rows)         # ONE fetch per chunk
+            kept = 0
             for sess, k, t0, adv in p.entries:
                 if sess.cancelled:
                     continue                   # cancelled mid-window
@@ -1221,6 +1242,19 @@ class SessionPool:
                     sess.first_logit_wall = time.perf_counter()
                 self._partials.append(PartialLogits(
                     req_id=sess.req_id, t0=t0, rows=rows[k, :adv].copy()))
+                kept += adv
+            self._tally(fetch_rows_kept=kept)
+
+    def _fetch(self, rows: jax.Array) -> np.ndarray:
+        """Device-to-host copy of one snapshot, timed in two spans: the
+        wait for the device to finish it, then the copy itself."""
+        with self._tracer.span("fetch_wait", self._boundary):
+            jax.block_until_ready(rows)
+        with self._tracer.span("fetch_copy", self._boundary):
+            host = np.asarray(rows)
+        self._tally(fetch_rows=host.shape[0] * host.shape[1],
+                    fetch_bytes=host.nbytes)
+        return host
 
     def _resolve_pending(self) -> List[RequestResult]:
         with self._state_lock:
@@ -1229,12 +1263,15 @@ class SessionPool:
             return []
         out: List[RequestResult] = []
         for p in pend:
-            rows = np.asarray(p.rows)          # ONE fetch for all retirees
+            rows = self._fetch(p.rows)         # ONE fetch for all retirees
+            kept = 0
             for sess, k in zip(p.sessions, p.slots):
                 if sess.cancelled:
                     continue   # cancelled inside the retirement window:
                     #            the snapshot is dropped, never delivered
                 out.append(sess.result(rows[k, :sess.cursor].copy()))
+                kept += sess.cursor
+            self._tally(fetch_rows_kept=kept)
         if self.obs is not None:
             self.obs.fold_results(out)
         return out
@@ -1265,7 +1302,7 @@ class SessionPool:
         )
 
     def mean_host_overlap_frac(self) -> float:
-        return float(np.mean(self._overlap_fracs)) if self._overlap_fracs \
+        return self._overlap_sum / self._overlap_n if self._overlap_n \
             else 0.0
 
     def drain(self, now: int) -> List[RequestResult]:

@@ -148,16 +148,14 @@ def test_tracer_records_loadable_chrome_json():
         pass
     with tr.span("snapshot_fetch"):
         pass
-    tr.instant("note", {"k": "v"})
     doc = json.loads(tr.to_json())
     assert set(doc) == {"traceEvents", "displayTimeUnit"}
     names = {e["name"] for e in doc["traceEvents"]}
-    assert names == {"dispatch", "snapshot_fetch", "note"}
+    assert names == {"dispatch", "snapshot_fetch"}
     for e in doc["traceEvents"]:
-        assert e["ph"] in ("X", "i")
+        assert e["ph"] == "X"
         assert e["ts"] >= 0
-        if e["ph"] == "X":
-            assert e["dur"] >= 0
+        assert e["dur"] >= 0
 
 
 def test_tracer_bounded_events():
@@ -176,6 +174,25 @@ def test_disabled_tracer_records_nothing():
     assert tr.n_events == 0
     assert NULL_TRACER.n_events == 0
     assert json.loads(NULL_TRACER.to_json())["traceEvents"] == []
+
+
+def test_span_sums_seconds_into_and_keeps_disabled_ring_empty():
+    """A span feeds ``into[<phase>_s]`` whether or not the Chrome ring
+    records: the disabled tracer adds seconds and records no event."""
+    into = {}
+    for tr in (NULL_TRACER, Tracer(enabled=False)):
+        for _ in range(2):
+            with tr.span("snapshot_fetch", into) as sp:
+                pass
+            assert sp.t1 >= sp.t0 and sp.seconds >= 0
+        assert tr.n_events == 0
+    assert set(into) == {"snapshot_fetch_s"}
+    assert into["snapshot_fetch_s"] >= 0
+    rec = Tracer(enabled=True)
+    with rec.span("dispatch", into):
+        pass
+    assert rec.phase_names() == ["dispatch"]
+    assert set(into) == {"snapshot_fetch_s", "dispatch_s"}
 
 
 def test_tracer_dump(tmp_path):

@@ -5,6 +5,7 @@ tick-loop tracer must cover all five driver phases, and the admin
 endpoint must answer every command against a live async pool under load.
 """
 import asyncio
+import contextlib
 import json
 import re
 import threading
@@ -34,14 +35,18 @@ GAMMA, M, THETA = 0.75, 4, 0.05
 LENS = [5, 9, 3, 12, 1, 7, 8, 2]
 
 
-@pytest.fixture(scope="module")
-def engine():
+def _make_engine():
     cfg = lstm_am.LSTMAMConfig(input_dim=INPUT_DIM, hidden_dim=HIDDEN,
                                n_layers=2, n_classes=CLASSES)
     params = lstm_am.cbtd_prune_stacks(
         lstm_am.init_params(jax.random.key(0), cfg), gamma=GAMMA, m=M)
     ecfg = EngineConfig(theta=THETA, gamma=GAMMA, m=M, capacity_frac=1.0)
     return BatchedSpartusEngine(params, cfg, ecfg)
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return _make_engine()
 
 
 @pytest.fixture(scope="module")
@@ -75,6 +80,26 @@ def test_compiled_chunk_identical_with_and_without_obs(engine, workload):
     assert hlo_on == hlo_off
     hits = hlolib.host_transfer_lines(hlo_on)
     assert hits == [], f"host-transfer ops in compiled chunk: {hits[:5]}"
+
+
+DEVICE_SCOPES = ("reset", "step_scan", "bank_rows", "delta_encode",
+                 "matvec", "gates", "state_update", "telemetry", "head")
+
+
+def test_device_scopes_are_metadata_only(engine, workload, monkeypatch):
+    """The named scopes name the chunk program's work (the ``op_name``
+    metadata a profiler trace carries) and change none of its ops: the
+    compiled chunk with every scope turned into a no-op differs only in
+    metadata."""
+    scoped = lower_pool_chunk(engine, workload)
+    for name in DEVICE_SCOPES:
+        assert f"/{name}/" in scoped, name
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = lower_pool_chunk(_make_engine(), workload)
+    assert "/bank_rows/" not in bare
+    assert hlolib.op_histogram(scoped) == hlolib.op_histogram(bare)
+    assert hlolib.strip_metadata(scoped) == hlolib.strip_metadata(bare)
 
 
 def test_telemetry_totals_reduction_is_transfer_free(engine):
@@ -205,8 +230,9 @@ def test_bench_writer_stamps_and_refuses_mixed_schemas(tmp_path):
 
 # --------------------------------------------- tracer + admin end-to-end
 
-FIVE_PHASES = {"admission_upload", "dispatch", "snapshot_fetch",
-               "delivery_pump", "pacing_idle"}
+PHASES = {"client_pump", "admission_upload", "dispatch", "retire_snapshot",
+          "snapshot_fetch", "fetch_wait", "fetch_copy", "delivery_pump",
+          "pacing_idle"}
 
 
 async def _admin_query(reader, writer, msg):
@@ -217,8 +243,8 @@ async def _admin_query(reader, writer, msg):
 
 def test_async_trace_and_admin_endpoint(engine, workload):
     """One live async run, under client load, covering the tentpole's
-    operator surface end to end: the tracer records all five tick-loop
-    phases as loadable Chrome trace JSON, and the admin endpoint answers
+    operator surface end to end: the tracer records every tick-loop
+    phase as loadable Chrome trace JSON, and the admin endpoint answers
     healthz/stats/metrics/timeseries (plus in-band errors) while the
     pool is actively serving."""
     from repro.launch.serve import start_admin_server
@@ -269,16 +295,104 @@ def test_async_trace_and_admin_endpoint(engine, workload):
     assert len(ts["timeseries"]) <= 4 and ts["n_appended"] > 0
     for s in ts["timeseries"]:
         assert {"chunk", "occupancy", "frames", "dispatch_s",
-                "temporal_sparsity_inc"} <= set(s)
+                "temporal_sparsity_inc", "snapshot_fetch_s", "fetch_rows",
+                "upload_frame_slots", "client_pump_s", "delivery_pump_s",
+                "pacing_idle_s"} <= set(s)
     assert "error" in bad and "error" in not_obj
     assert len(results) == 6 and all(r.logits.size for r in results)
     # the delivered-result counters agree with what the clients saw:
     assert obs.c_completed.value == 6.0
-    # all five driver phases traced, and the trace round-trips as JSON:
+    # every driver phase traced, and the trace round-trips as JSON:
     doc = json.loads(obs.tracer.to_json())
     names = {e["name"] for e in doc["traceEvents"]}
-    assert FIVE_PHASES <= names, f"missing phases: {FIVE_PHASES - names}"
+    assert PHASES <= names, f"missing phases: {PHASES - names}"
     assert all(e["ph"] in ("X", "i") for e in doc["traceEvents"])
+
+
+# ------------------------------- spans on the profiler clock, boundary counts
+
+def _host_event_names(log_dir):
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    return {e.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+
+
+def test_spans_land_on_the_profiler_clock(engine, workload, tmp_path):
+    """With no observability attached (the tracer is NULL_TRACER) the
+    pool's and the driver's phases still appear as ``spartus.*`` host
+    events of a ``jax.profiler`` trace, on the device trace's clock."""
+    async def run():
+        async with AsyncSpartusServer(engine, capacity=3,
+                                      chunk_frames=4) as server:
+            return await asyncio.gather(*(server.submit(f)
+                                          for f in workload[:4]))
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        results = asyncio.run(run())
+    finally:
+        jax.profiler.stop_trace()
+    assert len(results) == 4
+    names = _host_event_names(str(tmp_path))
+    want = {"spartus.snapshot_fetch", "spartus.fetch_wait",
+            "spartus.fetch_copy", "spartus.client_pump",
+            "spartus.dispatch", "spartus.delivery_pump"}
+    assert want <= names, f"missing: {want - names}"
+    assert not any(n.startswith("bench.") for n in names)
+
+
+@pytest.mark.parametrize("stream_partials", [False, True])
+def test_boundary_counts_match_what_crossed(engine, workload,
+                                            stream_partials):
+    """The boundary samples count what crossed between host and device:
+    one admission wave of ``rb x T_buf`` frame slots holding the real
+    frames, one whole-bank snapshot of ``B x T_pad`` rows per retiring
+    boundary, and of the fetched rows exactly those delivered (each
+    session's rows, and with partials each chunk's rows once more).
+    Work resolved after the last dispatch waits in ``obs.boundary``."""
+    cap, chunk = 3, 4
+    feats = workload[:cap]          # 5, 9, 3 frames: each retires alone
+    obs = PoolObservability()
+    pool = SessionPool(engine, capacity=cap, max_frames=16,
+                       chunk_frames=chunk, stream_partials=stream_partials,
+                       observability=obs)
+    for i, f in enumerate(feats):
+        pool.admit(StreamRequest(i, 0, f), 0)
+    results, now = [], 0
+    while len(results) < cap:
+        done, adv = pool.tick(now)
+        results += done
+        now += max(adv, 1)
+    partial_rows = sum(p.rows.shape[0] for p in pool.take_partials())
+    samples = obs.timeseries.snapshot()
+
+    def total(key):
+        return sum(s[key] for s in samples) + obs.boundary.get(key, 0)
+
+    t_buf, t_pad = pool._frames.shape[1], pool._out.shape[1]
+    n_frames = sum(f.shape[0] for f in feats)
+    assert samples[0]["upload_frame_slots"] == 4 * t_buf   # rb = 4 >= 3
+    assert samples[0]["upload_frames"] == n_frames
+    assert samples[0]["upload_bytes"] == 4 * t_buf * INPUT_DIM * 4
+    assert total("upload_frame_slots") == 4 * t_buf
+    delivered = sum(r.logits.shape[0] for r in results)
+    assert delivered == n_frames
+    assert partial_rows == (n_frames if stream_partials else 0)
+    assert total("fetch_rows_kept") == delivered + partial_rows
+    # scans of 4, 4 and 1 frames (the 9-frame session sets each length);
+    # a partials snapshot holds every slot's rows of one scan:
+    chunk_rows = cap * (4 + 4 + 1) if stream_partials else 0
+    assert total("fetch_rows") == len(results) * cap * t_pad + chunk_rows
+    assert total("fetch_bytes") == total("fetch_rows") * CLASSES * 4
+    for key in ("snapshot_fetch_s", "fetch_wait_s", "fetch_copy_s",
+                "retire_snapshot_s", "admission_upload_s"):
+        assert total(key) > 0
 
 
 # ----------------------------------------- scrape-vs-update thread safety

@@ -1,6 +1,6 @@
 """Observability smoke for CI: a live async pool under client load, the
-admin endpoint answering every command, the tracer covering all five
-tick-loop phases, and the counters agreeing with the delivered results.
+admin endpoint answering every command, the tracer covering every
+tick-loop phase, and the counters agreeing with the delivered results.
 
 Spins up an in-process `AsyncSpartusServer` (tiny untrained CBTD model —
 this exercises plumbing, not accuracy) with observability + tracing
@@ -25,8 +25,9 @@ import sys
 
 import numpy as np
 
-REQUIRED_PHASES = {"admission_upload", "dispatch", "snapshot_fetch",
-                   "delivery_pump", "pacing_idle"}
+REQUIRED_PHASES = {"client_pump", "admission_upload", "dispatch",
+                   "retire_snapshot", "snapshot_fetch", "fetch_wait",
+                   "fetch_copy", "delivery_pump", "pacing_idle"}
 ADMIN_COMMANDS = ("healthz", "stats", "metrics", "timeseries")
 
 
