@@ -283,6 +283,14 @@ def _built_gather_rows() -> BuiltCase:
                      args=(buf, start), kwargs={"n": 4}, donate_argnums=())
 
 
+def _built_retire_rows() -> BuiltCase:
+    engine = _engine()
+    buf = jax.random.normal(jax.random.key(12), (4, 16, CLASSES), jnp.float32)
+    slot_idx = jnp.asarray([2, 0, 2], jnp.int32)   # last index: padding
+    return BuiltCase(fn=engine._retire_rows, args=(buf, slot_idx),
+                     kwargs={}, donate_argnums=())
+
+
 def _built_gather_frames() -> BuiltCase:
     from repro.kernels import ops
 
@@ -335,6 +343,7 @@ def build_cases(*, include_sharded: Optional[bool] = None) -> List[ContractCase]
         ContractCase("fold_totals", "fold_totals", _built_fold_totals),
         ContractCase("bank_rows", "bank_rows", _built_bank_rows),
         ContractCase("gather_rows", "gather_rows", _built_gather_rows),
+        ContractCase("retire_rows", "retire_rows", _built_retire_rows),
         ContractCase("gather_frames", "gather_frames", _built_gather_frames),
     ]
     if include_sharded:

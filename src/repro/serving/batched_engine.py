@@ -34,10 +34,11 @@ serving-path functions (`step_frames`, `step_chunk`) donate the incoming
 in place tick over tick instead of reallocating.
 
 Because the output buffer is donated, anything that must outlive the
-next chunk is detached device-side first: `snapshot_out` copies the
-whole buffer (retiring sessions' rows), and `snapshot_chunk` slices just
-one chunk's `[B, C, n_classes]` window (the partial-logits stream for
-live sessions — `SessionPool.stream_partials` / the async front-end).
+next chunk is detached device-side first: `retire_rows` gathers just
+the retiring slots' rows (`[R, T_pad, n_classes]`), and `snapshot_chunk`
+slices just one chunk's `[B, C, n_classes]` window (the partial-logits
+stream for live sessions — `SessionPool.stream_partials` / the async
+front-end).
 Both are dispatched before the next `step_chunk` and fetched one chunk
 later, overlapped with the in-flight dispatch.
 
@@ -94,9 +95,13 @@ def _fresh_layer_state(layer: PackedLayer, n_slots: int) -> BatchedLayerState:
     )
 
 
-def snapshot_out(out_buf: jax.Array) -> jax.Array:
-    """Device-side copy of the chunk output buffer (jitted per engine)."""
-    return out_buf.copy()
+@hotpath_contract("retire_rows", forbid_ops=("transpose",))
+def retire_rows(out_buf: jax.Array, slot_idx: jax.Array) -> jax.Array:
+    """Device-side gather of whole slots' rows out of the chunk output
+    buffer (jitted per engine): ``out_buf [B, T_pad, C]``, ``slot_idx
+    [R] int32`` -> ``[R, T_pad, C]``.  Indices are pool slots, always in
+    range, so ``clip`` adds no masking."""
+    return jnp.take(out_buf, slot_idx, axis=0, mode="clip")
 
 
 class BatchedSpartusEngine(PackedSpartusModel):
@@ -116,12 +121,13 @@ class BatchedSpartusEngine(PackedSpartusModel):
         self._step_chunk = jax.jit(self._step_chunk_impl,
                                    static_argnames=("n_frames",),
                                    donate_argnums=(0, 5))
-        # output-buffer snapshots (chunked serving): full-buffer copy for
-        # retirements, chunk-window slice for streamed partial logits.
-        # Both are dispatched BEFORE the next step_chunk donates the
-        # buffer away, detaching the rows device-side; the host fetch
-        # happens one chunk later, overlapped with the next dispatch.
-        self._snapshot_out = jax.jit(snapshot_out)
+        # output-buffer snapshots (chunked serving): a gather of the
+        # retiring slots' rows, and a chunk-window slice for streamed
+        # partial logits.  Both are dispatched BEFORE the next step_chunk
+        # donates the buffer away, detaching the rows device-side; the
+        # host fetch happens one chunk later, overlapped with the next
+        # dispatch.
+        self._retire_rows = jax.jit(retire_rows)
         self._snapshot_chunk = jax.jit(ops.gather_rows,
                                        static_argnames=("n",))
         # observability: [3] device reduction of the telemetry slabs
@@ -379,13 +385,16 @@ class BatchedSpartusEngine(PackedSpartusModel):
             jnp.asarray(active, bool), jnp.asarray(reset, bool), out_buf,
             n_frames=int(n_frames))
 
-    def snapshot_out(self, out_buf: jax.Array) -> jax.Array:
-        """Device-side copy of the whole chunk output buffer (ONE op,
-        shape-stable: a single compile per pool however many sessions
-        retire).  Used to detach retiring sessions' rows before the next
-        ``step_chunk`` donates the buffer away; the retirees' rows are
-        then fetched in one D2H copy one chunk later."""
-        return self._snapshot_out(out_buf)
+    def retire_rows(self, out_buf: jax.Array,
+                    slot_idx: jax.Array) -> jax.Array:
+        """Device-side gather of the retiring slots' rows: ``out_buf [B,
+        T_pad, n_classes]``, ``slot_idx [R] int32`` -> ``[R, T_pad,
+        n_classes]``.  The pool calls it with a fixed ``R`` (padding the
+        indices), so it compiles once per pool however many sessions
+        retire.  Used to detach retiring sessions' rows before the next
+        ``step_chunk`` donates the buffer away; each block is then
+        fetched in one D2H copy one chunk later."""
+        return self._retire_rows(out_buf, jnp.asarray(slot_idx, jnp.int32))
 
     def snapshot_chunk(self, out_buf: jax.Array, starts: jax.Array,
                        *, n_frames: int) -> jax.Array:
@@ -393,7 +402,7 @@ class BatchedSpartusEngine(PackedSpartusModel):
         ``out_buf [B, T_pad, n_classes]``, per-slot chunk-start cursors
         ``starts [B]`` -> ``[B, n_frames, n_classes]``.
 
-        This is the live-slot counterpart of ``snapshot_out``: partial-
+        This is the live-slot counterpart of ``retire_rows``: partial-
         logits streaming needs every chunk's rows for every advancing
         session, and copying the whole output buffer per chunk would
         scale with utterance length — the window slice scales with the

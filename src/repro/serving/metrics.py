@@ -69,11 +69,12 @@ SPAN_PREFIX = "spartus."
 #: fields every boundary sample carries besides the fixed ones: host
 #: seconds per pool phase span, and what crossed between host and device
 #: since the previous boundary (rows and bytes fetched, rows of them
-#: delivered; real frames uploaded, frame slots and bytes sent).
+#: delivered, retirement gathers dispatched; real frames uploaded, frame
+#: slots and bytes sent).
 BOUNDARY_FIELDS = (
     "admission_upload_s", "retire_snapshot_s", "snapshot_fetch_s",
     "fetch_wait_s", "fetch_copy_s",
-    "fetch_rows", "fetch_rows_kept", "fetch_bytes",
+    "fetch_rows", "fetch_rows_kept", "fetch_bytes", "retire_gathers",
     "upload_frames", "upload_frame_slots", "upload_bytes",
 )
 

@@ -259,17 +259,23 @@ class _Session:
         )
 
 
+#: slots per retirement gather (clipped to the pool's capacity): one
+#: block shape, so a pool compiles one retirement program however many
+#: sessions retire at a boundary; more retirees take more blocks.
+RETIRE_BLOCK = 32
+
+
 @dataclasses.dataclass
 class _PendingChunk:
-    """Sessions that finished inside an in-flight chunk: their logits rows
-    were gathered out of the device output buffer in one op (async,
-    BEFORE the next chunk donates that buffer away) and are fetched to
-    host one chunk later — one D2H copy for all of them — overlapped with
-    the next chunk's device execution."""
+    """Sessions that finished inside an in-flight chunk: their slots' rows
+    were gathered out of the device output buffer in blocks of
+    ``RETIRE_BLOCK`` slots (async, BEFORE the next chunk donates that
+    buffer away) and are fetched to host one chunk later — one D2H copy
+    per block — overlapped with the next chunk's device execution."""
 
     sessions: List[_Session]
-    slots: List[int]       # pool slot each session occupied
-    rows: jax.Array        # [B, T_pad, n_classes] device-side snapshot
+    where: List[Tuple[int, int]]  # (block, row) holding each session's rows
+    blocks: List[jax.Array]       # [R, T_pad, n_classes] device gathers
 
 
 @dataclasses.dataclass
@@ -1119,14 +1125,11 @@ class SessionPool:
             with self._tracer.span("retire_snapshot", self._boundary), \
                     self._state_lock:
                 if retiring:
-                    # snapshot the output buffer NOW, in one device op: it
-                    # is dispatched against this chunk's output before the
-                    # next step_chunk donates it, detaching the rows
-                    # device-side; the one-copy host fetch waits one more
-                    # chunk.
-                    newly.append(_PendingChunk(
-                        sessions=retiring, slots=slots,
-                        rows=self.engine.snapshot_out(self._out)))
+                    # gather the retirees' rows NOW: dispatched against
+                    # this chunk's output before the next step_chunk
+                    # donates it, detaching the rows device-side; the
+                    # host fetch waits one more chunk.
+                    newly.append(self._gather_retirees(retiring, slots))
                 if partial_entries:
                     # likewise for the streamed chunk rows — but only this
                     # chunk's [B, n, n_classes] window, not the whole
@@ -1162,7 +1165,7 @@ class SessionPool:
     def _queue_done_retirements(self) -> None:
         """Retire sessions that are already done WITHOUT another dispatch
         (a stream finished after its last received frame was consumed, or
-        finished with zero frames): snapshot their banked rows now; the
+        finished with zero frames): gather their banked rows now; the
         results surface at the next resolve like any other retirement."""
         retiring: List[_Session] = []
         slots: List[int] = []
@@ -1174,9 +1177,25 @@ class SessionPool:
         if retiring:
             with self._tracer.span("retire_snapshot", self._boundary), \
                     self._state_lock:
-                self._pending.append(_PendingChunk(
-                    sessions=retiring, slots=slots,
-                    rows=self.engine.snapshot_out(self._out)))
+                self._pending.append(self._gather_retirees(retiring, slots))
+
+    def _gather_retirees(self, retiring: List[_Session],
+                         slots: List[int]) -> _PendingChunk:
+        """Dispatch ``ceil(len(slots) / R)`` gathers of the retiring slots'
+        rows out of ``self._out``, R slots each; the last block's spare
+        indices repeat its first slot, and those rows are never read.
+        Caller holds ``_state_lock``."""
+        r = min(RETIRE_BLOCK, self.capacity)
+        blocks: List[jax.Array] = []
+        where: List[Tuple[int, int]] = []
+        for lo in range(0, len(slots), r):
+            block = slots[lo:lo + r]
+            idx = np.full((r,), block[0], np.int32)
+            idx[:len(block)] = block
+            where += [(len(blocks), j) for j in range(len(block))]
+            blocks.append(self.engine.retire_rows(self._out, idx))
+        self._tally(retire_gathers=len(blocks))
+        return _PendingChunk(sessions=retiring, where=where, blocks=blocks)
 
     def flush(self) -> List[RequestResult]:
         """Resolve retirements (and streamed partials) still pending from
@@ -1263,13 +1282,13 @@ class SessionPool:
             return []
         out: List[RequestResult] = []
         for p in pend:
-            rows = self._fetch(p.rows)         # ONE fetch for all retirees
+            blocks = [self._fetch(b) for b in p.blocks]  # ONE fetch a block
             kept = 0
-            for sess, k in zip(p.sessions, p.slots):
+            for sess, (b, j) in zip(p.sessions, p.where):
                 if sess.cancelled:
                     continue   # cancelled inside the retirement window:
-                    #            the snapshot is dropped, never delivered
-                out.append(sess.result(rows[k, :sess.cursor].copy()))
+                    #            the rows are dropped, never delivered
+                out.append(sess.result(blocks[b][j, :sess.cursor].copy()))
                 kept += sess.cursor
             self._tally(fetch_rows_kept=kept)
         if self.obs is not None:

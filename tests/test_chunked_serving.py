@@ -22,7 +22,8 @@ from repro.serving import (
     StreamRequest,
     serve_requests,
 )
-from repro.serving.scheduler import SessionPool
+from repro.serving.metrics import PoolObservability
+from repro.serving.scheduler import RETIRE_BLOCK, SessionPool
 
 INPUT_DIM, HIDDEN, CLASSES = 20, 32, 11
 GAMMA, M, THETA = 0.75, 4, 0.05
@@ -332,3 +333,137 @@ def test_dispatch_amortisation_metrics(engines):
     assert chunked.total_steps == 16
     assert 0.0 <= chunked.host_overlap_frac < 1.0
     assert per_frame.host_overlap_frac == 0.0
+
+
+# -- retirement gather -------------------------------------------------------
+
+
+def _step_batch_oracle(eb, feats, batch):
+    """Each utterance's logits from host-staged ``step_batch`` calls on a
+    ``batch``-slot state, ``batch`` utterances at a time: bit-identical
+    to what the chunked pool banks at the same capacity."""
+    out = []
+    for lo in range(0, len(feats), batch):
+        group = feats[lo:lo + batch]
+        state = eb.init_state(batch)
+        rows = [[] for _ in group]
+        for t in range(max(f.shape[0] for f in group)):
+            x = np.zeros((batch, INPUT_DIM), np.float32)
+            act = np.zeros(batch, bool)
+            for b, f in enumerate(group):
+                if t < f.shape[0]:
+                    x[b], act[b] = f[t], True
+            state, logits = eb.step_batch(state, x, act,
+                                          np.full(batch, t == 0))
+            logits = np.asarray(logits)
+            for b in np.flatnonzero(act):
+                rows[b].append(logits[b])
+        out += [np.stack(r) for r in rows]
+    return out
+
+
+def _drive(pool, queue, now=0):
+    """Admit queued requests as slots free, tick until everything has
+    resolved; returns the results."""
+    queue, results = list(queue), []
+    while queue or pool.n_active or pool.has_pending:
+        while queue and pool.admit(queue[0], now):
+            queue.pop(0)
+        done, adv = pool.tick(now)
+        results += done
+        now += max(adv, 1)
+    return results
+
+
+def _retire_fewer(pool, feats):
+    return _drive(pool, [StreamRequest(i, 0, f) for i, f in enumerate(feats)])
+
+
+def _retire_readmit(pool, feats):
+    """The first 40 sessions fill the pool and retire together; the
+    queued ones take their slots at the very next boundary, while the
+    retirement gathers are still to be fetched."""
+    results = _retire_fewer(pool, feats)
+    first = [r for r in results if r.req_id < pool.capacity]
+    later = [r for r in results if r.req_id >= pool.capacity]
+    assert later and all(r.admit_step == max(f.finish_step for f in first)
+                         + 1 for r in later)
+    return results
+
+
+def _retire_cancel(pool, feats):
+    """Two sessions, one in each gathered block, are cancelled inside the
+    retirement window: their rows are fetched but never delivered."""
+    for i, f in enumerate(feats):
+        assert pool.admit(StreamRequest(i, 0, f), 0)
+    assert pool.step_chunk(0) == [] and pool.step_chunk(4) == []
+    assert pool.has_pending and pool.n_active == 0
+    for req_id in (3, 35):
+        pool.cancel(req_id)
+    return pool.flush()
+
+
+def _retire_flush(pool, feats):
+    """Streams that have consumed every frame and then finish retire
+    without another dispatch (``_queue_done_retirements``)."""
+    for i, f in enumerate(feats):
+        assert pool.admit_stream(i, 0, f)
+    now = 0
+    while pool.max_chunk_advance():
+        assert pool.step_chunk(now) == []
+        now += 4
+    dispatches = pool.n_dispatches
+    for i in range(len(feats)):
+        pool.finish_stream(i)
+    results = pool.flush()
+    assert pool.n_dispatches == dispatches and not pool.has_pending
+    return results
+
+
+# (driver, capacity, lengths, cancelled ids, retirement gathers): R is 32
+# and a 6-frame session retires at the second 4-frame chunk boundary.
+RETIRE_CASES = {
+    "fewer_than_block": (_retire_fewer, 64, [6] * 5, (), 1),
+    "exactly_block": (_retire_fewer, 64, [6] * 32, (), 1),
+    "more_than_block": (_retire_fewer, 64, [6] * 40 + [10] * 5, (), 3),
+    "readmit_next_boundary": (_retire_readmit, 40, [6] * 40 + [5] * 8, (),
+                              3),
+    "cancel_in_window": (_retire_cancel, 64, [6] * 40, (3, 35), 2),
+    "flush_path": (_retire_flush, 64, [6] * 40, (), 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RETIRE_CASES))
+def test_retirement_gather_serves_bank_rows(engines, case):
+    """Retiring sessions' rows leave the device in blocks of RETIRE_BLOCK
+    gathered slots, the last block padded with a slot already in it.
+    Every served utterance equals the ``step_batch`` oracle bit for bit,
+    cancelled ones are never served, the boundary samples count the
+    gathers and their R x T_pad rows, and every block count runs the one
+    retirement program."""
+    _, eb = engines
+    drive, capacity, lens, cancelled, gathers = RETIRE_CASES[case]
+    feats = [_utterance(900 + i, t) for i, t in enumerate(lens)]
+    obs = PoolObservability()
+    pool = SessionPool(eb, capacity=capacity, max_frames=16,
+                       chunk_frames=4, observability=obs)
+    compiled = eb._retire_rows._cache_size()
+    results = drive(pool, feats)
+
+    assert sorted(r.req_id for r in results) == [
+        i for i in range(len(feats)) if i not in cancelled]
+    refs = _step_batch_oracle(eb, feats, capacity)
+    for r in results:
+        np.testing.assert_array_equal(r.logits, refs[r.req_id])
+
+    def total(key):
+        return (sum(s[key] for s in obs.timeseries.snapshot())
+                + obs.boundary.get(key, 0))
+
+    t_pad = pool._out.shape[1]
+    assert min(RETIRE_BLOCK, capacity) == 32
+    assert total("retire_gathers") == gathers
+    assert total("fetch_rows") == gathers * RETIRE_BLOCK * t_pad
+    assert total("fetch_rows_kept") == sum(r.logits.shape[0]
+                                           for r in results)
+    assert eb._retire_rows._cache_size() - compiled <= 1

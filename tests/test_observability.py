@@ -28,7 +28,7 @@ from repro.serving import (
     Tracer,
     serve_requests,
 )
-from repro.serving.scheduler import SessionPool
+from repro.serving.scheduler import RETIRE_BLOCK, SessionPool
 
 INPUT_DIM, HIDDEN, CLASSES = 20, 32, 11
 GAMMA, M, THETA = 0.75, 4, 0.05
@@ -352,10 +352,11 @@ def test_boundary_counts_match_what_crossed(engine, workload,
                                             stream_partials):
     """The boundary samples count what crossed between host and device:
     one admission wave of ``rb x T_buf`` frame slots holding the real
-    frames, one whole-bank snapshot of ``B x T_pad`` rows per retiring
-    boundary, and of the fetched rows exactly those delivered (each
-    session's rows, and with partials each chunk's rows once more).
-    Work resolved after the last dispatch waits in ``obs.boundary``."""
+    frames, one retirement gather of ``R x T_pad`` rows per retiring
+    boundary (R = RETIRE_BLOCK slots, clipped to the capacity), and of
+    the fetched rows exactly those delivered (each session's rows, and
+    with partials each chunk's rows once more).  Work resolved after the
+    last dispatch waits in ``obs.boundary``."""
     cap, chunk = 3, 4
     feats = workload[:cap]          # 5, 9, 3 frames: each retires alone
     obs = PoolObservability()
@@ -388,8 +389,23 @@ def test_boundary_counts_match_what_crossed(engine, workload,
     # scans of 4, 4 and 1 frames (the 9-frame session sets each length);
     # a partials snapshot holds every slot's rows of one scan:
     chunk_rows = cap * (4 + 4 + 1) if stream_partials else 0
-    assert total("fetch_rows") == len(results) * cap * t_pad + chunk_rows
+    assert total("retire_gathers") == len(results)
+    r = min(RETIRE_BLOCK, cap)
+    assert total("fetch_rows") == len(results) * r * t_pad + chunk_rows
     assert total("fetch_bytes") == total("fetch_rows") * CLASSES * 4
+    # a lone retiree of a 64-slot pool fetches one block of R slots'
+    # rows, not the whole 64-slot bank:
+    big_obs = PoolObservability()
+    big = SessionPool(engine, capacity=64, max_frames=16, chunk_frames=chunk,
+                      observability=big_obs)
+    big.admit(StreamRequest(0, 0, feats[0]), 0)
+    now = 0
+    while big.n_active or big.has_pending:
+        big.tick(now)
+        now += chunk
+    big_rows = (sum(s["fetch_rows"] for s in big_obs.timeseries.snapshot())
+                + big_obs.boundary.get("fetch_rows", 0))
+    assert big_rows == RETIRE_BLOCK * big._out.shape[1]
     for key in ("snapshot_fetch_s", "fetch_wait_s", "fetch_copy_s",
                 "retire_snapshot_s", "admission_upload_s"):
         assert total(key) > 0

@@ -309,3 +309,98 @@ def test_sharded_serving_subprocess_4dev():
     assert data["devices"] == 4
     assert data["max_err"] <= 1e-5
     assert data["collectives"] == 0
+
+
+RETIRE_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json
+    import numpy as np
+    import jax, jax.numpy as jnp
+    from repro.analysis import hlo
+    from repro.models import lstm_am
+    from repro.serving import (BatchedSpartusEngine, EngineConfig,
+                               StreamRequest, serve_requests)
+    from repro.serving.scheduler import RETIRE_BLOCK, SessionPool
+
+    cfg = lstm_am.LSTMAMConfig(input_dim=20, hidden_dim=32, n_layers=2,
+                               n_classes=11)
+    params = lstm_am.cbtd_prune_stacks(
+        lstm_am.init_params(jax.random.key(0), cfg), gamma=0.75, m=4)
+    eb = BatchedSpartusEngine(
+        params, cfg, EngineConfig(theta=0.05, gamma=0.75, m=4,
+                                  capacity_frac=1.0))
+    # 40 sessions retire at one boundary (two blocks, the second padded),
+    # five more at a later one:
+    lens = [6] * 40 + [10] * 5
+    reqs = [StreamRequest(i, 0, np.asarray(
+        jax.random.normal(jax.random.key(800 + i), (t, 20)), np.float32))
+        for i, t in enumerate(lens)]
+    base, _ = serve_requests(eb, reqs, capacity=64, chunk_frames=4)
+    compiled = eb._retire_rows._cache_size()
+    sharded, _ = serve_requests(eb, reqs, capacity=64, chunk_frames=4,
+                                n_devices=4)
+    same_ids = [r.req_id for r in sharded] == [r.req_id for r in base]
+    max_err = max(float(np.max(np.abs(a.logits - b.logits)))
+                  for a, b in zip(sharded, base))
+
+    # each device steps its own 16 slots, so the banked rows are those of
+    # a 16-slot step_batch, bit for bit; the gather must not change them
+    def oracle(feats, batch=16):
+        state, rows = eb.init_state(batch), []
+        for t in range(feats.shape[0]):
+            x = np.zeros((batch, 20), np.float32)
+            x[0] = feats[t]
+            state, logits = eb.step_batch(state, x, np.arange(batch) == 0,
+                                          np.full(batch, t == 0))
+            rows.append(np.asarray(logits)[0])
+        return np.stack(rows)
+
+    exact = all(np.array_equal(r.logits, oracle(reqs[r.req_id].feats))
+                for r in sharded[::4])
+
+    # the retirement program of the slot-sharded bank: every collective
+    # in it moves the [R, T_pad, C] block, none the bank or a shard of it
+    pool = SessionPool(eb, capacity=64, max_frames=16, chunk_frames=4,
+                       n_devices=4)
+    b, t_pad, c = pool._out.shape
+    txt = hlo.compiled_text(eb._retire_rows, pool._out,
+                            jnp.arange(RETIRE_BLOCK, dtype=jnp.int32))
+    colls = hlo.collective_lines(txt)
+    bank = [f"f32[{n},{t_pad},{c}]" for n in (b, b // pool.n_shards)]
+    print(json.dumps({
+        "devices": len(jax.devices()), "n_shards": pool.n_shards,
+        "same_ids": same_ids, "max_err": max_err, "exact": exact,
+        "new_programs":
+        eb._retire_rows._cache_size() - compiled,
+        "collectives": hlo.count_collectives(txt),
+        "bank_collectives": sum(any(s in l for s in bank) for l in colls),
+        "block_collectives": sum(f"f32[{RETIRE_BLOCK},{t_pad},{c}]" in l
+                                 for l in colls)}))
+""")
+
+
+@pytest.mark.slow
+def test_sharded_retirement_gather_subprocess_4dev():
+    """4 emulated host devices: through the retirement gather the
+    slot-sharded pool serves what it banked bit for bit (the ``step_batch``
+    oracle at its 16 slots per device) and matches the unsharded pool at
+    1e-5, compiles one retirement program for the pool, and that program
+    moves no copy of the bank between devices (GSPMD's masked local
+    gather: at most a reduction of the gathered block)."""
+    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, "-c", RETIRE_SCRIPT],
+        capture_output=True, text=True, timeout=900,
+        env={"PYTHONPATH": os.path.join(repo_root, "src"),
+             "PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu"},
+        cwd=repo_root,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    data = json.loads(out.stdout.strip().splitlines()[-1])
+    assert data["devices"] == 4 and data["n_shards"] == 4
+    assert data["same_ids"] and data["max_err"] <= 1e-5
+    assert data["exact"]
+    assert data["new_programs"] == 1
+    assert data["bank_collectives"] == 0
+    assert data["collectives"] == data["block_collectives"]
