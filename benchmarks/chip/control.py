@@ -59,19 +59,19 @@ def plant(fault: str) -> None:
 
 
 def readings(cell: run.Cell, seed: int) -> dict:
-    cfg, mix = cell.config, cell.mix
-    params, utts, rng = run.make_inputs(cfg, mix, seed)
+    cfg, model = cell.config, cell.model
+    params, utts, rng = run.make_inputs(cell, seed)
     ids = traffic.sample_ids(np.array([u.shape[0] for u in utts]),
-                             int(mix["sample"]), rng)
+                             int(cell.mix["sample"]), rng)
     sample = [utts[i] for i in ids]
     t = time.perf_counter()
-    ref, st = reference.reference_logits(params, sample, cfg,
+    ref, st = reference.reference_logits(model, params, sample, cfg,
                                          precision=cfg["matmul_precision"])
     t_ref = time.perf_counter() - t
     out = {"seed": seed, "reference_s": t_ref, "reference_stats": st}
     for name, dtype, prec in (("control_bfloat16", "bfloat16", "default"),
                               ("float32_highest", "float32", "highest")):
-        got, _ = reference.reference_logits(params, sample, cfg,
+        got, _ = reference.reference_logits(model, params, sample, cfg,
                                             dtype=dtype, precision=prec)
         nums = compare.numbers(got, ref, st["h_absmax"])
         out[name] = {**nums,

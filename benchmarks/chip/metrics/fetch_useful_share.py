@@ -1,7 +1,10 @@
 """Pool (serving/scheduler.py): share of the logits rows fetched to the
 host in the window that were delivered, in percent (the boundary
-samples' ``fetch_rows_kept`` over ``fetch_rows``; a whole-bank
-retirement snapshot fetches every slot's every row)."""
+samples' ``fetch_rows_kept`` over ``fetch_rows``).  A retirement gather
+fetches R = 32 slots' every row (R x T_pad), of which each retiree keeps
+the rows up to its cursor and a padded block's spare slots keep none; a
+chunk snapshot, where a mix takes partial logits, keeps each partial's
+new rows."""
 
 
 def read(run):

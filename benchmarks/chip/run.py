@@ -1,20 +1,22 @@
-"""Chip benchmark of the DeltaLSTM streaming server.
+"""Chip benchmark of the Spartus streaming server.
 
     python benchmarks/chip/run.py --workload <cell> --seed <n> \
         --seconds <s> --trace <0|1>
 
 Runs one cell of ``BENCHMARK.json`` (a configuration under a traffic mix)
 in this process, on the first chip: weights and traffic from the seed,
-the program's ``AsyncSpartusServer`` over ``BatchedSpartusEngine``
-(``watchdog=False``) driven in-process by ``traffic.py``, a measured
-window of ``--seconds``, then the served logits of a seeded sample of
-finished requests against the plain reference (``compare.py``).
+the program's ``AsyncSpartusServer`` (``watchdog=False``) over the engine
+of the configuration's model family, driven in-process by ``traffic.py``,
+a measured window of ``--seconds``, then the served logits of a seeded
+sample of finished requests against the plain reference (``compare.py``).
 
 Everything a cell needs is found by name: ``configs/<config>.json``,
-``traffic/<mix>.json`` and, for each per-layer metric, ``metrics/<name>.py``
-(a ``read(run)`` function over the run's collected sources; ``None`` when
-it finds nothing to read).  Peaks are in ``peaks.json``, keyed by
-``device_kind``.
+the model family that the configuration's ``"model"`` names,
+``models/<family>.py`` (its weights, plain reference, weight count and
+engine: see ``models/delta_lstm.py``), ``traffic/<mix>.json`` and, for
+each per-layer metric, ``metrics/<name>.py`` (a ``read(run)`` function
+over the run's collected sources; ``None`` when it finds nothing to
+read).  Peaks are in ``peaks.json``, keyed by ``device_kind``.
 
 With ``--trace 0`` the result's metrics are the cell's end-to-end metrics;
 with ``--trace 1`` the profiler records the window and the metrics are the
@@ -47,6 +49,7 @@ import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 from pathlib import Path  # noqa: E402
+from types import ModuleType  # noqa: E402
 from typing import Any, Callable, Dict, List, Optional  # noqa: E402
 
 import numpy as np  # noqa: E402
@@ -77,13 +80,17 @@ class CompileInWindow(RuntimeError):
 class Cell:
     name: str
     config: Dict[str, Any]
+    model: ModuleType
     mix: Dict[str, Any]
     chips: int
     end_to_end: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
 
 
-def load_cell(name: str, bench_path: Path = ROOT / "BENCHMARK.json") -> Cell:
+def load_cell(name: str, bench_path: Path = ROOT / "BENCHMARK.json",
+              models: Path = HERE / "models") -> Cell:
+    """The cell ``name`` of ``bench_path``; configuration files are found
+    from the directory that holds it, model families under ``models``."""
     bench = json.loads(bench_path.read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -96,20 +103,33 @@ def load_cell(name: str, bench_path: Path = ROOT / "BENCHMARK.json") -> Cell:
     per_layer = [m for m in bench["per_layer"]
                  if (name in m["workloads"] if "workloads" in m
                      else m["moves"] in moved)]
-    return Cell(name=name,
-                config=json.loads((ROOT / cfg_file).read_text()),
+    config = json.loads((bench_path.parent / cfg_file).read_text())
+    return Cell(name=name, config=config,
+                model=load_model(config["model"], models),
                 mix=json.loads((HERE / "traffic" / f"{w['traffic']}.json")
                                .read_text()),
                 chips=int(w["chips"]), end_to_end=e2e, per_layer=per_layer)
 
 
-def load_reader(metric: str) -> Callable[[Any], Optional[float]]:
-    path = HERE / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_"), path)
+def _load_file(path: Path, module_name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(module_name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(metric: str) -> Callable[[Any], Optional[float]]:
+    return _load_file(HERE / "metrics" / f"{metric}.py",
+                      "bench_metric_" + metric.replace(".", "_")).read
+
+
+def load_model(name: str, root: Path = HERE / "models") -> ModuleType:
+    """The family module ``<root>/<name>.py``: ``layer_dims``,
+    ``make_params``, ``forward``, ``weights_held`` and ``engine``."""
+    path = root / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"model family {name!r}: no {path}")
+    return _load_file(path, "bench_model_" + name.replace(".", "_"))
 
 
 def load_peaks(kind: str) -> Dict[str, float]:
@@ -231,29 +251,32 @@ class _Hooks(traffic.Hooks):
             self._ann = None
 
 
-def _program_sparsity(server, cfg) -> Dict[str, List[float]]:
+def _program_sparsity(server, model, cfg) -> Dict[str, List[float]]:
     """Per-layer temporal sparsity and active columns from the program's
     own device counters (over every frame the pool served)."""
     tel = server.pool.state.telemetry
     nnz = np.asarray(tel.nnz_sum, np.float64).sum(axis=1)
     steps = np.asarray(tel.steps, np.float64).sum(axis=1)
-    cols = np.array([d + h for d, h in reference.layer_dims(cfg)])
+    cols = np.array([d + h for d, h in model.layer_dims(cfg)])
     act = nnz / np.maximum(steps, 1)
     return {"temporal_sparsity": (1 - act / cols).tolist(),
             "active_columns": act.tolist()}
 
 
-def make_inputs(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int):
+def make_inputs(cell: Cell, seed: int):
     """Weights (on the device) and the traffic's utterances from the seed,
     and the generator the traffic goes on to draw its order from."""
     import jax
 
     words = reference.seed_words(seed, 3)
-    params = jax.block_until_ready(reference.make_params(words[0], cfg))
+    params = jax.block_until_ready(
+        cell.model.make_params(words[0], cell.config))
     rng = np.random.default_rng(words[2])
-    lengths = traffic.stratified_lengths(mix["length"], mix["n_distinct"])
+    lengths = traffic.stratified_lengths(cell.mix["length"],
+                                         cell.mix["n_distinct"])
     lengths = lengths[rng.permutation(len(lengths))]
-    return params, speech.utterances(words[1], lengths, mix["speech"]), rng
+    return params, speech.utterances(words[1], lengths,
+                                     cell.mix["speech"]), rng
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
@@ -279,21 +302,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         log(f"compile cache {use_compile_cache()}")
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     counter = CompileCounter()
-    cfg, mix = cell.config, cell.mix
+    cfg, mix, model = cell.config, cell.mix, cell.model
     t0 = time.perf_counter()
-    params, utts, rng = make_inputs(cfg, mix, seed)
+    params, utts, rng = make_inputs(cell, seed)
     t_inputs = time.perf_counter()
-    from repro.models.lstm_am import LSTMAMConfig
-    from repro.serving import (AsyncSpartusServer, BatchedSpartusEngine,
-                               EngineConfig, PoolObservability)
+    from repro.serving import AsyncSpartusServer, PoolObservability
 
-    engine = BatchedSpartusEngine(
-        params, LSTMAMConfig(input_dim=cfg["input_dim"],
-                             hidden_dim=cfg["hidden_dim"],
-                             n_layers=cfg["n_layers"],
-                             n_classes=cfg["n_classes"]),
-        EngineConfig(theta=cfg["theta"], gamma=cfg["gamma"], m=cfg["m"],
-                     capacity_frac=cfg["capacity_frac"]))
+    engine = model.engine(params, cfg)
     t_pack = time.perf_counter()
     lengths = np.array([u.shape[0] for u in utts])
     log(f"set-up before serving: weights and traffic data "
@@ -338,7 +353,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     peak_bytes = int(stats.get("peak_bytes_in_use", 0))
     log(f"device memory: peak {peak_bytes} bytes, in use "
         f"{int(stats.get('bytes_in_use', 0))} bytes")
-    prog_sp = _program_sparsity(made[-1], cfg)
+    prog_sp = _program_sparsity(made[-1], model, cfg)
     log(f"program counters: temporal sparsity per layer "
         f"{prog_sp['temporal_sparsity']}, active columns per layer-step "
         f"{prog_sp['active_columns']}")
@@ -363,7 +378,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     t_ref = time.perf_counter()
     ids = sorted(out.sample)
     ref, ref_sp = reference.reference_logits(
-        params, [utts[i] for i in ids], cfg,
+        model, params, [utts[i] for i in ids], cfg,
         precision=cfg["matmul_precision"])
     nums = compare.numbers([out.sample[i] for i in ids], ref,
                            ref_sp["h_absmax"])
@@ -378,7 +393,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     shape = traffic.server_shape(mix)
     view = RunView(trace=red, timeseries=series,
                    frames_per_chunk=shape["capacity"] * shape["chunk_frames"],
-                   ops_per_frame=work.ops_per_frame(cfg), peaks=peaks)
+                   ops_per_frame=work.ops_per_frame(model, cfg), peaks=peaks)
     metrics: Dict[str, Dict[str, Any]] = {}
     if trace:
         for m in cell.per_layer:

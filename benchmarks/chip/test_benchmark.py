@@ -6,6 +6,7 @@ lower-precision control is held to each configuration's limits.
 """
 from __future__ import annotations
 
+import hashlib
 import json
 import sys
 from pathlib import Path
@@ -28,6 +29,8 @@ import work  # noqa: E402
 BENCH = json.loads((run.ROOT / "BENCHMARK.json").read_text())
 CONFIGS = {c["name"]: json.loads((run.ROOT / c["file"]).read_text())
            for c in BENCH["configs"]}
+LSTM = run.load_model("delta_lstm")
+FAMILY = ("layer_dims", "make_params", "forward", "weights_held", "engine")
 
 
 # -- work per frame against hand counts --------------------------------------
@@ -40,12 +43,12 @@ CONFIGS = {c["name"]: json.loads((run.ROOT / c["file"]).read_text())
     ("lstm_3l_512h", 2 * 626_560),
 ])
 def test_work_matches_hand_counts(name, ops):
-    assert work.ops_per_frame(CONFIGS[name]) == ops
+    assert work.ops_per_frame(LSTM, CONFIGS[name]) == ops
 
 
 def test_reference_weights_prune_to_the_column_balance():
     cfg = dict(CONFIGS["lstm_3l_512h"], hidden_dim=64, m=8, input_dim=20)
-    params = reference.make_params(7, cfg)
+    params = LSTM.make_params(7, cfg)
     for lp in params["lstm"]:
         w = np.concatenate([lp["w_x"], lp["w_h"]], axis=1)
         s = w.shape[0] // cfg["m"]
@@ -54,6 +57,31 @@ def test_reference_weights_prune_to_the_column_balance():
         step, _ = reference.grid(cfg["weights"]["lstm_scale"] /
                                  np.sqrt(cfg["hidden_dim"]))
         assert np.all(np.abs(w / step - np.rint(w / step)) == 0)
+
+
+def _digest(arrays) -> str:
+    h = hashlib.sha256()
+    for a in arrays:
+        h.update(np.ascontiguousarray(np.asarray(a)).tobytes())
+    return h.hexdigest()
+
+
+def test_family_module_reproduces_the_pinned_digests():
+    """Weights and reference logits of the DeltaLSTM family, pinned on the
+    CPU before its code moved into ``models/delta_lstm.py``: the same seed
+    gives the same numbers bit for bit."""
+    import jax
+
+    cfg = dict(CONFIGS["lstm_2l_1024h"], hidden_dim=64, m=8)
+    params = LSTM.make_params(7, cfg)
+    assert _digest(jax.tree_util.tree_leaves(params)) == (
+        "478e46e37bc388130d61fde52ebc4a0a8baf63f47c02eae50497b9f5bfa30bb5")
+    mix = json.loads((HERE / "traffic" / "offline.json").read_text())
+    utts = speech.utterances(11, np.array([40, 60, 80]), mix["speech"])
+    logits, _ = reference.reference_logits(LSTM, params, utts, cfg,
+                                           precision="highest")
+    assert _digest(logits) == (
+        "8d4b197485366f5af7654fe56713d4f3d2b862c4caa7afb060f3deb123e61a0c")
 
 
 # -- the trace reduction on a small recorded trace ---------------------------
@@ -119,6 +147,53 @@ def test_every_cell_finds_its_files(cell):
     assert c.per_layer
     for m in c.per_layer:
         assert callable(run.load_reader(m["name"]))
+    assert c.model.__name__ == "bench_model_" + c.config["model"]
+    for fn in FAMILY:
+        assert callable(getattr(c.model, fn)), fn
+
+
+def _bench_with_family(tmp_path: Path, family: str) -> Path:
+    """A benchmark in ``tmp_path`` that adds one configuration of the
+    model family ``family`` and its offline cell to ``BENCHMARK.json``:
+    new entries and new files only."""
+    (tmp_path / "configs").mkdir()
+    cfg = dict(CONFIGS["lstm_2l_1024h"], name="added_2l", model=family)
+    (tmp_path / "configs" / "added_2l.json").write_text(json.dumps(cfg))
+    bench = json.loads(json.dumps(BENCH))
+    bench["configs"].append({"name": "added_2l", "source": "test",
+                             "file": "configs/added_2l.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "added_2l.offline",
+                               "config": "added_2l", "traffic": "offline",
+                               "chips": 1, "why": "test"})
+    for m in bench["per_layer"]:
+        m["workloads"].append("added_2l.offline")
+    path = tmp_path / "BENCHMARK.json"
+    path.write_text(json.dumps(bench))
+    return path
+
+
+def test_a_family_added_as_files_only_runs_correct(tmp_path):
+    models = tmp_path / "models"
+    models.mkdir()
+    (models / "thin_lstm.py").write_text(
+        "import run\n"
+        "_base = run.load_model('delta_lstm')\n"
+        "layer_dims, make_params, forward, weights_held, engine = (\n"
+        "    _base.layer_dims, _base.make_params, _base.forward,\n"
+        "    _base.weights_held, _base.engine)\n")
+    cell = run.load_cell("added_2l.offline",
+                         _bench_with_family(tmp_path, "thin_lstm"), models)
+    assert Path(cell.model.__file__) == models / "thin_lstm.py"
+    res = _run_tiny(cell=cell)
+    assert res["correct"], res["checks"]
+    assert res["failed"] == 0 and res["attempted"] > 0
+
+
+def test_unknown_model_family_is_refused(tmp_path):
+    bench = _bench_with_family(tmp_path, "no_such_family")
+    with pytest.raises(FileNotFoundError, match="no_such_family.py"):
+        run.load_cell("added_2l.offline", bench, tmp_path / "models")
 
 
 def test_unknown_device_kind_is_refused():
@@ -142,10 +217,11 @@ def test_lower_precision_control_fails_the_limits(name):
     lengths = traffic.stratified_lengths(
         dict(mix["length"], median=300, max=800), 4)
     utts = speech.utterances(11, lengths, mix["speech"])
-    params = reference.make_params(5, cfg)
+    model = run.load_model(cfg["model"])
+    params = model.make_params(5, cfg)
     ref, st = reference.reference_logits(
-        params, utts, cfg, precision=cfg["matmul_precision"])
-    ctrl, _ = reference.reference_logits(params, utts, cfg,
+        model, params, utts, cfg, precision=cfg["matmul_precision"])
+    ctrl, _ = reference.reference_logits(model, params, utts, cfg,
                                          dtype="bfloat16",
                                          precision="default")
     assert min(st["h_absmax"]) > 0
@@ -160,9 +236,8 @@ def test_lower_precision_control_fails_the_limits(name):
 
 # -- the whole run on the CPU, sound and with the served path broken ---------
 
-def _tiny_cell() -> run.Cell:
-    """The 2x1024 offline cell at hidden 64 and a pool of 8."""
-    cell = run.load_cell("lstm_2l_1024h.offline")
+def _tiny_cell(cell: run.Cell) -> run.Cell:
+    """A 2x1024 offline cell at hidden 64 and a pool of 8."""
     cell.config = dict(cell.config, hidden_dim=64, m=8)
     cell.mix = dict(cell.mix, capacity=8, chunk_frames=8, max_frames=128,
                     n_distinct=16, ramp_completions=8, sample=4)
@@ -171,8 +246,9 @@ def _tiny_cell() -> run.Cell:
     return cell
 
 
-def _run_tiny(trace: bool = False):
-    return run.run_cell(_tiny_cell(), 2**31 + 99, 1.0, trace,
+def _run_tiny(trace: bool = False, cell: run.Cell = None):
+    cell = cell or run.load_cell("lstm_2l_1024h.offline")
+    return run.run_cell(_tiny_cell(cell), 2**31 + 99, 1.0, trace,
                         require_chip=False, use_cache=False,
                         log=lambda _m: None)
 
