@@ -93,6 +93,20 @@ def _engine(spmv_path: str = "auto", use_pallas: bool = False,
     return BatchedSpartusEngine(params, cfg, ecfg)
 
 
+@functools.lru_cache(maxsize=None)
+def _gru_engine():
+    """The DeltaGRU build of the same test-scale model: dense weights
+    (gamma 0), so every layer takes the dense-mirror route."""
+    from repro.models import lstm_am
+    from repro.serving import BatchedSpartusEngine, EngineConfig
+
+    cfg = lstm_am.LSTMAMConfig(input_dim=INPUT_DIM, hidden_dim=HIDDEN,
+                               n_layers=2, n_classes=CLASSES, cell="gru")
+    params = lstm_am.init_params(jax.random.key(0), cfg)
+    ecfg = EngineConfig(theta=THETA, gamma=0.0, m=M, capacity_frac=1.0)
+    return BatchedSpartusEngine(params, cfg, ecfg)
+
+
 def _feats(n: int = 4) -> List[np.ndarray]:
     return [np.asarray(
         jax.random.normal(jax.random.key(800 + i), (t, INPUT_DIM)),
@@ -320,6 +334,9 @@ def build_cases(*, include_sharded: Optional[bool] = None) -> List[ContractCase]
                      op_budget_override={"sort": 0}),
         ContractCase("step_chunk/scatter", "step_chunk",
                      lambda: _built_step_chunk("scatter")),
+        ContractCase("step_chunk/gru-dense-mirror", "step_chunk",
+                     lambda: built_pool_chunk(_gru_engine(), _feats()),
+                     op_budget_override={"sort": 0}),
         ContractCase("step_chunk/post-restore", "step_chunk",
                      _built_step_chunk_restored,
                      op_budget_override={"sort": 0}),

@@ -16,6 +16,12 @@ memory decomposition exact):
 
 Delta memories: M_r, M_u accumulate both matmul streams; the candidate gate
 needs the recurrent stream kept separate (M_hc) because of the r_t gating.
+
+The session pool serves this cell (serving/engine.py ``pack_gru_layer``):
+``stacked_weight_matrix`` lays the four accumulators out as one
+[4H, D+H] matrix, so the pool's delta encoder and SpMV routes run on the
+concatenated ``[x, h]`` exactly as for the DeltaLSTM, and
+``initial_delta_memories`` gives the accumulators' values at t = 0.
 """
 from __future__ import annotations
 
@@ -136,3 +142,28 @@ def delta_gru_layer(
 
     state, (hs, nnz_dx, nnz_dh) = jax.lax.scan(step, state, xs)
     return hs, state, {"nnz_dx": nnz_dx, "nnz_dh": nnz_dh}
+
+
+def stacked_weight_matrix(params: Params) -> jax.Array:
+    """The [4H, D+H] matrix whose product with the concatenated delta
+    ``[Δx, Δh]`` updates the four delta memories in one matvec.  Row
+    blocks (r, u, xc, hc): ``[W_xr W_hr]``, ``[W_xu W_hu]``, ``[W_xc 0]``
+    and ``[0 W_hc]`` — the xc block is zero on the h-columns and the hc
+    block on the x-columns, so a quarter of a dense [4H, D+H] product
+    multiplies structural zeros."""
+    hdim, d = params["w_h"].shape[1], params["w_x"].shape[1]
+    w_x, w_h = params["w_x"], params["w_h"]
+    top = jnp.concatenate([w_x[:2 * hdim], w_h[:2 * hdim]], axis=1)
+    xc = jnp.concatenate(
+        [w_x[2 * hdim:], jnp.zeros((hdim, hdim), w_x.dtype)], axis=1)
+    hc = jnp.concatenate(
+        [jnp.zeros((hdim, d), w_h.dtype), w_h[2 * hdim:]], axis=1)
+    return jnp.concatenate([top, xc, hc], axis=0)
+
+
+def initial_delta_memories(params: Params) -> jax.Array:
+    """``[4, H]`` delta memories at t = 0, in the stack's row order:
+    (b_xr + b_hr, b_xu + b_hu, b_xc, b_hc) — what ``init_delta_gru_state``
+    puts in ``m_r, m_u, m_xc, m_hc``."""
+    b_x, b_h = params["b_x"], params["b_h"]
+    return jnp.stack([b_x[0] + b_h[0], b_x[1] + b_h[1], b_x[2], b_h[2]])

@@ -238,6 +238,13 @@ def lstm_pointwise_batch(
     return jax.vmap(_ref.lstm_pointwise_ref)(dm, c)
 
 
+@jax.jit
+def gru_pointwise_batch(dm: jax.Array, h_prev: jax.Array) -> jax.Array:
+    """Batched DeltaGRU gate math (XLA only; no Pallas kernel).  dm:
+    [B, 4, H], h_prev: [B, H] -> h [B, H]."""
+    return jax.vmap(_ref.gru_pointwise_ref)(dm, h_prev)
+
+
 @hotpath_contract("gather_frames", op_budget={"gather": 1})
 def gather_frames(frames: jax.Array, cursor: jax.Array) -> jax.Array:
     """Gather each slot's current frame from its device-resident buffer.

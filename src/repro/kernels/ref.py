@@ -49,6 +49,15 @@ def lstm_pointwise_ref(
     return h, c_new
 
 
+def gru_pointwise_ref(dm: jax.Array, h_prev: jax.Array) -> jax.Array:
+    """DeltaGRU post-MxV math: dm [4, H] (M_r, M_u, M_xc, M_hc), h_prev
+    [H] -> h.  The reset gate scales the recurrent candidate stream."""
+    r = jax.nn.sigmoid(dm[0])
+    u = jax.nn.sigmoid(dm[1])
+    c = jnp.tanh(dm[2] + r * dm[3])
+    return (1.0 - u) * c + u * h_prev
+
+
 def stsp_spmv_ref(
     val: jax.Array,      # [Q, M, BLEN] CBCSC values (0-padded)
     lidx: jax.Array,     # [Q, M, BLEN] local indices

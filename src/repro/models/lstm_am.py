@@ -19,6 +19,7 @@ from repro.core import (
     delta_lstm_layer,
     fake_quant_act_ste,
     fake_quant_ste,
+    init_gru_params,
     init_lstm_params,
     lstm_layer,
     stacked_weight_matrix,
@@ -37,19 +38,26 @@ class LSTMAMConfig:
     delta: bool = False          # DeltaLSTM (retrain phase) vs LSTM (pretrain)
     theta: float = 0.0           # delta threshold
     quant: QuantConfig = QuantConfig(enabled=False)
+    # recurrent cell the serving pack builds: "lstm" (params["lstm"]) or
+    # "gru" (params["gru"], core/delta_gru.py layers); training is LSTM-only
+    cell: str = "lstm"
 
     @property
     def name(self) -> str:
-        kind = "DeltaLSTM" if self.delta else "LSTM"
+        kind = "LSTM" if self.cell == "lstm" else self.cell.upper()
+        kind = f"Delta{kind}" if self.delta else kind
         return f"{kind}-{self.n_layers}L-{self.hidden_dim}H-UNI"
 
 
 def init_params(key: jax.Array, cfg: LSTMAMConfig, dtype=jnp.float32) -> Params:
+    """Recurrent layers under ``params[cfg.cell]`` (LSTM or DeltaGRU
+    layers), then the FCL and logit layer."""
     keys = jax.random.split(key, cfg.n_layers + 2)
+    init_layer = init_gru_params if cfg.cell == "gru" else init_lstm_params
     layers = []
     d = cfg.input_dim
     for i in range(cfg.n_layers):
-        layers.append(init_lstm_params(keys[i], d, cfg.hidden_dim, dtype))
+        layers.append(init_layer(keys[i], d, cfg.hidden_dim, dtype))
         d = cfg.hidden_dim
     bound = 1.0 / jnp.sqrt(cfg.hidden_dim)
     fcl = {
@@ -64,7 +72,7 @@ def init_params(key: jax.Array, cfg: LSTMAMConfig, dtype=jnp.float32) -> Params:
         ),
         "b": jnp.zeros((cfg.n_classes,), dtype),
     }
-    return {"lstm": layers, "fcl": fcl, "logit": logit}
+    return {cfg.cell: layers, "fcl": fcl, "logit": logit}
 
 
 def n_params(params: Params) -> int:

@@ -62,6 +62,7 @@ from repro.serving.faults import (
     error_payload,
 )
 from repro.serving.batched_engine import (
+    BatchedGRULayerState,
     BatchedLayerState,
     BatchedSpartusEngine,
     PoolState,

@@ -11,9 +11,14 @@ a fixed-capacity pool is stored as stacked device slabs
     CTRL  select_active_columns_batch   (scatter route; the dense-mirror
     MACs  stsp_spmv_batch                route fuses both into
                                          delta_spmv_dense_topk_batch)
-    HPE   lstm_pointwise_batch
+    HPE   lstm_pointwise_batch | gru_pointwise_batch
 
-for every layer, plus the FCL/logit head, inside one jit.  An `active`
+for every layer, plus the FCL/logit head, inside one jit.  The cell kind
+is the pack's (`PackedLayer.cell`): a DeltaLSTM layer carries
+`BatchedLayerState` (with its cell state ``c``), a DeltaGRU layer
+`BatchedGRULayerState` (no cell state; ``dm`` holds M_r, M_u, M_xc,
+M_hc), and only the `gates` / `state_update` steps differ, chosen per
+layer at trace time.  An `active`
 mask freezes idle slots (their state is carried through unchanged), and
 a `reset` mask re-initialises slots at admission time so attach/detach
 never recompiles.  Telemetry is accumulated on device (telemetry.py) and
@@ -50,7 +55,7 @@ tests/test_serving_pool.py).
 """
 from __future__ import annotations
 
-from typing import Any, Dict, NamedTuple, Tuple
+from typing import Any, Dict, NamedTuple, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -73,26 +78,40 @@ class BatchedLayerState(NamedTuple):
     dm: jax.Array     # [B, 4H] delta memories
 
 
+class BatchedGRULayerState(NamedTuple):
+    """Stacked per-slot state of one DeltaGRU layer."""
+
+    s_hat: jax.Array  # [B, D+H] concatenated x̂ / ĥ references
+    h: jax.Array      # [B, H] hidden state
+    dm: jax.Array     # [B, 4H] delta memories (M_r, M_u, M_xc, M_hc)
+
+
+LayerStates = Union[BatchedLayerState, BatchedGRULayerState]
+
+#: per-slot state type of each cell kind (`PackedLayer.cell`)
+STATE_TYPES = {"lstm": BatchedLayerState, "gru": BatchedGRULayerState}
+
+
 class PoolState(NamedTuple):
     """Full device-resident state of the session pool."""
 
-    layers: Tuple[BatchedLayerState, ...]
+    layers: Tuple[LayerStates, ...]
     telemetry: tele.TelemetryState
     cursor: jax.Array  # [B] int32 per-slot frame cursor into the pool's
     #                    device-resident feature buffers (step_frames);
     #                    carried through unchanged by the legacy step_batch
 
 
-def _fresh_layer_state(layer: PackedLayer, n_slots: int) -> BatchedLayerState:
+def _fresh_layer_state(layer: PackedLayer, n_slots: int) -> LayerStates:
     d, h = layer.input_dim, layer.hidden_dim
     dm0 = jnp.broadcast_to(layer.bias.astype(jnp.float32).reshape(-1),
                            (n_slots, 4 * h))
-    return BatchedLayerState(
-        s_hat=jnp.zeros((n_slots, d + h), jnp.float32),
-        c=jnp.zeros((n_slots, h), jnp.float32),
-        h=jnp.zeros((n_slots, h), jnp.float32),
-        dm=dm0,
-    )
+    fresh = {"s_hat": jnp.zeros((n_slots, d + h), jnp.float32),
+             "c": jnp.zeros((n_slots, h), jnp.float32),
+             "h": jnp.zeros((n_slots, h), jnp.float32),
+             "dm": dm0}
+    state_type = STATE_TYPES[layer.cell]
+    return state_type(*(fresh[f] for f in state_type._fields))
 
 
 @hotpath_contract("retire_rows", forbid_ops=("transpose",))
@@ -167,12 +186,8 @@ class BatchedSpartusEngine(PackedSpartusModel):
         layers = []
         for layer, st in zip(self.layers, state.layers):
             fresh = _fresh_layer_state(layer, n_slots)
-            layers.append(BatchedLayerState(
-                s_hat=jnp.where(rm, fresh.s_hat, st.s_hat),
-                c=jnp.where(rm, fresh.c, st.c),
-                h=jnp.where(rm, fresh.h, st.h),
-                dm=jnp.where(rm, fresh.dm, st.dm),
-            ))
+            layers.append(type(st)(*(
+                jnp.where(rm, f, old) for f, old in zip(fresh, st))))
         cursor = jnp.where(reset, 0, state.cursor) if reset_cursor \
             else state.cursor
         return PoolState(tuple(layers), state.telemetry, cursor)
@@ -228,18 +243,20 @@ class BatchedSpartusEngine(PackedSpartusModel):
                     )
                 dm = st.dm + y.astype(st.dm.dtype)
             with jax.named_scope("gates"):
-                h_new, c_new = ops.lstm_pointwise_batch(
-                    dm.reshape(n_slots, 4, layer.hidden_dim), st.c,
-                    use_pallas=cfg.use_pallas,
-                )
+                dm4 = dm.reshape(n_slots, 4, layer.hidden_dim)
+                if layer.cell == "gru":
+                    h_new = ops.gru_pointwise_batch(dm4, st.h)
+                    stepped = {"s_hat": s_hat, "h": h_new, "dm": dm}
+                else:
+                    h_new, c_new = ops.lstm_pointwise_batch(
+                        dm4, st.c, use_pallas=cfg.use_pallas)
+                    stepped = {"s_hat": s_hat, "c": c_new, "h": h_new,
+                               "dm": dm}
             with jax.named_scope("state_update"):
                 am = active[:, None]
-                new_layers.append(BatchedLayerState(
-                    s_hat=jnp.where(am, s_hat, st.s_hat),
-                    c=jnp.where(am, c_new, st.c),
-                    h=jnp.where(am, h_new, st.h),
-                    dm=jnp.where(am, dm, st.dm),
-                ))
+                new_layers.append(type(st)(*(
+                    jnp.where(am, stepped[f], old)
+                    for f, old in zip(st._fields, st))))
             nnz_layers.append(nnz)
             dropped_layers.append(dropped)
             h = h_new

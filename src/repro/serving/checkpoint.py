@@ -5,8 +5,9 @@ What a session *is*, for checkpoint purposes, is exactly the state the
 chunked tick loop threads through `engine.step_chunk` plus the host-side
 bookkeeping the scheduler keeps per slot:
 
-  * per-layer recurrent slabs — ``s_hat`` (delta references), ``c``,
-    ``h``, ``dm`` (delta memories) rows of each `BatchedLayerState`;
+  * per-layer recurrent slabs — the rows of each layer state's fields:
+    ``s_hat`` (delta references), ``c`` (DeltaLSTM only), ``h``, ``dm``
+    (delta memories);
   * the per-slot telemetry columns (sparsity accumulators);
   * the device frame cursor and the frames received so far (device
     feature buffer row, with any *staged-but-not-yet-uploaded* host
@@ -49,7 +50,7 @@ import numpy as np
 
 from repro.serving import sharding as shardlib
 from repro.serving import telemetry as tele
-from repro.serving.batched_engine import BatchedLayerState, PoolState
+from repro.serving.batched_engine import STATE_TYPES, PoolState
 from repro.training.checkpoint import CheckpointManager
 
 if TYPE_CHECKING:  # import cycle: scheduler imports this module's consumers
@@ -57,8 +58,6 @@ if TYPE_CHECKING:  # import cycle: scheduler imports this module's consumers
 
 FORMAT = "spartus-pool"
 VERSION = 1
-
-_LAYER_FIELDS = ("s_hat", "c", "h", "dm")
 
 
 # -- snapshot containers ------------------------------------------------------
@@ -68,7 +67,9 @@ _LAYER_FIELDS = ("s_hat", "c", "h", "dm")
 class SessionSnapshot:
     """One session's full state: JSON-able ``meta`` + named host arrays.
 
-    Array keys: ``layer{i}/{s_hat,c,h,dm}``, ``telemetry`` ``[3, L]``
+    Array keys: ``layer{i}/<field>`` for each field of the layer's state
+    type (``s_hat, c, h, dm`` for DeltaLSTM, ``s_hat, h, dm`` for
+    DeltaGRU), ``telemetry`` ``[3, L]``
     (nnz_sum / overflow_steps / steps columns), ``frames`` ``[n_recv, D]``
     and ``rows`` ``[cursor, n_classes]`` (the banked logits)."""
 
@@ -98,11 +99,13 @@ def engine_fingerprint(engine) -> Dict[str, Any]:
     The quantization entry keeps a quantized pool from restoring an fp32
     pool's sessions (and vice versa): the recurrent state evolves on a
     different numeric grid, so resuming across formats would silently
-    diverge rather than fail."""
+    diverge rather than fail.  The cell entry keeps a DeltaLSTM pool from
+    restoring a DeltaGRU pool's sessions and the reverse."""
     from repro.serving.engine import active_quant
 
     quant = active_quant(engine.cfg)
     return {
+        "cell": engine.cell,
         "input_dim": int(engine.input_dim),
         "n_classes": int(engine.n_classes),
         "layers": [[int(l.input_dim), int(l.hidden_dim)]
@@ -122,11 +125,19 @@ def _fp_key(fp: Dict[str, Any]) -> str:
 def _check_engine(pool: "SessionPool", meta: Dict[str, Any]) -> None:
     have = engine_fingerprint(pool.engine)
     want = meta.get("engine")
+    if want is not None and "cell" not in want:
+        want = {**want, "cell": "lstm"}   # written before the GRU cell
     if want is None or _fp_key(have) != _fp_key(want):
         raise ValueError(
             f"checkpoint engine fingerprint {want} does not match the "
-            f"pool's engine {have}; restore requires the same model "
+            f"pool's engine {have}; restore requires the same cell, model "
             f"shapes and sparsity config (theta/gamma)")
+
+
+def _layer_rows(state: PoolState, k: int):
+    """Slot ``k``'s row of every field of every layer state, by name."""
+    return tuple({f: getattr(st, f)[k] for f in st._fields}
+                 for st in state.layers)
 
 
 # -- session snapshot ---------------------------------------------------------
@@ -185,7 +196,7 @@ def _snap(pool: "SessionPool", sess, k: int, layer_rows, tel_col,
           frames_row, out_row) -> SessionSnapshot:
     arrays: Dict[str, np.ndarray] = {}
     for i, row in enumerate(layer_rows):
-        for name, val in zip(_LAYER_FIELDS, row):
+        for name, val in row.items():
             arrays[f"layer{i}/{name}"] = np.asarray(val, np.float32).copy()
     arrays["telemetry"] = np.asarray(np.stack(tel_col), np.float32)
     arrays["frames"] = _overlay_frames(pool, sess, k, frames_row)
@@ -206,8 +217,7 @@ def snapshot_session(pool: "SessionPool", req_id: int) -> SessionSnapshot:
     with pool._state_lock:
         state = pool.state
         layer_rows, tel_col, frames_row, out_row = jax.device_get((
-            tuple(tuple(getattr(st, f)[k] for f in _LAYER_FIELDS)
-                  for st in state.layers),
+            _layer_rows(state, k),
             (state.telemetry.nnz_sum[:, k],
              state.telemetry.overflow_steps[:, k],
              state.telemetry.steps[:, k]),
@@ -230,8 +240,7 @@ def snapshot_pool(pool: "SessionPool") -> PoolCheckpoint:
     for k, sess in enumerate(pool._slots):
         if sess is None:
             continue
-        layer_rows = tuple(tuple(getattr(st, f)[k] for f in _LAYER_FIELDS)
-                           for st in state.layers)
+        layer_rows = _layer_rows(state, k)
         tel_col = (state.telemetry.nnz_sum[:, k],
                    state.telemetry.overflow_steps[:, k],
                    state.telemetry.steps[:, k])
@@ -296,6 +305,20 @@ def _make_session(pool: "SessionPool", snap: SessionSnapshot, k: int,
     return sess
 
 
+def _check_layer_fields(pool: "SessionPool", snap: SessionSnapshot) -> None:
+    """A session snapshot carries no engine fingerprint: refuse one whose
+    layer arrays are not the pool's state fields (another cell kind or
+    depth) before any slot is touched."""
+    want = {f"layer{i}/{f}" for i, layer in enumerate(pool.engine.layers)
+            for f in STATE_TYPES[layer.cell]._fields}
+    have = {k for k in snap.arrays if k.startswith("layer")}
+    if have != want:
+        raise ValueError(
+            f"request {snap.meta['req_id']}: snapshot layer state "
+            f"{sorted(have)} does not match the pool's {pool.engine.cell} "
+            f"layers {sorted(want)}")
+
+
 def restore_session(pool: "SessionPool", snap: SessionSnapshot) -> bool:
     """Restore ONE session into a free slot of a live pool (the
     single-session migration primitive).  Returns False if the pool is
@@ -307,6 +330,7 @@ def restore_session(pool: "SessionPool", snap: SessionSnapshot) -> bool:
     m = snap.meta
     if int(m["req_id"]) in pool._by_req:
         raise ValueError(f"request {m['req_id']} is already in the pool")
+    _check_layer_fields(pool, snap)
     if int(m["n_recv"]) > pool.max_buffer_frames:
         raise ValueError(
             f"request {m['req_id']}: snapshot holds {m['n_recv']} frames, "
@@ -322,10 +346,10 @@ def restore_session(pool: "SessionPool", snap: SessionSnapshot) -> bool:
         state = pool.state
         layers = []
         for i, st in enumerate(state.layers):
-            layers.append(BatchedLayerState(**{
+            layers.append(type(st)(**{
                 f: _write_row(getattr(st, f),
                               jnp.asarray(snap.arrays[f"layer{i}/{f}"]), kk)
-                for f in _LAYER_FIELDS}))
+                for f in st._fields}))
         telemetry = tele.TelemetryState(
             nnz_sum=_write_col(state.telemetry.nnz_sum,
                                jnp.asarray(snap.arrays["telemetry"][0]), kk),
@@ -383,7 +407,8 @@ def restore_into(pool: "SessionPool", ckpt: PoolCheckpoint) -> None:
     # host-side assembly on top of the fresh-init values (so untouched
     # slots keep the exact fresh state, dm bias rows included):
     base = jax.device_get(pool.state)
-    layers = [{f: np.array(getattr(st, f)) for f in _LAYER_FIELDS}
+    layer_types = [type(st) for st in base.layers]
+    layers = [{f: np.array(getattr(st, f)) for f in st._fields}
               for st in base.layers]
     # three DISTINCT arrays: the step donates the whole state and aliased
     # telemetry leaves reject donation (the init_telemetry bug)
@@ -403,9 +428,9 @@ def restore_into(pool: "SessionPool", ckpt: PoolCheckpoint) -> None:
         k = pool._pick_slot()
         assert k is not None  # capacity checked above
         sess = _make_session(pool, snap, k, now_wall)
-        for i in range(len(layers)):
-            for f in _LAYER_FIELDS:
-                layers[i][f][k] = snap.arrays[f"layer{i}/{f}"]
+        for i, fields in enumerate(layers):
+            for f, slab in fields.items():
+                slab[k] = snap.arrays[f"layer{i}/{f}"]
         tel_n[:, k] = snap.arrays["telemetry"][0]
         tel_o[:, k] = snap.arrays["telemetry"][1]
         tel_s[:, k] = snap.arrays["telemetry"][2]
@@ -415,9 +440,8 @@ def restore_into(pool: "SessionPool", ckpt: PoolCheckpoint) -> None:
             out_np[k, :rows.shape[0]] = rows
 
     new_state = PoolState(
-        layers=tuple(BatchedLayerState(**{f: jnp.asarray(d[f])
-                                          for f in _LAYER_FIELDS})
-                     for d in layers),
+        layers=tuple(t(**{f: jnp.asarray(a) for f, a in d.items()})
+                     for t, d in zip(layer_types, layers)),
         telemetry=tele.TelemetryState(nnz_sum=jnp.asarray(tel_n),
                                       overflow_steps=jnp.asarray(tel_o),
                                       steps=jnp.asarray(tel_s)),

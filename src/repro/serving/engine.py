@@ -7,6 +7,13 @@ Per step and per layer:
   MACs  -> kernels.ops.stsp_spmv      (CBCSC spatio-temporal SpMxSpV)
   HPE   -> kernels.ops.lstm_pointwise (gates + cell update)
 
+The cell kind is fixed at pack time (`PackedLayer.cell`): a DeltaLSTM
+layer stacks its (i, g, f, o) gates, a DeltaGRU layer (core/delta_gru.py)
+its four delta memories (M_r, M_u, M_xc, M_hc); both are one [4H, D+H]
+matrix, so everything up to the gate math is shared.  The batched engine
+serves both; the batch-1 `SpartusEngine` and the Pallas route are
+DeltaLSTM-only.
+
 The engine exports any trained LSTM AM (models/lstm_am.py) into packed
 CBCSC + int8 form, runs batched streaming sessions, and records the
 per-step NZI occupancies that drive the hwsim performance model.
@@ -35,6 +42,7 @@ import numpy as np
 from repro.core import (
     CBCSC, blen_for, cbcsc_decode, cbcsc_encode, int8_pack,
 )
+from repro.core import delta_gru
 from repro.core.delta_lstm import stacked_weight_matrix
 from repro.core.quantization import QuantConfig
 from repro.kernels import ops
@@ -54,6 +62,11 @@ class PackedLayer:
     # GEMM-contraction layout because XLA CPU re-transposes `w.T` on every
     # tick otherwise (~3x the dot cost at hidden=128)
     w_dense_t: Optional[jax.Array] = None
+    cell: str = "lstm"         # "lstm" (i, g, f, o) | "gru" (r, u, xc, hc)
+    # MACs one slot-frame of this layer's matvec route issues, and the part
+    # of them that multiplies weights the model holds (host-side counters)
+    matvec_macs: int = 0
+    matvec_macs_held: int = 0
 
 
 @dataclasses.dataclass
@@ -82,8 +95,10 @@ def active_quant(cfg: EngineConfig) -> Optional[QuantConfig]:
     return q if (q is not None and q.enabled) else None
 
 
-def pack_lstm_layer(params: Dict[str, Any], cfg: EngineConfig) -> PackedLayer:
-    """Export one (CBTD-pruned) LSTM layer to the serving format.
+def _pack_stack(w: jax.Array, bias: jax.Array, hidden_dim: int,
+                cfg: EngineConfig, cell: str,
+                held_per_col: Optional[int] = None) -> PackedLayer:
+    """Export one stacked [4H, D+H] matrix to the serving format.
 
     BLEN is *enforced* at ``blen_for(gamma)`` (Alg. 3), clipping the
     smallest-magnitude overflow nonzeros per subcolumn, rather than derived
@@ -91,11 +106,14 @@ def pack_lstm_layer(params: Dict[str, Any], cfg: EngineConfig) -> PackedLayer:
     inflate BLEN to S, silently voiding the format's bandwidth contract
     (and making ``weight_sparsity()`` report near 0).  The clipped count is
     recorded as ``pack_overflow`` — 0 for any properly CBTD-pruned model.
+
+    ``held_per_col`` is how many weights of a column the model holds
+    (counted from shapes; default every stored slot, m x BLEN): the
+    ``matvec_macs_held`` counter credits that many MACs per column.
     """
     if cfg.spmv_path not in ("auto", "scatter", "dense"):
         raise ValueError(f"spmv_path must be 'auto', 'scatter' or 'dense', "
                          f"got {cfg.spmv_path!r}")
-    w = stacked_weight_matrix(params)              # [4H, D+H]
     q8, scale = int8_pack(w)
     wq = q8.astype(jnp.float32) * scale            # dequantized int8 grid
     wq = wq * (w != 0)                             # keep pruned zeros exact
@@ -129,12 +147,42 @@ def pack_lstm_layer(params: Dict[str, Any], cfg: EngineConfig) -> PackedLayer:
         if w_dense_t is not None:
             w_dense_t = jnp.round(w_dense_t / scale).astype(jnp.int8)
     capacity = max(int(n_cols * cfg.capacity_frac), 8)
+    stored = m * blen
+    held = stored if held_per_col is None else held_per_col
+    # the dense mirror multiplies every column by all 4H rows; the scatter
+    # route fetches m x BLEN stored slots for each of its K list entries
+    cols = n_cols if w_dense_t is not None else min(capacity, n_cols)
+    row_macs = h4 if w_dense_t is not None else stored
     return PackedLayer(
-        enc=enc, scale=scale, bias=params["b"],
-        input_dim=w.shape[1] - params["w_h"].shape[1],
-        hidden_dim=params["w_h"].shape[1], capacity=capacity,
-        pack_overflow=overflow, w_dense_t=w_dense_t,
+        enc=enc, scale=scale, bias=bias,
+        input_dim=n_cols - hidden_dim, hidden_dim=hidden_dim,
+        capacity=capacity, pack_overflow=overflow, w_dense_t=w_dense_t,
+        cell=cell, matvec_macs=cols * row_macs,
+        matvec_macs_held=cols * min(held, row_macs),
     )
+
+
+def pack_lstm_layer(params: Dict[str, Any], cfg: EngineConfig) -> PackedLayer:
+    """Export one (CBTD-pruned) LSTM layer: the (i, g, f, o) stack of eq.
+    (8), biases as the initial delta memories."""
+    return _pack_stack(stacked_weight_matrix(params), params["b"],
+                       params["w_h"].shape[1], cfg, "lstm")
+
+
+def pack_gru_layer(params: Dict[str, Any], cfg: EngineConfig) -> PackedLayer:
+    """Export one DeltaGRU layer (core/delta_gru.py params): the
+    (r, u, xc, hc) stack with its two structural-zero blocks.  Each column
+    holds 3H weights of the model (r, u and one of xc / hc)."""
+    if cfg.use_pallas:
+        raise ValueError("use_pallas=True serves DeltaLSTM layers only: "
+                         "there is no Pallas DeltaGRU kernel")
+    h = params["w_h"].shape[1]
+    return _pack_stack(delta_gru.stacked_weight_matrix(params),
+                       delta_gru.initial_delta_memories(params), h, cfg,
+                       "gru", held_per_col=3 * h)
+
+
+_PACKS = {"lstm": pack_lstm_layer, "gru": pack_gru_layer}
 
 
 class LayerState:
@@ -152,6 +200,10 @@ def _step_layer(
     layer: PackedLayer, state: LayerState, x: jax.Array, cfg: EngineConfig
 ) -> Tuple[jax.Array, Dict[str, int]]:
     """One streaming step of one layer.  x: [D] -> h: [H]."""
+    if layer.cell != "lstm":
+        raise NotImplementedError(
+            f"the batch-1 SpartusEngine steps DeltaLSTM layers only, not "
+            f"{layer.cell!r}; serve other cells with BatchedSpartusEngine")
     quant = active_quant(cfg)
     act_kw = (
         {"act_bits": quant.act_bits, "act_frac_bits": quant.act_frac_bits}
@@ -204,7 +256,11 @@ class PackedSpartusModel:
     def __init__(self, am_params: Dict[str, Any], am_cfg: LSTMAMConfig,
                  cfg: EngineConfig = EngineConfig()):
         self.cfg = cfg
-        self.layers = [pack_lstm_layer(lp, cfg) for lp in am_params["lstm"]]
+        cell = am_cfg.cell
+        if cell not in _PACKS:
+            raise ValueError(f"cell must be one of {sorted(_PACKS)}, got "
+                             f"{cell!r}")
+        self.layers = [_PACKS[cell](lp, cfg) for lp in am_params[cell]]
         self.fcl = am_params["fcl"]
         self.logit = am_params["logit"]
         self.am_cfg = am_cfg
@@ -216,6 +272,17 @@ class PackedSpartusModel:
     @property
     def n_classes(self) -> int:
         return self.logit["w"].shape[0]
+
+    @property
+    def cell(self) -> str:
+        return self.am_cfg.cell
+
+    @property
+    def matvec_macs(self) -> Tuple[int, int]:
+        """(MACs, MACs on held weights) of one slot-frame's matvecs over
+        every layer, from the pack's shapes."""
+        return (sum(l.matvec_macs for l in self.layers),
+                sum(l.matvec_macs_held for l in self.layers))
 
     @property
     def n_cols(self) -> List[int]:

@@ -590,6 +590,14 @@ class SessionPool:
             for key, n in counts.items():
                 self._boundary[key] = self._boundary.get(key, 0) + n
 
+    def _tally_matvec(self, n_frames: int) -> None:
+        """The matvec MACs of one dispatch of ``n_frames`` frames over every
+        slot, and those on held weights, from the pack's shapes: no sync."""
+        macs, held = self.engine.matvec_macs
+        slot_frames = self.capacity * n_frames
+        self._tally(matvec_macs=slot_frames * macs,
+                    matvec_macs_held=slot_frames * held)
+
     def _dev1d(self, arr: np.ndarray) -> jax.Array:
         """Place a per-slot host vector (active/reset masks, chunk-start
         cursors) to match the pool's slot sharding.  Identity-cost when
@@ -1005,6 +1013,7 @@ class SessionPool:
                 self.state, self._frames, self._dev1d(active),
                 self._dev1d(reset))
         self.n_dispatches += 1
+        self._tally_matvec(1)
         with self._tracer.span("snapshot_fetch", self._boundary):
             logits_np = np.asarray(logits)      # ONE device->host fetch/tick
         self._tally(fetch_rows=logits_np.shape[0],
@@ -1097,6 +1106,7 @@ class SessionPool:
                 self.state, self._frames, self._lengths, self._dev1d(active),
                 self._dev1d(reset), self._out, n_frames=n)
         self.n_dispatches += 1
+        self._tally_matvec(n)
 
         # ---- everything below overlaps the in-flight device chunk ----
         retiring: List[_Session] = []

@@ -2,7 +2,8 @@
 
 Every Pallas kernel that ``use_pallas=True`` reaches is compiled by Mosaic
 at the paper's widths, and the pool's chunk step at ``LSTM_2L_1024H`` by
-XLA's TPU backend, one chip and four.  Nothing runs: these tests catch what
+XLA's TPU backend, one chip and four (and at EdgeDRNN's 2x768 DeltaGRU on
+one).  Nothing runs: these tests catch what
 the chip's compilers refuse (tile-misaligned blocks, unlowerable
 primitives, a sharded step that communicates) at no chip time.  The
 topology is described inside a fixture, so a worker that cannot load the
@@ -133,6 +134,20 @@ def test_step_chunk_compiles(one_chip, params, use_pallas, spmv_path):
                       lambda st: jax.tree.map(lambda _: one_chip, st),
                       lambda _: one_chip)
     assert (KERNEL in txt) == use_pallas
+
+
+def test_gru_step_chunk_compiles(one_chip):
+    """EdgeDRNN's dense 2x768 DeltaGRU (gamma 0) on the dense-mirror
+    route: the GRU gate math fuses into the chunk program, no kernel."""
+    cfg = lstm_am.LSTMAMConfig(input_dim=123, hidden_dim=768, n_layers=2,
+                               n_classes=41, cell="gru")
+    engine = BatchedSpartusEngine(
+        lstm_am.init_params(jax.random.key(0), cfg), cfg,
+        EngineConfig(theta=THETA, gamma=0.0, m=M, capacity_frac=1.0))
+    txt = _chunk_text(engine, POOL,
+                      lambda st: jax.tree.map(lambda _: one_chip, st),
+                      lambda _: one_chip)
+    assert KERNEL not in txt
 
 
 def test_sharded_step_chunk_compiles_without_collectives(topo, params):
