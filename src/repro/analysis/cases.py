@@ -315,6 +315,26 @@ def _built_gather_frames() -> BuiltCase:
                      kwargs={}, donate_argnums=())
 
 
+def _built_place_blocks() -> BuiltCase:
+    """One admission wave as ``SessionPool._place_staged`` ships it into
+    a 4-slot pool at T_buf = 2K: a 300-frame utterance in two blocks, a
+    5-frame one in one, and a padding block (slot 4) that must drop."""
+    from repro.serving.scheduler import UPLOAD_BLOCK_FRAMES, _device_place
+
+    k = UPLOAD_BLOCK_FRAMES
+    frames = jax.random.normal(jax.random.key(14), (4, 2 * k, INPUT_DIM),
+                               jnp.float32)
+    blocks = jax.random.normal(jax.random.key(15), (4, k, INPUT_DIM),
+                               jnp.float32)
+    return BuiltCase(
+        fn=_device_place,
+        args=(frames, jnp.zeros((4,), jnp.int32), blocks,
+              np.asarray([1, 1, 3, 4], np.int32),
+              np.asarray([0, k, 0, 0], np.int32),
+              np.asarray([300, 300, 5, 0], np.int32)),
+        kwargs={}, donate_argnums=(0, 1))
+
+
 def build_cases(*, include_sharded: Optional[bool] = None) -> List[ContractCase]:
     """Every contract case runnable on the current device topology.
 
@@ -322,7 +342,7 @@ def build_cases(*, include_sharded: Optional[bool] = None) -> List[ContractCase]
     so do that before any lookup.
     """
     from repro.kernels import ops  # noqa: F401  (registers contracts)
-    from repro.serving import batched_engine, telemetry  # noqa: F401
+    from repro.serving import batched_engine, scheduler, telemetry  # noqa: F401
 
     if include_sharded is None:
         include_sharded = jax.device_count() >= 4
@@ -362,6 +382,7 @@ def build_cases(*, include_sharded: Optional[bool] = None) -> List[ContractCase]
         ContractCase("gather_rows", "gather_rows", _built_gather_rows),
         ContractCase("retire_rows", "retire_rows", _built_retire_rows),
         ContractCase("gather_frames", "gather_frames", _built_gather_frames),
+        ContractCase("place_blocks", "place_blocks", _built_place_blocks),
     ]
     if include_sharded:
         cases.append(

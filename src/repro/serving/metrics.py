@@ -70,13 +70,14 @@ SPAN_PREFIX = "spartus."
 #: seconds per pool phase span, and what crossed between host and device
 #: since the previous boundary (rows and bytes fetched, rows of them
 #: delivered, retirement gathers dispatched; real frames uploaded, frame
-#: slots and bytes sent), and the matvec MACs the dispatches issued and
-#: the part of them on weights the model holds (from the pack's shapes).
+#: slots and bytes sent, admission blocks holding real frames), and the
+#: matvec MACs the dispatches issued and the part of them on weights the
+#: model holds (from the pack's shapes).
 BOUNDARY_FIELDS = (
     "admission_upload_s", "retire_snapshot_s", "snapshot_fetch_s",
     "fetch_wait_s", "fetch_copy_s",
     "fetch_rows", "fetch_rows_kept", "fetch_bytes", "retire_gathers",
-    "upload_frames", "upload_frame_slots", "upload_bytes",
+    "upload_frames", "upload_frame_slots", "upload_bytes", "upload_blocks",
     "matvec_macs", "matvec_macs_held",
 )
 

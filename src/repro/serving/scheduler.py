@@ -74,6 +74,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis import lockorder
+from repro.analysis.contracts import hotpath_contract
 from repro.serving.batched_engine import BatchedSpartusEngine, PoolState
 from repro.serving.faults import FaultInjector
 from repro.serving.metrics import NULL_TRACER, PoolObservability
@@ -87,25 +88,42 @@ from repro.serving import telemetry as tele
 DEFAULT_MAX_BUFFER_FRAMES = 4096
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def _device_upload(
-    frames: jax.Array, lengths: jax.Array, rows: jax.Array,
-    slots: jax.Array, ts: jax.Array,
-) -> Tuple[jax.Array, jax.Array]:
-    """Scatter one admission wave's (bucket-padded) utterances + lengths
-    into the pool's device buffers at DYNAMIC slot indices.
+#: frames per admission block: a wave ships each staged utterance of L
+#: frames as ceil(L / K) blocks of K = min(UPLOAD_BLOCK_FRAMES, T_buf)
+#: frames (T_buf is a power of two >= 64, so K divides it).
+UPLOAD_BLOCK_FRAMES = 256
 
-    rows [R, T_buf, D], slots/ts [R] int32; padding entries carry an
-    out-of-bounds slot and are dropped.  Jitted with traced indices so it
-    compiles once per (buffer shape, R-bucket): an eagerly dispatched
-    ``frames.at[slot, :t].set(...)`` re-lowers per (slot, t) pair and
-    cost ~2 ms PER ADMISSION on the CPU backend — an admission storm of
-    16 requests used to spend longer staging frames than the device
-    spends computing a 32-frame chunk.  The buffers are donated, so the
-    scatter updates them in place instead of copying the whole slab (the
-    runtime serializes the write against any in-flight chunk still
-    reading the old frames)."""
-    frames = frames.at[slots].set(rows, mode="drop")
+#: where ``_device_place`` writes each block's ``[K, D]`` window: block i
+#: lands at ``frames[slot[i], t0[i]:t0[i] + K, :]``.
+_PLACE_DNUMS = jax.lax.ScatterDimensionNumbers(
+    update_window_dims=(1, 2), inserted_window_dims=(0,),
+    scatter_dims_to_operand_dims=(0, 1))
+
+
+@hotpath_contract("place_blocks", donates=("frames", "lengths"),
+                  op_budget={"scatter": 2})
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _device_place(
+    frames: jax.Array, lengths: jax.Array, blocks: jax.Array,
+    slots: jax.Array, t0s: jax.Array, ts: jax.Array,
+) -> Tuple[jax.Array, jax.Array]:
+    """Place one admission wave's frame blocks and lengths into the pool's
+    device buffers at DYNAMIC slot and frame offsets.
+
+    blocks [nb, K, D], slots/t0s/ts [nb] int32: block i is written whole
+    at ``frames[slots[i], t0s[i]:t0s[i] + K]`` and ``lengths[slots[i]]``
+    becomes ``ts[i]`` (the utterance's length, repeated on each of its
+    blocks); padding blocks carry an out-of-bounds slot and are dropped.
+    One scatter whose update windows are whole blocks, so the program
+    moves the wave's bytes and is keyed by ``nb`` alone (an eagerly
+    dispatched ``frames.at[slot, :t].set(...)`` re-lowers per (slot, t)
+    pair and cost ~2 ms PER ADMISSION on the CPU backend).  The buffers
+    are donated, so the scatter updates them in place instead of copying
+    the whole slab (the runtime serializes the write against any
+    in-flight chunk still reading the old frames)."""
+    idx = jnp.stack([slots, t0s], axis=1)
+    frames = jax.lax.scatter(frames, idx, blocks, _PLACE_DNUMS,
+                             mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
     lengths = lengths.at[slots].set(ts, mode="drop")
     return frames, lengths
 
@@ -914,9 +932,10 @@ class SessionPool:
     def _flush_uploads(self) -> None:
         """One batched H2D copy of every utterance admitted — and every
         frame block appended — since the last step (the whole admission
-        wave: [R, T_buf, D] in one ``device_put`` + one jitted scatter,
-        with R bucketed to a power of two so at most log2(capacity)
-        variants ever compile; appends go in a second [R, A, D] wave).
+        wave: its real frames cut into ``[nb, K, D]`` blocks, one
+        ``device_put`` + one jitted placement, with nb bucketed to a power
+        of two so at most log2(capacity · T_buf / K) variants ever
+        compile; appends go in a second [R, A, D] wave).
 
         The only host->device bytes are the new frames themselves: when a
         long utterance outgrows the bucket, the frame slab is reallocated
@@ -941,22 +960,7 @@ class SessionPool:
             if t_need > self._t_buf:
                 self._grow_buffers(t_need)
             if self._staged:
-                rb = _frame_bucket(len(self._staged), floor=1)
-                rows = np.zeros((rb, self._t_buf, self.engine.input_dim),
-                                np.float32)
-                slots = np.full((rb,), self.capacity, np.int32)  # OOB: drop
-                ts = np.zeros((rb,), np.int32)
-                for i, (k, feats) in enumerate(self._staged):
-                    rows[i, :feats.shape[0]] = feats  # zero tail clears stale
-                    slots[i] = k
-                    ts[i] = feats.shape[0]
-                self._staged.clear()
-                self._tally(upload_frames=int(ts.sum()),
-                            upload_frame_slots=rb * self._t_buf,
-                            upload_bytes=rows.nbytes)
-                self._frames, self._lengths = _device_upload(
-                    self._frames, self._lengths, jax.device_put(rows),
-                    slots, ts)
+                self._place_staged()
             if appends:
                 rb = _frame_bucket(len(appends), floor=1)
                 rows = np.zeros((rb, a_pad, self.engine.input_dim),
@@ -977,6 +981,41 @@ class SessionPool:
                     self._frames, self._lengths, jax.device_put(rows), slots,
                     starts, ts)
             self._ensure_slot_sharding()
+
+    def _place_staged(self) -> None:
+        """Ship the staged admissions as one wave of ``K``-frame blocks
+        (``_device_place``).  An utterance of L frames takes ceil(L / K)
+        blocks, a zero-length staging one all-zero block that sets the
+        slot's length to 0; the tail of each last block is zero, and
+        frames past that keep whatever an earlier occupant left (the scan
+        masks ``cursor < lengths``, snapshots copy ``[:n_recv]``).  A slot
+        staged twice since the last wave keeps its latest staging, so no
+        two blocks of a wave land on one window.  Caller holds
+        ``_state_lock``."""
+        k_blk = min(UPLOAD_BLOCK_FRAMES, self._t_buf)
+        staged = dict(self._staged)
+        self._staged.clear()
+        counts = [max(1, -(-f.shape[0] // k_blk)) for f in staged.values()]
+        n_real = sum(counts)
+        nb = _frame_bucket(n_real, floor=1)
+        blocks = np.zeros((nb, k_blk, self.engine.input_dim), np.float32)
+        flat = blocks.reshape(nb * k_blk, -1)
+        slots = np.full((nb,), self.capacity, np.int32)  # OOB: dropped
+        t0s = np.zeros((nb,), np.int32)
+        ts = np.zeros((nb,), np.int32)
+        b = 0
+        for (k, feats), n in zip(staged.items(), counts):
+            flat[b * k_blk:b * k_blk + feats.shape[0]] = feats
+            slots[b:b + n] = k
+            t0s[b:b + n] = np.arange(n, dtype=np.int32) * k_blk
+            ts[b:b + n] = feats.shape[0]
+            b += n
+        self._tally(upload_frames=sum(f.shape[0] for f in staged.values()),
+                    upload_frame_slots=nb * k_blk,
+                    upload_bytes=blocks.nbytes, upload_blocks=n_real)
+        self._frames, self._lengths = _device_place(
+            self._frames, self._lengths, jax.device_put(blocks),
+            slots, t0s, ts)
 
     def _masks(self) -> Tuple[np.ndarray, np.ndarray]:
         """active = occupied AND has unconsumed frames (a starved streaming

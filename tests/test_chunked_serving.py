@@ -23,7 +23,12 @@ from repro.serving import (
     serve_requests,
 )
 from repro.serving.metrics import PoolObservability
-from repro.serving.scheduler import RETIRE_BLOCK, SessionPool
+from repro.serving import scheduler
+from repro.serving.scheduler import (
+    RETIRE_BLOCK,
+    UPLOAD_BLOCK_FRAMES,
+    SessionPool,
+)
 
 INPUT_DIM, HIDDEN, CLASSES = 20, 32, 11
 GAMMA, M, THETA = 0.75, 4, 0.05
@@ -276,6 +281,122 @@ def test_upload_growth_single_realloc_no_host_recopy(engines, monkeypatch):
     assert pool.admit(StreamRequest(2, 0, _utterance(252, 100)), 0)
     pool._flush_uploads()
     assert pool.n_frame_grows == 1
+
+
+def _admit_wave(pool, lengths, key0):
+    """Admit one utterance per length (0 = a stream with no frames yet)
+    and return each slot's frames."""
+    sent = {}
+    for i, n in enumerate(lengths):
+        rid = key0 + i
+        if n:
+            feats = _utterance(rid, n)
+            assert pool.admit(StreamRequest(rid, 0, feats), 0)
+        else:
+            feats = np.zeros((0, INPUT_DIM), np.float32)
+            assert pool.admit_stream(rid, 0)
+        sent[pool._by_req[rid]] = feats
+    return sent
+
+
+def test_block_placement_lands_each_utterance_bit_for_bit(engines):
+    """An admission wave ships K-frame blocks of real frames: each
+    utterance lands bit for bit below its length, the tail of its last
+    block is zero, frames past that keep the slot's earlier occupant, and
+    the padding blocks of the pow2 bucket touch no slot at all."""
+    _, eb = engines
+    obs = PoolObservability()
+    pool = SessionPool(eb, capacity=8, max_frames=1024, chunk_frames=4,
+                       observability=obs)
+    t_buf = pool._t_buf
+    k = min(UPLOAD_BLOCK_FRAMES, t_buf)
+    assert (t_buf, k) == (1024, 256)
+    _admit_wave(pool, [t_buf] * 8, 300)              # earlier occupants
+    pool._flush_uploads()
+    prior = np.asarray(pool._frames)
+    for rid in range(300, 307):
+        pool.cancel(rid)
+    pool._reap_cancelled()
+    obs.boundary.clear()
+
+    lengths = [1, k - 1, k, k + 1, 2 * k + 3, t_buf, 0]
+    sent = _admit_wave(pool, lengths, 400)
+    pool._flush_uploads()
+    frames = np.asarray(pool._frames)
+    want_len = np.full((8,), t_buf, np.int32)
+    for slot, feats in sent.items():
+        n = feats.shape[0]
+        end = max(1, -(-n // k)) * k                 # end of its last block
+        np.testing.assert_array_equal(frames[slot, :n], feats)
+        assert not frames[slot, n:end].any()
+        np.testing.assert_array_equal(frames[slot, end:], prior[slot, end:])
+        want_len[slot] = n
+    np.testing.assert_array_equal(frames[7], prior[7])   # not in the wave
+    np.testing.assert_array_equal(np.asarray(pool._lengths), want_len)
+    n_blocks = 1 + 1 + 1 + 2 + 3 + 4 + 1             # 13 real: nb = 16
+    assert obs.boundary["upload_blocks"] == n_blocks
+    assert obs.boundary["upload_frame_slots"] == 16 * k
+    assert obs.boundary["upload_frames"] == sum(lengths)
+    assert obs.boundary["upload_bytes"] == 16 * k * INPUT_DIM * 4
+
+
+def test_block_placement_keyed_by_block_count_alone(engines):
+    """Waves of different sizes with the same bucketed block count run
+    one compiled placement program."""
+    _, eb = engines
+    pool = SessionPool(eb, capacity=5, max_frames=1024, chunk_frames=4)
+    _admit_wave(pool, [700], 500)                    # 1 utterance: nb = 4
+    pool._flush_uploads()
+    n0 = scheduler._device_place._cache_size()
+    pool.cancel(500)
+    pool._reap_cancelled()
+    _admit_wave(pool, [10, 20, 30, 0], 510)          # 4 utterances: nb = 4
+    pool._flush_uploads()
+    assert scheduler._device_place._cache_size() == n0
+    for rid in range(510, 514):
+        pool.cancel(rid)
+    pool._reap_cancelled()
+    _admit_wave(pool, [600, 300], 520)               # 5 blocks: nb = 8
+    pool._flush_uploads()
+    assert scheduler._device_place._cache_size() == n0 + 1
+
+
+def test_block_placement_ships_only_the_blocks(engines, monkeypatch):
+    """What crosses to the device for a wave is nb x K x D floats plus
+    the three [nb] int32 vectors (slot, block offset, length)."""
+    _, eb = engines
+    shipped = []
+    real_place = scheduler._device_place
+
+    def recording_place(frames, lengths, *wave):
+        shipped.append([(np.shape(a), np.asarray(a).nbytes) for a in wave])
+        return real_place(frames, lengths, *wave)
+
+    monkeypatch.setattr(scheduler, "_device_place", recording_place)
+    pool = SessionPool(eb, capacity=4, max_frames=1024, chunk_frames=4)
+    _admit_wave(pool, [300, 5, 0], 600)              # 2 + 1 + 1 blocks
+    pool._flush_uploads()
+    k, nb = UPLOAD_BLOCK_FRAMES, 4
+    (wave,) = shipped
+    assert wave[0][0] == (nb, k, INPUT_DIM)
+    assert sum(b for _, b in wave) == nb * k * INPUT_DIM * 4 + 3 * nb * 4
+
+
+def test_restaged_slot_keeps_its_latest_staging(engines):
+    """A stream that finished with no frames retires without a dispatch;
+    its slot is re-admitted before the next wave, and the wave carries
+    only the later staging for that slot."""
+    _, eb = engines
+    pool = SessionPool(eb, capacity=1, max_frames=64, chunk_frames=4)
+    assert pool.admit_stream(700, 0)
+    pool.finish_stream(700)
+    assert pool.tick(0)[1] == 0                      # retired, not uploaded
+    feats = _utterance(701, 9)
+    assert pool.admit(StreamRequest(701, 0, feats), 0)
+    assert [k for k, _ in pool._staged] == [0, 0]
+    pool._flush_uploads()
+    assert int(pool._lengths[0]) == 9
+    np.testing.assert_array_equal(np.asarray(pool._frames[0, :9]), feats)
 
 
 def test_no_per_tick_reallocation(engines):

@@ -49,6 +49,34 @@ def test_every_registered_contract_has_a_case():
     assert covered == set(contracts.registered_contracts())
 
 
+def test_place_blocks_moves_only_the_wave():
+    """The admission placement program donates the frame slab and the
+    lengths, is keyed by the block count alone (waves whose slots, block
+    offsets and lengths differ share one compiled program), and makes no
+    temporary the size of the slab: a copy of the whole slab beside the
+    donated output would need one."""
+    from repro.serving.scheduler import _device_place
+
+    (case,) = [c for c in caselib.build_cases(include_sharded=False)
+               if c.contract == "place_blocks"]
+    built = case.build()
+    assert built.fn is _device_place and built.donate_argnums == (0, 1)
+    slab = built.args[0]
+    mem = built.fn.lower(*built.args).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < slab.nbytes // 2
+    jax.block_until_ready(built.fn(*built.args))
+    n0 = _device_place._cache_size()
+    other = case.build()
+    slots, t0s, ts = (np.roll(a, 1) for a in other.args[3:])
+    jax.block_until_ready(built.fn(*other.args[:3], slots, t0s, ts))
+    assert _device_place._cache_size() == n0
+    wider = case.build()
+    jax.block_until_ready(built.fn(
+        *wider.args[:2], jnp.concatenate([wider.args[2]] * 2),
+        *(np.concatenate([a, a]) for a in wider.args[3:])))
+    assert _device_place._cache_size() == n0 + 1
+
+
 # -- negative cases: one per contract clause ---------------------------------
 
 # a minimal synthetic optimized-HLO module; the header carries a real

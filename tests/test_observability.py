@@ -28,7 +28,11 @@ from repro.serving import (
     Tracer,
     serve_requests,
 )
-from repro.serving.scheduler import RETIRE_BLOCK, SessionPool
+from repro.serving.scheduler import (
+    RETIRE_BLOCK,
+    UPLOAD_BLOCK_FRAMES,
+    SessionPool,
+)
 
 INPUT_DIM, HIDDEN, CLASSES = 20, 32, 11
 GAMMA, M, THETA = 0.75, 4, 0.05
@@ -351,12 +355,12 @@ def test_spans_land_on_the_profiler_clock(engine, workload, tmp_path):
 def test_boundary_counts_match_what_crossed(engine, workload,
                                             stream_partials):
     """The boundary samples count what crossed between host and device:
-    one admission wave of ``rb x T_buf`` frame slots holding the real
-    frames, one retirement gather of ``R x T_pad`` rows per retiring
-    boundary (R = RETIRE_BLOCK slots, clipped to the capacity), and of
-    the fetched rows exactly those delivered (each session's rows, and
-    with partials each chunk's rows once more).  Work resolved after the
-    last dispatch waits in ``obs.boundary``."""
+    one admission wave of ``nb x K`` frame slots (nb blocks of K frames)
+    holding the real frames, one retirement gather of ``R x T_pad`` rows
+    per retiring boundary (R = RETIRE_BLOCK slots, clipped to the
+    capacity), and of the fetched rows exactly those delivered (each
+    session's rows, and with partials each chunk's rows once more).
+    Work resolved after the last dispatch waits in ``obs.boundary``."""
     cap, chunk = 3, 4
     feats = workload[:cap]          # 5, 9, 3 frames: each retires alone
     obs = PoolObservability()
@@ -378,10 +382,12 @@ def test_boundary_counts_match_what_crossed(engine, workload,
 
     t_buf, t_pad = pool._frames.shape[1], pool._out.shape[1]
     n_frames = sum(f.shape[0] for f in feats)
-    assert samples[0]["upload_frame_slots"] == 4 * t_buf   # rb = 4 >= 3
+    k = min(UPLOAD_BLOCK_FRAMES, t_buf)     # one block per utterance here
+    assert samples[0]["upload_blocks"] == cap
+    assert samples[0]["upload_frame_slots"] == 4 * k       # nb = 4 >= 3
     assert samples[0]["upload_frames"] == n_frames
-    assert samples[0]["upload_bytes"] == 4 * t_buf * INPUT_DIM * 4
-    assert total("upload_frame_slots") == 4 * t_buf
+    assert samples[0]["upload_bytes"] == 4 * k * INPUT_DIM * 4
+    assert total("upload_frame_slots") == 4 * k
     delivered = sum(r.logits.shape[0] for r in results)
     assert delivered == n_frames
     assert partial_rows == (n_frames if stream_partials else 0)

@@ -23,6 +23,7 @@ from repro.kernels.stsp_spmv import stsp_spmv_scatter_batch_pallas
 from repro.models import lstm_am
 from repro.serving import BatchedSpartusEngine, EngineConfig
 from repro.serving import sharding as shardlib
+from repro.serving.scheduler import UPLOAD_BLOCK_FRAMES, _device_place
 
 # the paper's widths: Q columns of the layer-2 stack [x, h], M PEs, BLEN
 # nonzeros per subcolumn at gamma=0.9375, S = 4H/M rows per subcolumn,
@@ -159,3 +160,33 @@ def test_sharded_step_chunk_compiles_without_collectives(topo, params):
         lambda st: shardlib.pool_state_shardings(st, mesh),
         lambda shape: shardlib.slot_sharding(shape, mesh))
     assert hlo.count_collectives(txt) == 0, hlo.collective_lines(txt)[:4]
+
+
+def test_admission_placement_moves_only_the_wave(one_chip):
+    """At the offline cells' slab ([1024, 4096, 123] f32) and a wave of
+    256 blocks, the placement program updates the donated slab in place:
+    no slab-sized temporary.  The padded-row scatter it replaced is
+    compiled beside it to show the pin fires: the TPU compiler relays out
+    the whole slab for it, twice."""
+    cap, t_buf, d, nb = 1024, 4096, 123, 256
+    frames = _sds((cap, t_buf, d), jnp.float32, one_chip)
+    lengths = _sds((cap,), jnp.int32, one_chip)
+    vec = _sds((nb,), jnp.int32, one_chip)
+    placed = _device_place.lower(
+        frames, lengths,
+        _sds((nb, UPLOAD_BLOCK_FRAMES, d), jnp.float32, one_chip),
+        vec, vec, vec).compile()
+    slab_bytes = cap * t_buf * d * 4
+    assert placed.memory_analysis().temp_size_in_bytes < slab_bytes // 100
+    assert hlo.alias_count(placed.as_text()) == 2
+
+    def padded_rows(frames, lengths, rows, slots, ts):
+        return (frames.at[slots].set(rows, mode="drop"),
+                lengths.at[slots].set(ts, mode="drop"))
+
+    rb = 32
+    padded = jax.jit(padded_rows, donate_argnums=(0, 1)).lower(
+        frames, lengths, _sds((rb, t_buf, d), jnp.float32, one_chip),
+        _sds((rb,), jnp.int32, one_chip),
+        _sds((rb,), jnp.int32, one_chip)).compile()
+    assert padded.memory_analysis().temp_size_in_bytes >= slab_bytes
