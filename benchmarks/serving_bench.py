@@ -14,14 +14,16 @@ identical (atol 1e-5) to the batch-1 engine's.
         offered load, plus sustained throughput vs the synchronous
         chunked pool at the same chunk size
     PYTHONPATH=src python benchmarks/serving_bench.py --check   # CI gate:
-        fail unless capacity-16 aggregate frames/s >= 4x sequential
+        fail unless capacity-16 aggregate frames/s >= 4x sequential, with
+        the pool at one frame per dispatch (chunk_frames=1), as are the
+        sweep's crossover legs below
     PYTHONPATH=src python benchmarks/serving_bench.py --sweep   # slow CI gate:
         hidden in {128, 512} at m=16 / capacity 16 plus a forced-scatter
         leg, emits BENCH_serving.json, fails if the pool ever drops below
         the batch-1 engine (the crossover that regressed before the
-        scatter/dense-gather SpMV paths); also runs the chunked tick loop
-        at chunk_frames in {1, 8, 32} vs the per-frame pool at hidden=128
-        and fails if chunk_frames=32 is slower than per-frame (the
+        scatter/dense-gather SpMV paths); also sweeps chunk_frames in
+        {8, 32} against one-frame chunks at hidden=128 and fails if
+        chunk_frames=32 is slower than chunk_frames=1 (the
         dispatch-amortisation gate), and the async open-loop leg, failing
         if the async front-end's sustained (saturated) throughput drops
         below ASYNC_FLOOR x the synchronous chunked pool, and the
@@ -33,12 +35,10 @@ identical (atol 1e-5) to the batch-1 engine's.
         quantized leg alone, at the CLI's model config
 
 Runs on CPU: the batch-1 engine pays ~8 XLA dispatches + 3 host syncs per
-(frame, layer) while the pool amortises one dispatch + one logits fetch
-across all slots per tick — the speedup below is that dispatch economy,
-before any accelerator parallelism.  The chunked tick loop compounds it:
-one lax.scan dispatch advances all slots up to C frames and logits leave
-the device once per session (at retirement), so the per-tick Python /
-dispatch / fetch overhead is amortised C-fold on top.
+(frame, layer) while the pool amortises one lax.scan dispatch across all
+slots and up to C frames, and logits leave the device once per session
+(at retirement) — the speedup below is that dispatch economy, before any
+accelerator parallelism.
 """
 from __future__ import annotations
 
@@ -68,8 +68,10 @@ from repro.serving import (
 #: (`obs_overhead`) and the per-row stamp itself.  v3 added the quantized
 #: leg (`quant_*` rows: divergence vs fp32, weight-payload ratio,
 #: bytes-per-slot and the equal-bytes capacity) and `bytes_per_slot`
-#: inside every ServeStats dict.
-SCHEMA_VERSION = 3
+#: inside every ServeStats dict.  v4 dropped the chunk sweep's
+#: `per_frame` row: its baseline is `chunk_1`, and each row's speedup is
+#: `speedup_vs_chunk_1`.
+SCHEMA_VERSION = 4
 
 
 def _write_report(path: str, report: dict) -> None:
@@ -122,7 +124,7 @@ def make_requests(n: int, frames: int, input_dim: int,
 def bench_config(hidden: int, layers: int, input_dim: int, classes: int,
                  frames: int, n_requests: int, caps: List[int], theta: float,
                  gamma: float, m: int, capacity_frac: float,
-                 spmv_path: str = "auto", chunk_frames: int = 0):
+                 spmv_path: str = "auto", chunk_frames: int = 1):
     """One model configuration: sequential batch-1 baseline + the pool at
     each capacity, with per-request logits parity checked against the
     batch-1 engine.  Returns (report dict, parity_ok)."""
@@ -178,11 +180,11 @@ def bench_config(hidden: int, layers: int, input_dim: int, classes: int,
 def bench_chunked(hidden: int, layers: int, input_dim: int, classes: int,
                   frames: int, n_requests: int, cap: int, theta: float,
                   gamma: float, m: int, capacity_frac: float,
-                  chunk_grid=(1, 8, 32)):
-    """Chunked tick loop vs the per-frame pool at one capacity: same
-    requests, logits parity pinned against the per-frame results, speedup
-    and dispatch amortisation reported per chunk_frames.  Returns
-    (report dict, parity_ok)."""
+                  chunk_grid=(8, 32)):
+    """Chunk sweep against one-frame chunks (chunk_frames=1) at one
+    capacity: same requests, logits parity pinned against the C=1
+    results, speedup and dispatch amortisation reported per
+    chunk_frames.  Returns (report dict, parity_ok)."""
     params, cfg = build_model(hidden, layers, input_dim, classes, gamma, m)
     ecfg = EngineConfig(theta=theta, gamma=gamma, m=m,
                         capacity_frac=capacity_frac)
@@ -195,11 +197,12 @@ def bench_chunked(hidden: int, layers: int, input_dim: int, classes: int,
         serve_requests(eb, [StreamRequest(i, 0, reqs[0].feats)
                             for i in range(cap)], cap, chunk_frames=chunk)
 
-    warm(0)
-    base_results, base = serve_requests(eb, reqs, capacity=cap)
+    warm(1)
+    base_results, base = serve_requests(eb, reqs, capacity=cap,
+                                        chunk_frames=1)
     report = {"hidden": hidden, "m": m, "capacity": cap,
-              "per_frame": base.to_dict()}
-    print(f"[bench] hidden={hidden} capacity={cap} per-frame pool: "
+              "chunk_1": base.to_dict()}
+    print(f"[bench] hidden={hidden} capacity={cap} chunk_frames   1: "
           f"{base.frames_per_s:8.0f} frames/s  "
           f"({base.dispatches_per_frame:.3f} dispatches/frame)")
 
@@ -216,9 +219,9 @@ def bench_chunked(hidden: int, layers: int, input_dim: int, classes: int,
                       f"chunk_frames {chunk}")
         speedup = stats.frames_per_s / base.frames_per_s
         report[f"chunk_{chunk}"] = dict(stats.to_dict(),
-                                        speedup_vs_per_frame=speedup)
+                                        speedup_vs_chunk_1=speedup)
         print(f"[bench] chunk_frames {chunk:3d}: {stats.frames_per_s:8.0f} "
-              f"frames/s ({speedup:4.1f}x per-frame)  "
+              f"frames/s ({speedup:4.1f}x C=1)  "
               f"{stats.dispatches_per_frame:.3f} dispatches/frame  "
               f"host overlap {stats.host_overlap_frac:.0%}")
     return report, parity_ok
@@ -590,12 +593,12 @@ def bench_quant(hidden: int, layers: int, input_dim: int, classes: int,
 # scatter kernels, which auto would otherwise never exercise here.
 SWEEP_LEGS = ((128, "auto"), (512, "auto"), (128, "scatter"))
 SWEEP_CAP = 16
-# chunked-vs-per-frame leg: hidden for the chunked tick-loop gate and the
-# chunk_frames grid recorded in BENCH_serving.json.  The gate requires the
-# largest chunk to be at least as fast as the per-frame pool; measured CPU
-# speedup at hidden=128 / capacity 16 is >= 3x (dispatch amortisation).
+# chunk-sweep leg: hidden for the dispatch-amortisation gate and the
+# chunk_frames swept against one-frame chunks (both recorded in
+# BENCH_serving.json).  The gate requires the largest chunk to be at
+# least as fast as chunk_frames=1.
 SWEEP_CHUNK_HIDDEN = 128
-SWEEP_CHUNK_GRID = (1, 8, 32)
+SWEEP_CHUNK_GRID = (8, 32)
 # async open-loop leg: offered-load multipliers (x the sync chunked pool's
 # throughput) for the latency-vs-load rows, and the CI floor on the
 # saturated-throughput ratio.  The async front-end runs the identical
@@ -672,9 +675,11 @@ def main() -> int:
     ap.add_argument("--gamma", type=float, default=0.9375)
     ap.add_argument("--m", type=int, default=4)
     ap.add_argument("--capacity-frac", type=float, default=0.5)
-    ap.add_argument("--chunk-frames", type=int, default=0,
-                    help="chunked tick loop: frames advanced per dispatch "
-                         "(0 = per-frame path)")
+    ap.add_argument("--chunk-frames", type=int, default=None,
+                    help="frames advanced per dispatch (default 1 for the "
+                         "capacity legs, one dispatch per frame as their "
+                         "gates assume; 32 for --quant, --obs-overhead "
+                         "and --async-load)")
     ap.add_argument("--check", action="store_true",
                     help="exit 1 unless capacity-16 (or max capacity) hits "
                          ">=4x sequential frames/s with matching logits")
@@ -740,20 +745,20 @@ def main() -> int:
             report[f"hidden_{hidden}_{path}"] = dict(
                 rep, parity=parity,
                 frames_per_s=rep[f"capacity_{SWEEP_CAP}"]["frames_per_s"])
-        # chunked tick-loop gate: the biggest chunk must never be slower
-        # than the per-frame pool (it measures >= 3x on CPU; the CI floor
-        # is 1x so a noisy shared runner cannot flake the job):
+        # dispatch-amortisation gate: the biggest chunk must never be
+        # slower than one-frame chunks (the CI floor is 1x so a noisy
+        # shared runner cannot flake the job):
         crep, cparity = bench_chunked(
             SWEEP_CHUNK_HIDDEN, args.layers, args.input_dim, args.classes,
             args.frames, args.requests, SWEEP_CAP, args.theta, args.gamma,
             m=16, capacity_frac=args.capacity_frac,
             chunk_grid=SWEEP_CHUNK_GRID)
         cmax = max(SWEEP_CHUNK_GRID)
-        cspeed = crep[f"chunk_{cmax}"]["speedup_vs_per_frame"]
+        cspeed = crep[f"chunk_{cmax}"]["speedup_vs_chunk_1"]
         cfast = cspeed >= 1.0
         print(f"[bench] sweep chunked hidden={SWEEP_CHUNK_HIDDEN}: parity="
               f"{'ok' if cparity else 'FAIL'} chunk_{cmax}="
-              f"{cspeed:.1f}x per-frame -> "
+              f"{cspeed:.1f}x C=1 -> "
               f"{'PASS' if (cparity and cfast) else 'FAIL'}")
         ok = ok and cparity and cfast
         report[f"chunked_hidden_{SWEEP_CHUNK_HIDDEN}"] = dict(
@@ -878,7 +883,7 @@ def main() -> int:
     report, parity_ok = bench_config(
         args.hidden, args.layers, args.input_dim, args.classes, args.frames,
         args.requests, caps, args.theta, args.gamma, args.m,
-        args.capacity_frac, chunk_frames=args.chunk_frames)
+        args.capacity_frac, chunk_frames=args.chunk_frames or 1)
 
     if args.json:
         print(json.dumps(report, indent=2))

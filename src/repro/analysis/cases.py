@@ -177,18 +177,6 @@ def lower_pool_chunk(
 # -- per-contract case builders ----------------------------------------------
 
 
-def _built_step_frames() -> BuiltCase:
-    engine = _engine()
-    state = engine.init_state(4)
-    frames = jax.random.normal(jax.random.key(3), (4, 8, INPUT_DIM),
-                               jnp.float32)
-    active = jnp.ones((4,), bool)
-    reset = jnp.zeros((4,), bool)
-    return BuiltCase(fn=engine._step_frames,
-                     args=(state, frames, active, reset),
-                     kwargs={}, donate_argnums=(0,))
-
-
 def _built_step_chunk(spmv_path: str, quant: bool = False) -> BuiltCase:
     return built_pool_chunk(_engine(spmv_path, quant=quant), _feats())
 
@@ -347,8 +335,6 @@ def build_cases(*, include_sharded: Optional[bool] = None) -> List[ContractCase]
     if include_sharded is None:
         include_sharded = jax.device_count() >= 4
     cases = [
-        ContractCase("step_frames/unsharded", "step_frames",
-                     _built_step_frames),
         ContractCase("step_chunk/dense-mirror", "step_chunk",
                      lambda: _built_step_chunk("auto"),
                      op_budget_override={"sort": 0}),

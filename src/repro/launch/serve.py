@@ -10,7 +10,7 @@ session pool with --pool N, or the asyncio streaming front-end with
     PYTHONPATH=src python -m repro.launch.serve --spartus --pool 8 --quant \
         --requests 24        # int8 weights + Q8.8 activations end-to-end
     PYTHONPATH=src python -m repro.launch.serve --spartus --pool 8 \
-        --chunk-frames 32    # chunked device tick loop (1 dispatch / 32 frames)
+        --chunk-frames 32    # 32-frame chunks (1 dispatch / 32 frames)
     XLA_FLAGS=--xla_force_host_platform_device_count=4 \
     PYTHONPATH=src python -m repro.launch.serve --spartus --pool 8 \
         --chunk-frames 32 --devices 4   # slot-sharded pool (2 slots/device)
@@ -173,9 +173,8 @@ def serve_spartus(args):
         results, stats = serve_requests(engine, reqs, capacity=args.pool,
                                         chunk_frames=args.chunk_frames,
                                         n_devices=n_devices)
-        mode = (f"chunked x{args.chunk_frames}" if args.chunk_frames
-                else "per-frame")
-        print(f"[serve] pool({args.pool}, {mode}): {stats.n_requests} "
+        print(f"[serve] pool({args.pool}, {args.chunk_frames}-frame "
+              f"chunks): {stats.n_requests} "
               f"sessions / {stats.total_frames} frames in {stats.wall_s:.2f}s "
               f"-> {stats.frames_per_s:.0f} frames/s, latency "
               f"p50 {stats.p50_latency_s*1e3:.0f} ms / "
@@ -492,7 +491,7 @@ def serve_spartus_async(args):
                                   quant=QuantConfig() if args.quant
                                   else None))
     capacity = max(args.pool, 1)
-    chunk = args.chunk_frames or 8
+    chunk = args.chunk_frames
 
     async def run():
         obs = PoolObservability(tracer=Tracer(enabled=bool(args.trace)))
@@ -593,9 +592,9 @@ def main():
                     help="session-pool capacity (0 = batch-1 engine)")
     ap.add_argument("--requests", type=int, default=16,
                     help="number of streaming requests for --pool mode")
-    ap.add_argument("--chunk-frames", type=int, default=0,
-                    help="--pool mode: frames advanced per device dispatch "
-                         "(0 = per-frame ticks; --async defaults to 8)")
+    ap.add_argument("--chunk-frames", type=int, default=8,
+                    help="--pool/--async: frames advanced per device "
+                         "dispatch")
     ap.add_argument("--devices", type=int, default=0,
                     help="--pool/--async: shard the pool's slot dimension "
                          "over N devices (0 = single-device; emulate with "

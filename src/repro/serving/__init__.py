@@ -1,8 +1,8 @@
 """Serving: the Spartus datapath as an inference service.
 
 - `engine`         — paper-faithful batch-1 streaming engine (SpartusEngine)
-- `batched_engine` — continuous-batching multi-session engine (step_batch /
-                     step_frames / chunked step_chunk + output snapshots)
+- `batched_engine` — continuous-batching multi-session engine (step_chunk:
+                     up to C frames per dispatch, + output snapshots)
 - `scheduler`      — SessionPool admission/eviction (incl. incremental
                      streaming admission + partial-logits snapshots) and
                      the synchronous serve_requests driver

@@ -182,9 +182,8 @@ class AsyncSpartusServer:
     Parameters
     ----------
     engine / capacity / chunk_frames / max_frames / max_buffer_frames:
-        forwarded to the underlying `SessionPool` (``chunk_frames >= 1``
-        selects the chunked tick loop; the pool streams per-chunk partial
-        logits).
+        forwarded to the underlying `SessionPool`, which streams per-chunk
+        partial logits (``chunk_frames < 1`` raises ``ValueError``).
     target_chunk_ms:
         wall-clock pacing of chunk boundaries: the driver sleeps out the
         remainder of this budget after each tick, so a chunk's worth of
@@ -259,9 +258,6 @@ class AsyncSpartusServer:
                  watchdog: bool = False,
                  max_recoveries: int = 8,
                  faults: Optional[FaultInjector] = None):
-        if chunk_frames < 1:
-            raise ValueError("AsyncSpartusServer requires chunk_frames >= 1 "
-                             "(the per-chunk partial-logits contract)")
         if overload_policy not in ("wait", "shed"):
             raise ValueError(f"overload_policy must be 'wait' or 'shed', "
                              f"got {overload_policy!r}")

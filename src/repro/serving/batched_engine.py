@@ -1,11 +1,12 @@
-"""Continuous-batching Spartus engine: all pool slots advance one frame
-in a single jitted call.
+"""Continuous-batching Spartus engine: all pool slots advance up to C
+frames in a single jitted call.
 
 `SpartusEngine` (engine.py) is the paper-faithful batch-1 datapath: a
 Python loop per frame and per layer with host syncs for telemetry.  This
 module is its server-grade twin: the per-layer state of every session in
 a fixed-capacity pool is stored as stacked device slabs
-(`BatchedLayerState`, shapes `[B, ...]`), and `step_batch` runs
+(`BatchedLayerState`, shapes `[B, ...]`), and each frame of
+`step_chunk` runs
 
     IPU   delta_encode_batch            (slots batched)
     CTRL  select_active_columns_batch   (scatter route; the dense-mirror
@@ -24,19 +25,16 @@ a `reset` mask re-initialises slots at admission time so attach/detach
 never recompiles.  Telemetry is accumulated on device (telemetry.py) and
 fetched only when `measured_sparsity` is called.
 
-Three step entry points share the same core: `step_batch` takes this
-tick's host-staged frames `x [B, D]` (reference semantics, tests);
-`step_frames` reads from pre-uploaded per-slot feature buffers
-`[B, T_buf, D]` indexed by the device cursor in `PoolState` — the
-steady-state serving tick (`SessionPool.step`) therefore performs no
-host->device frame copy at all; and `step_chunk` advances every active
-slot up to `n_frames` frames in ONE dispatch via `jax.lax.scan` over the
-same core, banking each frame's logits in a per-slot device output
-buffer `[B, T_buf, n_classes]` instead of returning them per tick — a
-finished slot's logits leave the device once, at retirement.  The
-serving-path functions (`step_frames`, `step_chunk`) donate the incoming
-`PoolState` (and the chunk output buffer), so the state slabs are reused
-in place tick over tick instead of reallocating.
+`step_chunk` is the one step entry point: it reads each slot's frames
+from pre-uploaded per-slot feature buffers `[B, T_buf, D]` by the
+device cursor in `PoolState` (a tick moves no frame bytes host ->
+device), advances every active slot up to `n_frames` frames in ONE
+dispatch via `jax.lax.scan` over the per-frame core, and banks each
+frame's logits in a per-slot device output buffer `[B, T_buf,
+n_classes]` instead of returning them per tick — a finished slot's
+logits leave the device once, at retirement.  It donates the incoming
+`PoolState` and the output buffer, so the slabs are reused in place
+chunk over chunk instead of reallocating.
 
 Because the output buffer is donated, anything that must outlive the
 next chunk is detached device-side first: `retire_rows` gathers just
@@ -98,8 +96,7 @@ class PoolState(NamedTuple):
     layers: Tuple[LayerStates, ...]
     telemetry: tele.TelemetryState
     cursor: jax.Array  # [B] int32 per-slot frame cursor into the pool's
-    #                    device-resident feature buffers (step_frames);
-    #                    carried through unchanged by the legacy step_batch
+    #                    device-resident feature buffers
 
 
 def _fresh_layer_state(layer: PackedLayer, n_slots: int) -> LayerStates:
@@ -130,13 +127,8 @@ class BatchedSpartusEngine(PackedSpartusModel):
     def __init__(self, am_params: Dict[str, Any], am_cfg: LSTMAMConfig,
                  cfg: EngineConfig = EngineConfig()):
         super().__init__(am_params, am_cfg, cfg)
-        self._step = jax.jit(self._step_impl)
-        # serving paths donate the incoming PoolState (and the chunk's
-        # output buffer) so the slabs are reused in place, never
-        # reallocated per tick; step_batch stays non-donating because the
-        # tests use it as the reference oracle and may re-step old states.
-        self._step_frames = jax.jit(self._step_frames_impl,
-                                    donate_argnums=(0,))
+        # the chunk donates the incoming PoolState and output buffer, so
+        # the slabs are reused in place, never reallocated per chunk.
         self._step_chunk = jax.jit(self._step_chunk_impl,
                                    static_argnames=("n_frames",),
                                    donate_argnums=(0, 5))
@@ -174,13 +166,11 @@ class BatchedSpartusEngine(PackedSpartusModel):
         """Per-slot device logits buffer for the chunked tick loop."""
         return jnp.zeros((n_slots, t_buf, self.n_classes), jnp.float32)
 
-    def _apply_reset(
-        self, state: PoolState, reset: jax.Array, *, reset_cursor: bool,
-    ) -> PoolState:
-        """Re-initialise reset slots' layer state (and optionally their
-        device cursor) — admission, fused into the step/chunk dispatch so
-        attach never costs an extra dispatch or recompiles.  Applied ONCE
-        per dispatch, at the boundary: inside a chunk no slot resets."""
+    def _apply_reset(self, state: PoolState, reset: jax.Array) -> PoolState:
+        """Re-initialise reset slots' layer state and device cursor —
+        admission, fused into the chunk dispatch so attach never costs an
+        extra dispatch or recompiles.  Applied ONCE per dispatch, at the
+        boundary: inside a chunk no slot resets."""
         n_slots = state.cursor.shape[0]
         rm = reset[:, None]
         layers = []
@@ -188,8 +178,7 @@ class BatchedSpartusEngine(PackedSpartusModel):
             fresh = _fresh_layer_state(layer, n_slots)
             layers.append(type(st)(*(
                 jnp.where(rm, f, old) for f, old in zip(fresh, st))))
-        cursor = jnp.where(reset, 0, state.cursor) if reset_cursor \
-            else state.cursor
+        cursor = jnp.where(reset, 0, state.cursor)
         return PoolState(tuple(layers), state.telemetry, cursor)
 
     # -- the batched step ----------------------------------------------------
@@ -269,29 +258,6 @@ class BatchedSpartusEngine(PackedSpartusModel):
             logits = h @ self.logit["w"].T + self.logit["b"]
         return PoolState(tuple(new_layers), tel, cursor), logits
 
-    def _step_impl(
-        self, state: PoolState, x: jax.Array, active: jax.Array,
-        reset: jax.Array,
-    ) -> Tuple[PoolState, jax.Array]:
-        # legacy host-staged entry: the caller supplies this tick's frames,
-        # the device cursor rides along untouched.
-        state = self._apply_reset(state, reset, reset_cursor=False)
-        return self._step_core(state, x, active, state.cursor)
-
-    @hotpath_contract("step_frames", donates=("state",),
-                      op_budget={"transpose": 0})
-    def _step_frames_impl(
-        self, state: PoolState, frames: jax.Array, active: jax.Array,
-        reset: jax.Array,
-    ) -> Tuple[PoolState, jax.Array]:
-        # device-resident entry: gather each slot's current frame from the
-        # pre-uploaded [B, T_buf, D] buffers by the cursor carried in
-        # PoolState — a tick moves zero frame bytes host -> device.
-        state = self._apply_reset(state, reset, reset_cursor=True)
-        x = ops.gather_frames(frames, state.cursor)
-        new_cur = state.cursor + active.astype(state.cursor.dtype)
-        return self._step_core(state, x, active, new_cur)
-
     @hotpath_contract("step_chunk", donates=("state", "out_buf"),
                       op_budget={"transpose": 0, "dynamic-update-slice": 8})
     def _step_chunk_impl(
@@ -299,9 +265,9 @@ class BatchedSpartusEngine(PackedSpartusModel):
         active: jax.Array, reset: jax.Array, out_buf: jax.Array,
         *, n_frames: int,
     ) -> Tuple[PoolState, jax.Array]:
-        # chunked entry: admission resets happen once at the chunk
-        # boundary, then lax.scan advances every slot up to n_frames
-        # frames with zero host involvement.  A slot whose cursor reaches
+        # admission resets happen once at the chunk boundary, then
+        # lax.scan advances every slot up to n_frames frames with zero
+        # host involvement.  A slot whose cursor reaches
         # its utterance length mid-chunk goes inactive for the remaining
         # iterations: its state freezes and it contributes no telemetry —
         # exactly as if the host had masked it.  The scan stacks each
@@ -312,7 +278,7 @@ class BatchedSpartusEngine(PackedSpartusModel):
         # scopes (reset, step_scan, bank_rows, and per layer in
         # _step_core) name the device work in a profiler trace.
         with jax.named_scope("reset"):
-            state = self._apply_reset(state, reset, reset_cursor=True)
+            state = self._apply_reset(state, reset)
         start = state.cursor
 
         def body(st, _):
@@ -326,44 +292,6 @@ class BatchedSpartusEngine(PackedSpartusModel):
             state, ys = jax.lax.scan(body, state, None, length=n_frames)
         with jax.named_scope("bank_rows"):
             return state, ops.bank_rows(out_buf, ys, start)
-
-    def step_batch(
-        self, state: PoolState, x: jax.Array, active: jax.Array,
-        reset: jax.Array | None = None,
-    ) -> Tuple[PoolState, jax.Array]:
-        """Advance every active slot one frame from host-staged frames.
-
-        x      [B, D]  next input frame per slot (zeros for idle slots)
-        active [B]     slots that consume a frame this tick
-        reset  [B]     slots to re-initialise *before* stepping (admission)
-
-        Returns (new_state, logits [B, n_classes]); logits rows of inactive
-        slots are garbage and must be ignored by the caller.
-        """
-        if reset is None:
-            reset = jnp.zeros(active.shape, bool)
-        return self._step(state, jnp.asarray(x, jnp.float32),
-                          jnp.asarray(active, bool), jnp.asarray(reset, bool))
-
-    def step_frames(
-        self, state: PoolState, frames: jax.Array, active: jax.Array,
-        reset: jax.Array | None = None,
-    ) -> Tuple[PoolState, jax.Array]:
-        """Advance every active slot one frame from device-resident buffers.
-
-        frames [B, T_buf, D]  per-slot feature buffers already on device
-                              (SessionPool.admit uploads each utterance once)
-        active / reset        as in ``step_batch``
-
-        Each slot's frame is selected by ``state.cursor`` *on device* (reset
-        slots restart at 0; active slots advance by 1), so the steady-state
-        tick issues no host staging copy at all.  Numerics are identical to
-        feeding the same frames through ``step_batch``.
-        """
-        if reset is None:
-            reset = jnp.zeros(active.shape, bool)
-        return self._step_frames(state, frames, jnp.asarray(active, bool),
-                                 jnp.asarray(reset, bool))
 
     def step_chunk(
         self, state: PoolState, frames: jax.Array, lengths: jax.Array,
@@ -395,7 +323,9 @@ class BatchedSpartusEngine(PackedSpartusModel):
         and use the returned arrays (slice a retiring slot's rows *before*
         the next call).  Logits never leave the device here — fetch a
         finished slot's rows from the output buffer once, at retirement.
-        Numerics per consumed frame are identical to ``step_frames``.
+        Numerics per consumed frame do not depend on ``n_frames``: C
+        one-frame chunks bank the rows of one C-frame chunk (pinned at
+        1e-5 in tests/test_chunked_serving.py).
         """
         return self._step_chunk(
             state, frames, jnp.asarray(lengths, jnp.int32),

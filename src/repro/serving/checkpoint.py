@@ -12,9 +12,8 @@ bookkeeping the scheduler keeps per slot:
   * the device frame cursor and the frames received so far (device
     feature buffer row, with any *staged-but-not-yet-uploaded* host
     blocks overlaid — a snapshot never has to force an upload flush);
-  * the banked logits rows ``[0, cursor)`` of the device output buffer
-    (chunked mode) or the host row list (per-frame mode) — the rows a
-    client may not have consumed yet;
+  * the banked logits rows ``[0, cursor)`` of the device output buffer —
+    the rows a client may not have consumed yet;
   * the `_Session` metadata (req id, totals, needs_reset, ...).
 
 Because every slot is computationally independent (the batched kernels
@@ -178,18 +177,12 @@ def _overlay_frames(pool: "SessionPool", sess, k: int,
     return fr
 
 
-def _session_rows(pool: "SessionPool", sess, k: int,
-                  out_row: Optional[np.ndarray]) -> np.ndarray:
-    """The banked logits rows ``[0, cursor)`` — from the device output
-    bank (chunked) or the host row list (per-frame)."""
-    n_classes = pool.engine.n_classes
-    if pool.chunk_frames:
-        if out_row is None or not sess.cursor:
-            return np.zeros((0, n_classes), np.float32)
-        return np.asarray(out_row[:sess.cursor], np.float32).copy()
-    if not sess.rows:
-        return np.zeros((0, n_classes), np.float32)
-    return np.stack(sess.rows).astype(np.float32)
+def _session_rows(pool: "SessionPool", sess,
+                  out_row: np.ndarray) -> np.ndarray:
+    """The banked logits rows ``[0, cursor)`` of the device output bank."""
+    if not sess.cursor:
+        return np.zeros((0, pool.engine.n_classes), np.float32)
+    return np.asarray(out_row[:sess.cursor], np.float32).copy()
 
 
 def _snap(pool: "SessionPool", sess, k: int, layer_rows, tel_col,
@@ -200,7 +193,7 @@ def _snap(pool: "SessionPool", sess, k: int, layer_rows, tel_col,
             arrays[f"layer{i}/{name}"] = np.asarray(val, np.float32).copy()
     arrays["telemetry"] = np.asarray(np.stack(tel_col), np.float32)
     arrays["frames"] = _overlay_frames(pool, sess, k, frames_row)
-    arrays["rows"] = _session_rows(pool, sess, k, out_row)
+    arrays["rows"] = _session_rows(pool, sess, out_row)
     return SessionSnapshot(meta=_session_meta(sess), arrays=arrays)
 
 
@@ -222,7 +215,7 @@ def snapshot_session(pool: "SessionPool", req_id: int) -> SessionSnapshot:
              state.telemetry.overflow_steps[:, k],
              state.telemetry.steps[:, k]),
             pool._frames[k],
-            pool._out[k] if pool._out is not None else None,
+            pool._out[k],
         ))
     return _snap(pool, sess, k, layer_rows, tel_col, frames_row, out_row)
 
@@ -245,7 +238,7 @@ def snapshot_pool(pool: "SessionPool") -> PoolCheckpoint:
                    state.telemetry.overflow_steps[:, k],
                    state.telemetry.steps[:, k])
         sessions.append(_snap(pool, sess, k, layer_rows, tel_col,
-                              frames[k], out[k] if out is not None else None))
+                              frames[k], out[k]))
     meta = {
         "format": FORMAT,
         "version": VERSION,
@@ -294,8 +287,6 @@ def _make_session(pool: "SessionPool", snap: SessionSnapshot, k: int,
         # this process's service, not the epoch of the dead one
         first_logit_wall=now_wall if m["had_first_logit"] else 0.0,
     )
-    if not pool.chunk_frames:
-        sess.rows = [np.array(r) for r in snap.arrays["rows"]]
     pool._slots[k] = sess
     pool._by_req[sess.req_id] = k
     # frames go through the standard staged-upload wave at the next
@@ -364,15 +355,14 @@ def restore_session(pool: "SessionPool", snap: SessionSnapshot) -> bool:
         if pool._mesh is not None:
             new_state = shardlib.shard_pool_state(new_state, pool._mesh)
         pool.state = new_state
-        if pool.chunk_frames:
-            rows = snap.arrays["rows"]
-            row_full = np.zeros((pool._out.shape[1], pool.engine.n_classes),
-                                np.float32)
-            row_full[:rows.shape[0]] = rows
-            out = _write_row(pool._out, jnp.asarray(row_full), kk)
-            if pool._mesh is not None:
-                out = shardlib.shard_slot_array(out, pool._mesh)
-            pool._out = out
+        rows = snap.arrays["rows"]
+        row_full = np.zeros((pool._out.shape[1], pool.engine.n_classes),
+                            np.float32)
+        row_full[:rows.shape[0]] = rows
+        out = _write_row(pool._out, jnp.asarray(row_full), kk)
+        if pool._mesh is not None:
+            out = shardlib.shard_slot_array(out, pool._mesh)
+        pool._out = out
     return True
 
 
@@ -416,9 +406,8 @@ def restore_into(pool: "SessionPool", ckpt: PoolCheckpoint) -> None:
     tel_o = np.array(base.telemetry.overflow_steps)
     tel_s = np.array(base.telemetry.steps)
     cursor = np.array(base.cursor)
-    out_np = (np.zeros((pool.capacity, pool._t_buf + pool.chunk_frames,
-                        pool.engine.n_classes), np.float32)
-              if pool.chunk_frames else None)
+    out_np = np.zeros((pool.capacity, pool._t_buf + pool.chunk_frames,
+                       pool.engine.n_classes), np.float32)
 
     now_wall = time.perf_counter()
     for snap in ckpt.sessions:
@@ -435,9 +424,8 @@ def restore_into(pool: "SessionPool", ckpt: PoolCheckpoint) -> None:
         tel_o[:, k] = snap.arrays["telemetry"][1]
         tel_s[:, k] = snap.arrays["telemetry"][2]
         cursor[k] = sess.cursor
-        if out_np is not None:
-            rows = snap.arrays["rows"]
-            out_np[k, :rows.shape[0]] = rows
+        rows = snap.arrays["rows"]
+        out_np[k, :rows.shape[0]] = rows
 
     new_state = PoolState(
         layers=tuple(t(**{f: jnp.asarray(a) for f, a in d.items()})
@@ -451,11 +439,10 @@ def restore_into(pool: "SessionPool", ckpt: PoolCheckpoint) -> None:
         if pool._mesh is not None:
             new_state = shardlib.shard_pool_state(new_state, pool._mesh)
         pool.state = new_state
-        if out_np is not None:
-            out = jnp.asarray(out_np)
-            if pool._mesh is not None:
-                out = shardlib.shard_slot_array(out, pool._mesh)
-            pool._out = out
+        out = jnp.asarray(out_np)
+        if pool._mesh is not None:
+            out = shardlib.shard_slot_array(out, pool._mesh)
+        pool._out = out
     if pool.obs is not None:
         pool.obs.fold_restore(n_sessions=len(ckpt.sessions),
                               seconds=time.perf_counter() - t0)

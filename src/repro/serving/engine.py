@@ -24,7 +24,7 @@ the XLA path is numerically identical.
 
 `SpartusEngine` is deliberately slow and simple — a Python loop per
 frame with host syncs for telemetry — because it is the parity oracle:
-the batched pool, the chunked tick loop and the async front-end are all
+the batched pool's chunked tick loop and the async front-end are both
 pinned against its logits at 1e-5 (see docs/serving.md).  The shared
 CBCSC export (`pack_lstm_layer`, via `PackedSpartusModel`) enforces
 `blen_for(gamma)` at pack time and fixes each layer's SpMV route —
@@ -76,7 +76,6 @@ class EngineConfig:
     m: int = 64                # PEs per column (CBCSC granularity)
     capacity_frac: float = 0.5  # NZI capacity as fraction of columns
     use_pallas: bool = False
-    quant_bits: int = 8
     # SpMV implementation: "auto" routes layers with S*(1-gamma) >= 1 to the
     # dense-gather mirror (ops.spmv_use_dense_gather); "scatter" forces the
     # CBCSC scatter path, "dense" forces the mirror.

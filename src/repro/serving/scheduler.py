@@ -8,35 +8,30 @@ slot dimension.
 
 Lifecycle of a request:
 
-  queued ──admit──> active(slot k) ──per-frame steps──> finished
-            ^                                              │
-            └── backpressure: waits while no slot is free ─┘
+  queued ──admit──> active(slot k) ──chunks of up to C frames──> finished
+            ^                                                      │
+            └──── backpressure: waits while no slot is free ───────┘
 
-* `admit` attaches a request to a free slot and uploads its *whole*
-  utterance `[T, D]` into the slot's device-resident feature buffer once;
-  the slot's device state is re-initialised by the `reset` mask *inside*
-  the next `step_frames`, so admission never triggers an extra dispatch
-  or a recompile.  `admit_stream` admits a session whose utterance is
-  still arriving: frames are appended incrementally (`append_frames`),
-  the session simply idles ("starved") whenever it has consumed
-  everything received so far, and `finish_stream` marks the end of the
-  utterance.  A starved session costs nothing: it rides the chunk
+* `admit` attaches a request to a free slot and stages its *whole*
+  utterance `[T, D]`; the next chunk boundary uploads every staged
+  utterance in one wave into the slots' device-resident feature buffers,
+  and the slot's device state is re-initialised by the `reset` mask
+  *inside* that boundary's `step_chunk`, so admission never triggers an
+  extra dispatch or a recompile.  `admit_stream` admits a session whose
+  utterance is still arriving: frames are appended incrementally
+  (`append_frames`), the session simply idles ("starved") whenever it has
+  consumed everything received so far, and `finish_stream` marks the end
+  of the utterance.  A starved session costs nothing: it rides the chunk
   masked out, exactly like a free slot.
-* `step` advances all active slots one frame in ONE jitted call
-  (`step_frames`): each slot's current frame is gathered **on device** by
-  the cursor carried in `PoolState` — the tick moves zero frame bytes
-  host -> device — then the `[B, n_classes]` logits are fetched once,
-  each active slot's row appended to its request, and slots whose
-  utterance is exhausted retire.
-* `step_chunk` (``chunk_frames >= 1``) amortises that dispatch over up to
-  C frames: ONE `lax.scan`-backed dispatch advances every slot by up to C
-  frames, banking logits in a per-slot device output buffer, and the pool
-  runs **double-buffered**: while chunk t executes on device, the host
-  does chunk t's retirement bookkeeping and the next admissions, and the
+* `step_chunk` advances every active slot up to ``chunk_frames`` (C)
+  frames in ONE `lax.scan`-backed dispatch: each slot's frames are
+  gathered **on device** by the cursor carried in `PoolState`, and the
+  logits are banked in a per-slot device output buffer.  The pool runs
+  **double-buffered**: while chunk t executes on device, the host does
+  chunk t's retirement bookkeeping and the next admissions, and the
   device->host logits fetch of chunk t-1's retired sessions.  A finished
-  session's logits leave the device once, at retirement, instead of one
-  `[B, n_classes]` row fetch per tick.  Admission happens at chunk
-  boundaries only.
+  session's logits leave the device once, at retirement.  Admission
+  happens at chunk boundaries only.
 * ``stream_partials=True`` additionally snapshots **each chunk's** rows
   for every live slot (`engine.snapshot_chunk`, a `[B, C, n_classes]`
   device copy — not the whole output buffer) and surfaces them one chunk
@@ -44,22 +39,20 @@ Lifecycle of a request:
   chunk instead of only at retirement.  This is what the asyncio
   front-end (`serving/async_server.py`) feeds to its per-session queues.
 * `tick` is the non-blocking driver entry point: one call does at most
-  one dispatch (chunk or frame), retires sessions that finished without
-  needing another dispatch, and returns `(finished_results,
-  frames_advanced)` without waiting for the device (JAX async dispatch;
-  the only sync is the previous chunk's one-copy logits fetch).
+  one chunk dispatch, retires sessions that finished without needing
+  another dispatch, and returns `(finished_results, frames_advanced)`
+  without waiting for the device (JAX async dispatch; the only sync is
+  the previous chunk's logits fetch).
 * Idle slots ride along masked-out for free; the pool never reshapes (the
-  frame buffer length is bucketed to powers of two), so the step function
-  compiles once per (capacity, bucket).  Growth past
+  frame buffer length is bucketed to powers of two), so the chunk step
+  compiles once per (capacity, bucket, scan length).  Growth past
   ``max_buffer_frames`` is refused at admission time with a clear error
   instead of silently truncating.
 
 `serve_requests` is the batteries-included synchronous driver: feed it an
 iterable of requests with arrival times (in scheduler ticks), get
-per-request logits plus queue/service/latency metrics back;
-``chunk_frames=C`` selects the chunked path (0 keeps the per-frame oracle
-path).  It is also the parity oracle the async front-end is pinned
-against in tests.
+per-request logits plus queue/service/latency metrics back.  It is also
+the parity oracle the async front-end is pinned against in tests.
 """
 from __future__ import annotations
 
@@ -248,7 +241,6 @@ class _Session:
     partials_paused: bool = False  # slow consumer: skip snapshot_chunk
     #                                entries for this slot until resumed
     first_logit_wall: float = 0.0  # 0.0 = no logits surfaced yet
-    rows: List[np.ndarray] = dataclasses.field(default_factory=list)
 
     @property
     def done(self) -> bool:
@@ -318,6 +310,7 @@ class ServeStats:
     p95_latency_s: float
     p50_turnaround_steps: float
     p95_turnaround_steps: float
+    chunk_frames: int     # C, frames per dispatch
     # aggregated device-side telemetry (telemetry.measured_sparsity output),
     # the input to hwsim.spartus_model.evaluate_from_telemetry:
     sparsity: Dict[str, float] = dataclasses.field(default_factory=dict)
@@ -325,16 +318,13 @@ class ServeStats:
     # in-flight sessions were drained into truncated RequestResults:
     truncated: bool = False
     # dispatch amortisation: jitted device dispatches issued and their
-    # ratio to frames served — the per-frame path pays ~1/B dispatches per
-    # frame, the chunked path ~1/(B*C):
-    chunk_frames: int = 0            # 0 = per-frame path
+    # ratio to frames served — a full pool of B slots pays ~1/(B*C):
     n_dispatches: int = 0
     dispatches_per_frame: float = 0.0
     # mean fraction of each step_chunk call's wall time the host spent on
     # useful work after the dispatch returned (retirement bookkeeping, the
     # device-side snapshot, the previous chunk's logits fetch) — all
-    # concurrent with the in-flight device chunk; 0.0 on the per-frame
-    # path, which syncs on its logits every tick:
+    # concurrent with the in-flight device chunk:
     host_overlap_frac: float = 0.0
     # tail latency + streaming responsiveness under concurrency:
     p99_latency_s: float = 0.0
@@ -345,7 +335,7 @@ class ServeStats:
     p99_queue_wait_s: float = 0.0
     # time-to-first-logit: how long a client waits before logits start
     # streaming back (== full latency when logits only surface at
-    # retirement, i.e. the sync chunked path without stream_partials):
+    # retirement, i.e. the sync pool without stream_partials):
     p50_ttfl_s: float = 0.0
     p95_ttfl_s: float = 0.0
     p99_ttfl_s: float = 0.0
@@ -368,7 +358,7 @@ def aggregate_stats(
     wall_s: float,
     sparsity: Dict[str, float],
     truncated: bool = False,
-    chunk_frames: int = 0,
+    chunk_frames: int,
     n_dispatches: int = 0,
     host_overlap_frac: float = 0.0,
     bytes_per_slot: float = 0.0,
@@ -423,12 +413,11 @@ def _frame_bucket(n: int, floor: int = 64) -> int:
 class SessionPool:
     """Fixed-capacity pool of device-resident streaming sessions.
 
-    Request features live on device: ``admit`` uploads the whole utterance
-    `[T, D]` into the slot's row of a `[B, T_buf, D]` buffer once, and every
-    tick gathers each slot's current frame by the device cursor in
-    ``PoolState`` — the steady state issues zero per-tick host staging
-    copies (the old `step_batch` path re-staged every slot's frame on host
-    each tick, which at large hidden sizes cost more than the math).
+    Request features live on device: the admission wave uploads each
+    utterance `[T, D]` into its slot's row of a `[B, T_buf, D]` buffer
+    once, and every chunk gathers each slot's frames by the device cursor
+    in ``PoolState`` — the steady state issues zero per-tick host staging
+    copies.
 
     ``admit_stream`` admits a session before its utterance is complete:
     `append_frames` stages further frame blocks (uploaded one jitted wave
@@ -436,16 +425,13 @@ class SessionPool:
     and `cancel` abandons it (the slot frees at the next boundary).  A
     session that has consumed everything received so far simply idles.
 
-    With ``chunk_frames=C >= 1`` the pool runs the chunked tick loop:
-    ``step_chunk`` advances every active slot up to C frames in ONE
-    dispatch and banks logits in a per-slot device output buffer
-    `[B, T_buf, n_classes]`; retired sessions' logits are fetched once, at
-    retirement, double-buffered one chunk behind the in-flight dispatch.
-    ``stream_partials=True`` also snapshots each chunk's `[B, C,
-    n_classes]` rows so live sessions stream partial logits per chunk
-    (``take_partials``).  A chunked pool steps with
-    ``step_chunk``/``flush``/``tick`` only (``step`` raises: the two modes
-    account logits differently).
+    ``step_chunk`` advances every active slot up to ``chunk_frames=C >=
+    1`` frames in ONE dispatch and banks logits in a per-slot device
+    output buffer `[B, T_buf + C, n_classes]`; retired sessions' logits
+    are fetched once, at retirement, double-buffered one chunk behind the
+    in-flight dispatch.  ``stream_partials=True`` also snapshots each
+    chunk's `[B, C, n_classes]` rows so live sessions stream partial
+    logits per chunk (``take_partials``).
 
     An utterance longer than ``max_buffer_frames`` (whether declared at
     admission or accumulated by appends) is rejected with a ValueError:
@@ -482,7 +468,7 @@ class SessionPool:
     }
 
     def __init__(self, engine: BatchedSpartusEngine, capacity: int,
-                 max_frames: int = 64, chunk_frames: int = 0,
+                 max_frames: int = 64, chunk_frames: int = 8,
                  max_buffer_frames: Optional[int] = None,
                  stream_partials: bool = False,
                  n_devices: Optional[int] = None,
@@ -490,8 +476,8 @@ class SessionPool:
                  faults: Optional[FaultInjector] = None):
         if capacity < 1:
             raise ValueError("capacity must be >= 1")
-        if chunk_frames < 0:
-            raise ValueError("chunk_frames must be >= 0 (0 = per-frame)")
+        if chunk_frames < 1:
+            raise ValueError("chunk_frames must be >= 1")
         self.engine = engine
         self.capacity = capacity
         self.chunk_frames = chunk_frames
@@ -524,13 +510,10 @@ class SessionPool:
         # per-slot utterance lengths (device) — the chunk masks a slot off
         # once its cursor reaches its length:
         self._lengths = jnp.zeros((capacity,), jnp.int32)
-        # chunked mode: device logits buffer + retirements pending their
-        # (overlapped) host fetch.  The time axis is padded by
-        # chunk_frames so the chunk's banking slice never clamps: rows
-        # past a session's length are scratch no reader consumes.
-        self._out: Optional[jax.Array] = (
-            engine.init_out_buf(capacity, self._t_buf + chunk_frames)
-            if chunk_frames else None)
+        # device logits bank.  The time axis is padded by chunk_frames so
+        # the chunk's banking slice never clamps: rows past a session's
+        # length are scratch no reader consumes.
+        self._out = engine.init_out_buf(capacity, self._t_buf + chunk_frames)
         if self._mesh is not None:
             # one placement pass at construction; the step functions
             # donate every slab, so the sharding persists tick over tick.
@@ -538,13 +521,14 @@ class SessionPool:
             self._frames = shardlib.shard_slot_array(self._frames, self._mesh)
             self._lengths = shardlib.shard_slot_array(self._lengths,
                                                       self._mesh)
-            if self._out is not None:
-                self._out = shardlib.shard_slot_array(self._out, self._mesh)
+            self._out = shardlib.shard_slot_array(self._out, self._mesh)
+        # retirements and streamed chunks pending their (overlapped) host
+        # fetch:
         self._pending: List[_PendingChunk] = []
         self._pending_partials: List[_PendingPartials] = []
         self._partials: List[PartialLogits] = []
         # admissions staged host-side, flushed to device in ONE batched
-        # upload at the next step/chunk boundary; appends staged likewise:
+        # upload at the next chunk boundary; appends staged likewise:
         self._staged: List[Tuple[int, np.ndarray]] = []
         self._staged_appends: List[Tuple[int, int, np.ndarray]] = []
         # observability: buffer growths (should be 0 when pre-sized),
@@ -658,9 +642,9 @@ class SessionPool:
 
     @property
     def has_pending(self) -> bool:
-        """Chunked mode: retired sessions (or streamed chunks) whose host
-        fetch is still outstanding (resolved by the next ``step_chunk``,
-        ``tick`` or ``flush``)."""
+        """Retired sessions (or streamed chunks) whose host fetch is still
+        outstanding (resolved by the next ``step_chunk``, ``tick`` or
+        ``flush``)."""
         with self._state_lock:
             return bool(self._pending or self._pending_partials
                         or self._partials)
@@ -729,7 +713,7 @@ class SessionPool:
             n_recv=n, last_step=now - 1)
         self._by_req[req_id] = k
         # host-side staging only; the device upload happens once
-        # per admission wave, at the next step/chunk boundary.
+        # per admission wave, at the next chunk boundary.
         # Zero-length stagings still clear the slot's stale device
         # length from its previous occupant.
         self._staged.append((k, feats))
@@ -836,25 +820,17 @@ class SessionPool:
         host copies are made for it until ``resume_partials``.  The
         missed range stays recoverable via ``peek_rows`` (or the final
         ``RequestResult``) — this is the pool half of the async server's
-        bounded-queue slow-consumer policy.  Chunked pools only: the
-        per-frame path has no logits bank to backfill from, so pausing
-        there would silently drop rows."""
-        if not self.chunk_frames:
-            raise RuntimeError("pause_partials requires a chunked pool "
-                               "(chunk_frames >= 1)")
+        bounded-queue slow-consumer policy."""
         self._live(req_id).partials_paused = True
 
     def resume_partials(self, req_id: int) -> None:
         """Re-enable per-chunk partial snapshots for a live session (the
         consumer drained; the caller backfills the gap via ``peek_rows``)."""
-        if not self.chunk_frames:
-            raise RuntimeError("resume_partials requires a chunked pool "
-                               "(chunk_frames >= 1)")
         self._live(req_id).partials_paused = False
 
     def peek_rows(self, req_id: int, t0: int = 0) -> np.ndarray:
         """Fetch a live session's banked logits rows ``[t0, cursor)`` from
-        the device output buffer (chunked mode only).
+        the device output buffer.
 
         This is the slow-consumer backfill path: rows the partial stream
         skipped while the session was paused are still in the logits bank
@@ -864,9 +840,6 @@ class SessionPool:
         on the in-flight chunk (the rows include frames it is writing) —
         an explicitly rare, caller-initiated sync, not a steady-state one.
         """
-        if not self.chunk_frames:
-            raise RuntimeError("peek_rows requires a chunked pool "
-                               "(chunk_frames >= 1)")
         sess = self._live(req_id)
         hi = sess.cursor
         if t0 >= hi:
@@ -919,13 +892,12 @@ class SessionPool:
             grown = shardlib.shard_slot_array(grown, self._mesh)
         # lint: allow(eager-scatter) one-time realloc
         self._frames = grown.at[:, :old_t, :].set(self._frames)
-        if self._out is not None:
-            out = jnp.zeros((self.capacity, new_t + self.chunk_frames,
-                             self.engine.n_classes), jnp.float32)
-            if self._mesh is not None:
-                out = shardlib.shard_slot_array(out, self._mesh)
-            self._out = out.at[  # lint: allow(eager-scatter) one-time realloc
-                :, :old_t + self.chunk_frames, :].set(self._out)
+        out = jnp.zeros((self.capacity, new_t + self.chunk_frames,
+                         self.engine.n_classes), jnp.float32)
+        if self._mesh is not None:
+            out = shardlib.shard_slot_array(out, self._mesh)
+        self._out = out.at[  # lint: allow(eager-scatter) one-time realloc
+            :, :old_t + self.chunk_frames, :].set(self._out)
         self._t_buf = new_t
         self.n_frame_grows += 1
 
@@ -1030,70 +1002,13 @@ class SessionPool:
             reset[k] = sess.needs_reset
         return active, reset
 
-    # -- per-frame tick loop -------------------------------------------------
-
-    def step(self, now: int) -> List[RequestResult]:
-        """Advance every active session one frame (one jitted call).
-        Returns the requests that finished on this tick."""
-        if self.chunk_frames:
-            raise RuntimeError(
-                "this pool was built with chunk_frames >= 1; "
-                "drive it with step_chunk()/flush(), not step()")
-        self._reap_cancelled()
-        active, reset = self._masks()
-        if not active.any():
-            return []
-        with self._tracer.span("admission_upload", self._boundary):
-            self._flush_uploads()
-        self._fire("dispatch")
-
-        with self._tracer.span("dispatch") as disp, self._state_lock:
-            self.state, logits = self.engine.step_frames(
-                self.state, self._frames, self._dev1d(active),
-                self._dev1d(reset))
-        self.n_dispatches += 1
-        self._tally_matvec(1)
-        with self._tracer.span("snapshot_fetch", self._boundary):
-            logits_np = np.asarray(logits)      # ONE device->host fetch/tick
-        self._tally(fetch_rows=logits_np.shape[0],
-                    fetch_rows_kept=int(active.sum()),
-                    fetch_bytes=logits_np.nbytes)
-
-        finished: List[RequestResult] = []
-        for k, sess in enumerate(self._slots):
-            if sess is None:
-                continue
-            sess.needs_reset = False
-            if not active[k]:
-                continue                        # starved: rode along masked
-            row = logits_np[k].copy()           # detach from the batch row
-            sess.rows.append(row)
-            if not sess.first_logit_wall:
-                sess.first_logit_wall = time.perf_counter()
-            if self.stream_partials:
-                self._partials.append(PartialLogits(
-                    req_id=sess.req_id, t0=sess.cursor, rows=row[None]))
-            sess.cursor += 1
-            sess.last_step = now
-            if sess.done:
-                finished.append(sess.result(np.stack(sess.rows)))
-                self._free(k)
-        if self.obs is not None:
-            self.obs.fold_results(finished)
-            self._fold_boundary(
-                n_active=int(active.sum()), frames=int(active.sum()),
-                dispatch_s=disp.seconds,
-                chunk_s=time.perf_counter() - disp.t0,
-                overlap=0.0, retirements=len(finished))
-        return finished
-
     def _free(self, k: int) -> None:
         sess = self._slots[k]
         if sess is not None:
             del self._by_req[sess.req_id]
         self._slots[k] = None
 
-    # -- chunked tick loop ---------------------------------------------------
+    # -- tick loop -----------------------------------------------------------
 
     def max_chunk_advance(self) -> int:
         """Ticks the next ``step_chunk`` will consume: min(chunk_frames,
@@ -1125,9 +1040,6 @@ class SessionPool:
         ``PartialLogits`` (``take_partials``) on the same one-chunk-later
         cadence.  Call ``flush()`` after the last chunk to collect the
         tail."""
-        if not self.chunk_frames:
-            raise RuntimeError(
-                "this pool was built with chunk_frames=0; use step()")
         self._reap_cancelled()
         self._queue_done_retirements()
         active, reset = self._masks()
@@ -1249,41 +1161,23 @@ class SessionPool:
     def flush(self) -> List[RequestResult]:
         """Resolve retirements (and streamed partials) still pending from
         the last dispatched chunk (the double-buffer tail)."""
-        if self.chunk_frames:
-            self._reap_cancelled()
-            self._queue_done_retirements()
+        self._reap_cancelled()
+        self._queue_done_retirements()
         with self._tracer.span("snapshot_fetch", self._boundary):
             return self._resolve()
 
     def tick(self, now: int) -> Tuple[List[RequestResult], int]:
-        """Non-blocking driver entry: at most one dispatch, in either mode.
+        """Non-blocking driver entry: at most one chunk dispatch.
 
         Returns ``(finished_results, frames_advanced)``.  Safe to call
         with nothing to do (returns ``([], 0)``); handles cancellations,
         dispatch-free retirements and the double-buffer tail.  The call
         does not wait for the device — the only host sync is the previous
-        chunk's one-copy logits fetch (per-frame mode syncs on its own
-        logits, as always)."""
-        if self.chunk_frames:
-            adv = self.max_chunk_advance()
-            if adv:
-                return self.step_chunk(now), adv
-            return self.flush(), 0
-        self._reap_cancelled()
-        finished: List[RequestResult] = []
-        # dispatch-free retirements (finished streams with nothing left):
-        for k, sess in enumerate(self._slots):
-            if sess is not None and sess.done:
-                finished.append(sess.result(
-                    np.stack(sess.rows) if sess.rows else np.zeros(
-                        (0, self.engine.n_classes), np.float32)))
-                self._free(k)
-        if self.obs is not None:
-            self.obs.fold_results(finished)
-        active, _ = self._masks()
-        if active.any():
-            return finished + self.step(now), 1
-        return finished, 0
+        chunk's logits fetch."""
+        adv = self.max_chunk_advance()
+        if adv:
+            return self.step_chunk(now), adv
+        return self.flush(), 0
 
     def take_partials(self) -> List[PartialLogits]:
         """Drain the streamed per-chunk logits resolved so far (in frame
@@ -1377,9 +1271,9 @@ class SessionPool:
         """Evict every in-flight session, returning truncated
         ``RequestResult``s with the logits produced so far (used when
         ``serve_requests`` hits ``max_steps`` mid-stream, so partial work is
-        surfaced instead of silently dropped).  In chunked mode the
-        already-finished (pending-fetch) sessions are resolved first, then
-        partial sessions' rows are read from the device output buffer —
+        surfaced instead of silently dropped).  The already-finished
+        (pending-fetch) sessions are resolved first, then partial
+        sessions' rows are read from the device output buffer —
         truncation granularity is the chunk."""
         n_classes = self.engine.n_classes
         self._staged.clear()    # evicted sessions' uploads must not land
@@ -1391,13 +1285,9 @@ class SessionPool:
             for k, sess in enumerate(self._slots):
                 if sess is None:
                     continue
-                if self.chunk_frames:
-                    logits = (np.asarray(self._out[k, :sess.cursor])
-                              if sess.cursor
-                              else np.zeros((0, n_classes), np.float32))
-                else:
-                    logits = (np.stack(sess.rows) if sess.rows
-                              else np.zeros((0, n_classes), np.float32))
+                logits = (np.asarray(self._out[k, :sess.cursor])
+                          if sess.cursor
+                          else np.zeros((0, n_classes), np.float32))
                 drained.append(sess.result(logits, truncated=not sess.done,
                                            finish_step=now))
                 self._free(k)
@@ -1433,8 +1323,7 @@ class SessionPool:
             total = sum(nbytes(l)
                         for l in jax.tree_util.tree_leaves(self.state))
             total += nbytes(self._frames) + nbytes(self._lengths)
-            if self._out is not None:
-                total += nbytes(self._out)
+            total += nbytes(self._out)
         total += self.engine.weight_bytes()
         per_slot = total / self.capacity
         if self.obs is not None:
@@ -1518,7 +1407,7 @@ def serve_requests(
     requests: Iterable[RequestLike],
     capacity: int,
     max_steps: Optional[int] = None,
-    chunk_frames: int = 0,
+    chunk_frames: int = 8,
     n_devices: Optional[int] = None,
     observability: Optional[PoolObservability] = None,
 ) -> Tuple[List[RequestResult], ServeStats]:
@@ -1529,20 +1418,18 @@ def serve_requests(
     waits (backpressure) and is admitted as soon as a slot frees.  Returns
     per-request results (logits + latency) and aggregate throughput stats.
 
-    ``chunk_frames=C >= 1`` selects the chunked tick loop: one device
-    dispatch advances all active sessions up to C frames, logits are
-    banked on device and fetched once per session at retirement
-    (double-buffered behind the next chunk), and admission happens at
-    chunk boundaries — higher throughput (fewer dispatches/frame), up to
-    C-1 ticks of extra queueing latency.  ``chunk_frames=0`` (default)
-    keeps the per-frame path, which is the chunked path's parity oracle.
+    ``chunk_frames=C >= 1``: one device dispatch advances all active
+    sessions up to C frames, logits are banked on device and fetched once
+    per session at retirement (double-buffered behind the next chunk),
+    and admission happens at chunk boundaries — fewer dispatches per
+    frame for up to C-1 ticks of extra queueing latency.
 
     If ``max_steps`` stops the run early, in-flight sessions are drained
     into ``RequestResult``s with ``truncated=True`` holding their partial
     logits (never-admitted requests have no partial logits and are simply
-    absent from the results); ``stats.truncated`` flags the cut — in
-    chunked mode the cut lands on the first chunk boundary at or past
-    ``max_steps``, so partial logits come in chunk granularity.
+    absent from the results); ``stats.truncated`` flags the cut — the
+    cut lands on the first chunk boundary at or past ``max_steps``, so
+    partial logits come in chunk granularity.
     ``total_steps`` counts only ticks that advanced at least one slot, so
     frames/step utilisation is not diluted by idle fast-forward ticks.
 
@@ -1584,17 +1471,10 @@ def serve_requests(
         # above makes idle iterations rare, but total_steps feeds per-step
         # utilisation metrics and must stay exact if the loop ever changes
         # (e.g. wall-clock-paced ticking instead of fast-forward).
-        if chunk_frames:
-            adv = pool.max_chunk_advance()
-            results.extend(pool.step_chunk(now) if adv else pool.flush())
-            total_steps += adv
-            now += max(adv, 1)
-        else:
-            dispatched = pool.n_active > 0
-            results.extend(pool.step(now))
-            if dispatched:
-                total_steps += 1
-            now += 1
+        finished, adv = pool.tick(now)
+        results.extend(finished)
+        total_steps += adv
+        now += max(adv, 1)
         if max_steps is not None and total_steps >= max_steps:
             truncated = bool(pending or waiting or pool.n_active)
             results.extend(pool.drain(now - 1))

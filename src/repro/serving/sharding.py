@@ -5,9 +5,9 @@ balanced workload; the serving-pool analogue is to partition the pool's
 *slot* dimension across devices.  Every per-slot slab the pool owns —
 layer state, delta memories, frame buffers, cursors, lengths, the logits
 bank, telemetry — is placed with a `NamedSharding` over a 1-D
-``("data",)`` mesh, so the existing jitted `step_frames`/`step_chunk`
-dispatches run SPMD across all devices: each device advances its own
-block of slots and, because slots are fully independent (the batched
+``("data",)`` mesh, so the existing jitted `step_chunk` dispatch runs
+SPMD across all devices: each device advances its own block of slots
+and, because slots are fully independent (the batched
 kernels are vmaps of per-session ops and telemetry is kept per-slot),
 the steady-state chunk contains **zero cross-device communication** —
 the partitioned program is the single-device program, n_devices times in

@@ -4,11 +4,10 @@ pool and the asyncio front-end.
 The batch-1 `SpartusEngine` appends a Python dict per (step, layer) with
 `int()` host syncs on every frame — fine for one utterance, fatal for a
 server.  Here telemetry is three `[L, B]` accumulators (layer x slot)
-that live on device and are folded into `BatchedSpartusEngine.step_batch`
-itself, so the steady state does zero host round-trips.  The accumulators
-ride the chunked tick loop for free: they are part of the `lax.scan`
-carry in `step_chunk`, so one chunk dispatch folds in L x C (layer,
-frame) samples — only frames a slot actually consumed count, since
+that live on device and ride the `lax.scan` carry of
+`BatchedSpartusEngine.step_chunk`, so the steady state does zero host
+round-trips and one chunk dispatch folds in L x C (layer, frame)
+samples — only frames a slot actually consumed count, since
 `accumulate` masks by the per-iteration active mask.  Keeping the slot
 dimension (rather than summing over it per step) is what lets the
 sharded pool (docs/serving.md, slot-dimension data parallelism) carry

@@ -2,11 +2,11 @@
 
 `step_chunk` advances every active slot up to C frames in ONE dispatch
 (`lax.scan` over the per-frame core) and banks logits in a per-slot
-device output buffer; the chunked `SessionPool`/`serve_requests` path
-overlaps retirement fetches and admission bookkeeping with the in-flight
-chunk.  The per-frame `step_frames` path is the parity oracle: every test
-here pins chunked logits/state/telemetry against it (or the batch-1
-engine) at 1e-5.
+device output buffer; `SessionPool`/`serve_requests` overlap retirement
+fetches and admission bookkeeping with the in-flight chunk.  One-frame
+chunks (``chunk_frames=1``) and the batch-1 engine are the parity
+oracles: every test here pins C-frame logits/state/telemetry against
+them at 1e-5.
 """
 import numpy as np
 import jax
@@ -16,6 +16,7 @@ import pytest
 from repro.models import lstm_am
 from repro.serving import telemetry as tele
 from repro.serving import (
+    AsyncSpartusServer,
     BatchedSpartusEngine,
     EngineConfig,
     SpartusEngine,
@@ -58,12 +59,14 @@ def _utterance(key, t):
 # -- engine level ------------------------------------------------------------
 
 
-def test_step_chunk_matches_step_frames(engines):
-    """One chunk dispatch == the same frames through per-frame step_frames:
-    identical logits in the output buffer, identical final layer state,
-    cursor and telemetry — including slots that go inactive mid-chunk."""
+@pytest.mark.parametrize("c", [4, 8])
+def test_step_chunk_matches_single_frame_chunks(engines, c):
+    """One C-frame chunk dispatch == the same frames through C one-frame
+    chunks: identical logits in the output buffer, identical final layer
+    state, cursor and telemetry — including slots that go inactive
+    mid-chunk."""
     _, eb = engines
-    lens = np.array([7, 4, 6], np.int32)
+    lens = np.array([c - 1, c // 2, c], np.int32)
     feats = [_utterance(200 + i, int(t)) for i, t in enumerate(lens)]
     frames = np.zeros((3, 8, INPUT_DIM), np.float32)
     for i, f in enumerate(feats):
@@ -71,25 +74,23 @@ def test_step_chunk_matches_step_frames(engines):
     frames = jnp.asarray(frames)
 
     s_ref = eb.init_state(3)
-    ref_rows = [[] for _ in lens]
-    for t in range(int(lens.max())):
-        act = np.array([t < l for l in lens])
-        s_ref, logits = eb.step_frames(s_ref, frames, act, np.full(3, t == 0))
-        logits = np.asarray(logits)
-        for b in range(3):
-            if act[b]:
-                ref_rows[b].append(logits[b])
+    ref = eb.init_out_buf(3, 9)
+    for t in range(c):
+        s_ref, ref = eb.step_chunk(s_ref, frames, lens, np.ones(3, bool),
+                                   np.full(3, t == 0), ref, n_frames=1)
+    ref = np.asarray(ref)
 
     s = eb.init_state(3)
-    out = eb.init_out_buf(3, 8)
+    out = eb.init_out_buf(3, 8 + c)
     s, out = eb.step_chunk(s, frames, lens, np.ones(3, bool),
-                           np.ones(3, bool), out, n_frames=8)
+                           np.ones(3, bool), out, n_frames=c)
     out = np.asarray(out)
     for b in range(3):
         # rows past lens[b] are scratch (never consumed by any reader)
-        np.testing.assert_allclose(out[b, :lens[b]], np.stack(ref_rows[b]),
+        np.testing.assert_allclose(out[b, :lens[b]], ref[b, :lens[b]],
                                    atol=1e-5)
     np.testing.assert_array_equal(np.asarray(s.cursor), lens)
+    np.testing.assert_array_equal(np.asarray(s_ref.cursor), lens)
     for a, b in zip(jax.tree.leaves(s_ref.layers), jax.tree.leaves(s.layers)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
     np.testing.assert_array_equal(np.asarray(s_ref.telemetry.steps),
@@ -121,8 +122,8 @@ def test_step_chunk_donates_state_and_out_buf(engines):
 # -- scheduler level ---------------------------------------------------------
 
 
-def test_chunked_vs_per_frame_parity_grid(engines):
-    """Chunked serving == per-frame serving == batch-1 engine over a grid
+def test_chunk_sizes_parity_grid(engines):
+    """C-frame chunks == one-frame chunks == batch-1 engine over a grid
     of (capacity, chunk_frames) with ragged utterance lengths, staggered
     arrivals, mid-chunk retirements and chunk-boundary admissions."""
     e1, eb = engines
@@ -133,7 +134,7 @@ def test_chunked_vs_per_frame_parity_grid(engines):
             for i in range(len(lens))]
 
     for capacity in (2, 4):
-        base, _ = serve_requests(eb, reqs, capacity=capacity)
+        base, _ = serve_requests(eb, reqs, capacity=capacity, chunk_frames=1)
         for chunk in (1, 3, 8, 32):
             results, stats = serve_requests(eb, reqs, capacity=capacity,
                                             chunk_frames=chunk)
@@ -141,7 +142,7 @@ def test_chunked_vs_per_frame_parity_grid(engines):
             for r in results:
                 np.testing.assert_allclose(r.logits, refs[r.req_id],
                                            atol=1e-5)
-                # and bit-level against the per-frame pool path:
+                # and against one-frame chunks:
                 np.testing.assert_allclose(
                     r.logits, base[r.req_id].logits, atol=1e-5)
             assert stats.total_frames == sum(lens)
@@ -171,7 +172,7 @@ def test_midchunk_retirement_and_boundary_admission(engines):
 
 
 def test_chunked_max_steps_drains_partial(engines):
-    """max_steps in chunked mode truncates at a chunk boundary: partial
+    """max_steps truncates at a chunk boundary: partial
     logits (chunk granularity) still match the batch-1 prefix."""
     e1, eb = engines
     feats = [_utterance(240, 8), _utterance(241, 8)]
@@ -219,14 +220,18 @@ def test_cancel_during_retirement_window_suppresses_result(engines):
         pool.cancel(99)
 
 
-def test_chunked_pool_rejects_per_frame_step_and_vice_versa(engines):
+def test_pool_rejects_chunk_frames_below_one(engines):
+    """A chunk advances at least one frame: the pool, the synchronous
+    driver and the async front end (through its pool) refuse
+    ``chunk_frames=0`` up front."""
     _, eb = engines
-    chunked = SessionPool(eb, capacity=2, chunk_frames=4)
-    with pytest.raises(RuntimeError, match="step_chunk"):
-        chunked.step(now=0)
-    per_frame = SessionPool(eb, capacity=2)
-    with pytest.raises(RuntimeError, match="chunk_frames=0"):
-        per_frame.step_chunk(now=0)
+    with pytest.raises(ValueError, match="chunk_frames"):
+        SessionPool(eb, capacity=2, chunk_frames=0)
+    with pytest.raises(ValueError, match="chunk_frames"):
+        serve_requests(eb, [StreamRequest(0, 0, _utterance(290, 3))],
+                       capacity=2, chunk_frames=0)
+    with pytest.raises(ValueError, match="chunk_frames"):
+        AsyncSpartusServer(eb, capacity=2, chunk_frames=0)
 
 
 def test_upload_growth_single_realloc_no_host_recopy(engines, monkeypatch):
@@ -440,46 +445,46 @@ def test_accumulate_layers_matches_per_layer_accumulate():
 
 def test_dispatch_amortisation_metrics(engines):
     """ServeStats surfaces the dispatch economy: C-frame chunks issue
-    ~1/C the dispatches of the per-frame path, and the overlap fraction
+    ~1/C the dispatches of one-frame chunks, and the overlap fraction
     is a sane [0, 1) number."""
     _, eb = engines
     reqs = [StreamRequest(i, 0, _utterance(270 + i, 16)) for i in range(4)]
-    _, per_frame = serve_requests(eb, reqs, capacity=4)
+    _, single = serve_requests(eb, reqs, capacity=4, chunk_frames=1)
     _, chunked = serve_requests(eb, reqs, capacity=4, chunk_frames=8)
 
-    assert per_frame.n_dispatches == 16      # one per tick
-    assert per_frame.dispatches_per_frame == pytest.approx(16 / 64)
+    assert single.n_dispatches == 16         # one per tick
+    assert single.dispatches_per_frame == pytest.approx(16 / 64)
+    assert single.total_steps == 16
     assert chunked.n_dispatches == 2         # 16 frames / 8-frame chunks
     assert chunked.dispatches_per_frame == pytest.approx(2 / 64)
     assert chunked.total_steps == 16
-    assert 0.0 <= chunked.host_overlap_frac < 1.0
-    assert per_frame.host_overlap_frac == 0.0
+    for stats in (single, chunked):
+        assert 0.0 <= stats.host_overlap_frac < 1.0
 
 
 # -- retirement gather -------------------------------------------------------
 
 
-def _step_batch_oracle(eb, feats, batch):
-    """Each utterance's logits from host-staged ``step_batch`` calls on a
+def _single_frame_oracle(eb, feats, batch):
+    """Each utterance's logits from one-frame ``step_chunk`` calls on a
     ``batch``-slot state, ``batch`` utterances at a time: bit-identical
-    to what the chunked pool banks at the same capacity."""
+    to what the pool banks in 4-frame chunks at the same capacity."""
     out = []
     for lo in range(0, len(feats), batch):
         group = feats[lo:lo + batch]
-        state = eb.init_state(batch)
-        rows = [[] for _ in group]
-        for t in range(max(f.shape[0] for f in group)):
-            x = np.zeros((batch, INPUT_DIM), np.float32)
-            act = np.zeros(batch, bool)
-            for b, f in enumerate(group):
-                if t < f.shape[0]:
-                    x[b], act[b] = f[t], True
-            state, logits = eb.step_batch(state, x, act,
-                                          np.full(batch, t == 0))
-            logits = np.asarray(logits)
-            for b in np.flatnonzero(act):
-                rows[b].append(logits[b])
-        out += [np.stack(r) for r in rows]
+        t_max = max(f.shape[0] for f in group)
+        frames = np.zeros((batch, t_max, INPUT_DIM), np.float32)
+        lens = np.zeros(batch, np.int32)
+        for b, f in enumerate(group):
+            frames[b, :f.shape[0]], lens[b] = f, f.shape[0]
+        frames = jnp.asarray(frames)
+        state, bank = eb.init_state(batch), eb.init_out_buf(batch, t_max + 1)
+        for t in range(t_max):
+            state, bank = eb.step_chunk(state, frames, lens, lens > 0,
+                                        np.full(batch, t == 0), bank,
+                                        n_frames=1)
+        bank = np.asarray(bank)
+        out += [bank[b, :f.shape[0]] for b, f in enumerate(group)]
     return out
 
 
@@ -558,7 +563,7 @@ RETIRE_CASES = {
 def test_retirement_gather_serves_bank_rows(engines, case):
     """Retiring sessions' rows leave the device in blocks of RETIRE_BLOCK
     gathered slots, the last block padded with a slot already in it.
-    Every served utterance equals the ``step_batch`` oracle bit for bit,
+    Every served utterance equals the one-frame-chunk oracle bit for bit,
     cancelled ones are never served, the boundary samples count the
     gathers and their R x T_pad rows, and every block count runs the one
     retirement program."""
@@ -573,7 +578,7 @@ def test_retirement_gather_serves_bank_rows(engines, case):
 
     assert sorted(r.req_id for r in results) == [
         i for i in range(len(feats)) if i not in cancelled]
-    refs = _step_batch_oracle(eb, feats, capacity)
+    refs = _single_frame_oracle(eb, feats, capacity)
     for r in results:
         np.testing.assert_array_equal(r.logits, refs[r.req_id])
 

@@ -28,7 +28,6 @@ import pytest
 
 from repro.analysis import cases as caselib
 from repro.analysis import contracts, hlo, lint
-from repro.analysis.cases import BuiltCase
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -385,22 +384,23 @@ def test_realiased_init_telemetry_fails_donation_probe(monkeypatch):
         capacity_frac=1.0))
 
     def build():
-        state = engine.init_state(4)
-        frames = jax.random.normal(jax.random.key(3),
-                                   (4, 8, caselib.INPUT_DIM), jnp.float32)
-        return BuiltCase(fn=engine._step_frames,
-                         args=(state, frames, jnp.ones((4,), bool),
-                               jnp.zeros((4,), bool)),
-                         kwargs={}, donate_argnums=(0,))
+        return caselib.built_pool_chunk(engine, caselib._feats())
 
-    case = caselib.ContractCase("step_frames/aliased-telemetry",
-                                "step_frames", build)
-    report = contracts.check_case(case)
-    assert not report.ok
-    assert [v.clause for v in report.violations] == ["donation"]
-    assert "donate" in report.violations[0].message
-    # the static alias map alone could NOT have caught it:
-    assert report.alias_entries == report.donated_leaves
+    # the static alias map alone can NOT catch it:
+    built = build()
+    text = hlo.compiled_text(built.fn, *built.args, **built.kwargs)
+    static = contracts.check_hlo(contracts.get_contract("step_chunk"), text,
+                                 donated_leaves=built.donated_leaf_count())
+    assert "donation" not in [v.clause for v in static]
+    assert hlo.alias_count(text) == built.donated_leaf_count()
+    # executing the donating chunk step does (fresh arguments: the probe
+    # consumes them):
+    probe = build()
+    violations = contracts.run_donation_probe(
+        "step_chunk", probe.fn, probe.args, probe.kwargs,
+        probe.donated_args())
+    assert [v.clause for v in violations] == ["donation"]
+    assert "donate" in violations[0].message
 
 
 IOTA_REVERT_SCRIPT = textwrap.dedent("""

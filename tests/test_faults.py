@@ -223,12 +223,12 @@ def test_rejected_admission_leaves_neighbours_bit_identical(
 # -- checkpoint / restore -----------------------------------------------------
 
 
-@pytest.mark.parametrize("capacity,chunk", [(2, 4), (4, 8), (3, 0)])
+@pytest.mark.parametrize("capacity,chunk", [(2, 4), (4, 8), (3, 1)])
 def test_checkpoint_restore_roundtrip_bit_identical(
         engines, workload, tmp_path, capacity, chunk):
     """Kill the pool mid-flight at a chunk boundary, restore from disk
     into a fresh pool, finish: every session's logits are bit-identical
-    to the uninterrupted run — over chunked and per-frame modes."""
+    to the uninterrupted run — over 4-, 8- and one-frame chunks."""
     _, eb = engines
     feats, refs = workload
     pool = SessionPool(eb, capacity, max_frames=16, chunk_frames=chunk)

@@ -120,7 +120,7 @@ def test_telemetry_totals_reduction_is_transfer_free(engine):
     (3, 4, None),     # chunked, multiple admission waves
     (2, 2, None),     # chunked, tiny chunks
     (4, 8, None),     # chunked, whole-utterance chunks
-    (3, 0, None),     # per-frame path
+    (3, 1, None),     # one-frame chunks
     (2, 4, 6),        # truncated by max_steps mid-run
 ])
 def test_counters_match_servestats(engine, workload, cap, chunk, max_steps):

@@ -42,24 +42,33 @@ def _utterance(key, t):
                       np.float32)
 
 
-def test_step_batch_matches_batch1(engines):
+def _one_frame_chunks(eb, state, frames, lengths, active, resets):
+    """Step ``state`` through ``len(resets)`` one-frame ``step_chunk``
+    dispatches over the ``[B, T, D]`` frame buffers, slots masked by
+    ``active`` and re-initialised where ``resets[t]`` says; returns
+    ``(state, out_buf)``."""
+    frames = jnp.asarray(frames)
+    b, t_buf = frames.shape[:2]
+    out = eb.init_out_buf(b, t_buf + 1)
+    for reset in resets:
+        state, out = eb.step_chunk(state, frames, lengths, active, reset,
+                                   out, n_frames=1)
+    return state, out
+
+
+def test_step_chunk_matches_batch1(engines):
     """All slots active with different utterances: each slot's logits are
     identical to running that utterance alone through SpartusEngine."""
     e1, eb = engines
     feats = [_utterance(i + 1, 10) for i in range(3)]
     ref = [np.asarray(e1.run_utterance(jnp.asarray(f))) for f in feats]
 
-    state = eb.init_state(3)
-    outs = [[] for _ in feats]
-    for t in range(10):
-        x = np.stack([f[t] for f in feats])
-        state, logits = eb.step_batch(state, x, np.ones(3, bool),
-                                      np.full(3, t == 0))
-        ln = np.asarray(logits)
-        for b in range(3):
-            outs[b].append(ln[b])
+    _, out = _one_frame_chunks(eb, eb.init_state(3), np.stack(feats),
+                               np.full(3, 10), np.ones(3, bool),
+                               [np.full(3, t == 0) for t in range(10)])
+    out = np.asarray(out)
     for b in range(3):
-        np.testing.assert_allclose(np.stack(outs[b]), ref[b], atol=1e-5)
+        np.testing.assert_allclose(out[b, :10], ref[b], atol=1e-5)
 
 
 def test_scheduler_staggered_and_early_finish(engines):
@@ -70,7 +79,7 @@ def test_scheduler_staggered_and_early_finish(engines):
     feats = [_utterance(10, 8), _utterance(11, 3), _utterance(12, 6)]
     reqs = [StreamRequest(0, 0, feats[0]), StreamRequest(1, 0, feats[1]),
             StreamRequest(2, 1, feats[2])]
-    results, stats = serve_requests(eb, reqs, capacity=2)
+    results, stats = serve_requests(eb, reqs, capacity=2, chunk_frames=1)
 
     assert [r.req_id for r in results] == [0, 1, 2]
     for r in results:
@@ -110,12 +119,12 @@ def test_telemetry_matches_batch1(model):
     sp1 = e1.measured_sparsity()
 
     # same utterance in slot 0 of a capacity-2 pool, slot 1 idle:
-    state = eb.init_state(2)
-    for t in range(12):
-        x = np.zeros((2, INPUT_DIM), np.float32)
-        x[0] = feats[t]
-        active = np.array([True, False])
-        state, _ = eb.step_batch(state, x, active, np.array([t == 0, False]))
+    frames = np.zeros((2, 12, INPUT_DIM), np.float32)
+    frames[0] = feats
+    state, _ = _one_frame_chunks(
+        eb, eb.init_state(2), frames, np.array([12, 0]),
+        np.array([True, False]),
+        [np.array([t == 0, False]) for t in range(12)])
     spb = eb.measured_sparsity(state)
 
     assert spb["temporal_sparsity"] == pytest.approx(sp1["temporal_sparsity"],
@@ -132,13 +141,16 @@ def test_telemetry_matches_batch1(model):
 def test_idle_slots_frozen(engines):
     """Inactive slots must not change state or contribute telemetry."""
     _, eb = engines
-    state = eb.init_state(2)
-    x = np.zeros((2, INPUT_DIM), np.float32)
-    x[0] = _utterance(40, 1)[0]
-    active = np.array([True, False])
-    state, _ = eb.step_batch(state, x, active, np.array([True, False]))
+    frames = np.zeros((2, 2, INPUT_DIM), np.float32)
+    frames[0] = _utterance(40, 2)
+    lengths = np.array([2, 0])
+    state, _ = _one_frame_chunks(eb, eb.init_state(2), frames, lengths,
+                                 np.array([True, False]),
+                                 [np.array([True, False])])
     before = jax.device_get(state.layers)
-    state2, _ = eb.step_batch(state, x, np.array([False, False]))
+    state2, _ = _one_frame_chunks(eb, state, frames, lengths,
+                                  np.array([False, False]),
+                                  [np.zeros(2, bool)])
     after = jax.device_get(state2.layers)
     for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
         np.testing.assert_array_equal(a, b)
@@ -150,40 +162,16 @@ def test_idle_slots_frozen(engines):
     np.testing.assert_array_equal(steps, [[1, 0], [1, 0]])
 
 
-def test_step_frames_matches_step_batch(engines):
-    """Device-resident frame buffers + device cursor == host-staged frames:
-    the two step entry points produce identical logits and state."""
-    _, eb = engines
-    feats = [_utterance(50 + i, 6) for i in range(2)]
-    frames = jnp.asarray(np.stack(feats))          # [B=2, T=6, D]
-
-    s_host = eb.init_state(2)
-    s_dev = eb.init_state(2)
-    for t in range(6):
-        x = np.stack([f[t] for f in feats])
-        active = np.ones(2, bool)
-        reset = np.full(2, t == 0)
-        s_host, l_host = eb.step_batch(s_host, x, active, reset)
-        s_dev, l_dev = eb.step_frames(s_dev, frames, active, reset)
-        np.testing.assert_array_equal(np.asarray(l_host), np.asarray(l_dev))
-    for a, b in zip(jax.tree.leaves(s_host.layers),
-                    jax.tree.leaves(s_dev.layers)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # the device cursor advanced once per consumed frame:
-    np.testing.assert_array_equal(np.asarray(s_dev.cursor), [6, 6])
-
-
-def test_step_frames_cursor_resets_midstream(engines):
+def test_step_chunk_cursor_resets_midstream(engines):
     """Re-admitting a new session into a used slot restarts its device
     cursor at frame 0 (reset mask), without touching the neighbour slot."""
     _, eb = engines
-    frames = jnp.asarray(np.stack([_utterance(60, 5), _utterance(61, 5)]))
-    state = eb.init_state(2)
-    active = np.ones(2, bool)
-    for t in range(3):
-        state, _ = eb.step_frames(state, frames, active, np.full(2, t == 0))
-    # slot 0 re-admitted (reset), slot 1 keeps streaming:
-    state, _ = eb.step_frames(state, frames, active, np.array([True, False]))
+    frames = np.stack([_utterance(60, 5), _utterance(61, 5)])
+    # three frames, then slot 0 re-admitted (reset), slot 1 streaming on:
+    resets = [np.full(2, t == 0) for t in range(3)] + [np.array([True,
+                                                                 False])]
+    state, _ = _one_frame_chunks(eb, eb.init_state(2), frames,
+                                 np.full(2, 5), np.ones(2, bool), resets)
     np.testing.assert_array_equal(np.asarray(state.cursor), [1, 4])
 
 
@@ -219,7 +207,8 @@ def test_max_steps_drains_partial_results(engines):
     e1, eb = engines
     feats = [_utterance(70, 8), _utterance(71, 8)]
     reqs = [StreamRequest(0, 0, feats[0]), StreamRequest(1, 0, feats[1])]
-    results, stats = serve_requests(eb, reqs, capacity=2, max_steps=3)
+    results, stats = serve_requests(eb, reqs, capacity=2, max_steps=3,
+                                    chunk_frames=1)
 
     assert stats.truncated
     assert stats.total_steps == 3
@@ -257,8 +246,8 @@ def test_total_steps_counts_only_dispatching_ticks(engines):
     # dispatches nothing (the driver must not count it as a step).
     from repro.serving.scheduler import SessionPool
     pool = SessionPool(eb, capacity=2)
-    assert pool.step(now=0) == []
-    assert pool.n_active == 0
+    assert pool.tick(now=0) == ([], 0)
+    assert pool.n_active == 0 and pool.n_dispatches == 0
 
 
 def test_admit_rejects_utterance_past_growth_limit(engines):
@@ -307,28 +296,28 @@ def test_append_rejects_frames_past_growth_limit(engines):
     assert results[0].logits.shape[0] == 30
 
 
-def test_incremental_admission_matches_full_admission(engines):
+@pytest.mark.parametrize("chunk", [1, 4])
+def test_incremental_admission_matches_full_admission(engines, chunk):
     """admit_stream + append_frames + finish_stream produces the same
-    logits as admitting the complete utterance (per-frame AND chunked) —
-    the contract the async front-end is built on."""
+    logits as admitting the complete utterance (one-frame and 4-frame
+    chunks) — the contract the async front-end is built on."""
     e1, eb = engines
     feats = _utterance(420, 11)
     ref = np.asarray(e1.run_utterance(jnp.asarray(feats)))
     from repro.serving.scheduler import SessionPool
-    for chunk in (0, 4):
-        pool = SessionPool(eb, capacity=2, max_frames=16, chunk_frames=chunk)
-        assert pool.admit_stream(0, 0, feats=feats[:3])
-        results, now, fed = [], 0, 3
-        while len(results) < 1:
-            if fed < 11:
-                pool.append_frames(0, feats[fed:fed + 4])
-                fed += 4
-                if fed >= 11:
-                    pool.finish_stream(0)
-            fin, adv = pool.tick(now)
-            results += fin
-            now += max(adv, 1)
-        np.testing.assert_allclose(results[0].logits, ref, atol=1e-5)
+    pool = SessionPool(eb, capacity=2, max_frames=16, chunk_frames=chunk)
+    assert pool.admit_stream(0, 0, feats=feats[:3])
+    results, now, fed = [], 0, 3
+    while len(results) < 1:
+        if fed < 11:
+            pool.append_frames(0, feats[fed:fed + 4])
+            fed += 4
+            if fed >= 11:
+                pool.finish_stream(0)
+        fin, adv = pool.tick(now)
+        results += fin
+        now += max(adv, 1)
+    np.testing.assert_allclose(results[0].logits, ref, atol=1e-5)
 
 
 def test_spmv_path_selection_parity(model):

@@ -118,7 +118,7 @@ def test_sharded_pool_n_devices_1_parity(engines, workload):
     replicated) and must be bit-comparable to the unsharded pool."""
     _, eb = engines
     feats, refs, reqs = workload
-    for chunk in (0, 4):
+    for chunk in (1, 4):
         base, _ = serve_requests(eb, reqs, capacity=4, chunk_frames=chunk)
         res, stats = serve_requests(eb, reqs, capacity=4, chunk_frames=chunk,
                                     n_devices=1)
@@ -147,7 +147,7 @@ def test_sharded_parity_grid(engines, workload):
     _, eb = engines
     feats, refs, reqs = workload
     for n_dev in (2, 4):
-        for capacity, chunk in ((4, 0), (4, 4), (8, 8), (6, 4)):
+        for capacity, chunk in ((4, 1), (4, 4), (8, 8), (6, 4)):
             res, _ = serve_requests(eb, reqs, capacity=capacity,
                                     chunk_frames=chunk, n_devices=n_dev)
             assert [r.req_id for r in res] == list(range(len(LENS)))
@@ -263,8 +263,8 @@ SUBPROCESS_SCRIPT = textwrap.dedent("""
 
     # compact grid: one sharded config plus the non-divisible replication
     # fallback — this test exists so EVERY tier-1 run exercises the
-    # multi-device path; the full grid (n_devices in {1, 2, 4}, per-frame
-    # path, 8-way, admission skew, async) runs in-process in the
+    # multi-device path; the full grid (n_devices in {1, 2, 4}, one-frame
+    # chunks, 8-way, admission skew, async) runs in-process in the
     # multi-device CI job where the flag is set for the whole suite:
     max_err = 0.0
     for n_dev, cap, chunk in ((4, 8, 4), (4, 6, 4)):
@@ -345,16 +345,22 @@ RETIRE_SCRIPT = textwrap.dedent("""
                   for a, b in zip(sharded, base))
 
     # each device steps its own 16 slots, so the banked rows are those of
-    # a 16-slot step_batch, bit for bit; the gather must not change them
+    # a 16-slot pool stepped in one-frame chunks, bit for bit; the gather
+    # must not change them
     def oracle(feats, batch=16):
-        state, rows = eb.init_state(batch), []
-        for t in range(feats.shape[0]):
-            x = np.zeros((batch, 20), np.float32)
-            x[0] = feats[t]
-            state, logits = eb.step_batch(state, x, np.arange(batch) == 0,
-                                          np.full(batch, t == 0))
-            rows.append(np.asarray(logits)[0])
-        return np.stack(rows)
+        t = feats.shape[0]
+        frames = np.zeros((batch, t, 20), np.float32)
+        frames[0] = feats
+        frames = jnp.asarray(frames)
+        lens = np.zeros(batch, np.int32)
+        lens[0] = t
+        state, bank = eb.init_state(batch), eb.init_out_buf(batch, t + 1)
+        for i in range(t):
+            state, bank = eb.step_chunk(state, frames, lens,
+                                        np.arange(batch) == 0,
+                                        np.full(batch, i == 0), bank,
+                                        n_frames=1)
+        return np.asarray(bank)[0, :t]
 
     exact = all(np.array_equal(r.logits, oracle(reqs[r.req_id].feats))
                 for r in sharded[::4])
@@ -383,9 +389,9 @@ RETIRE_SCRIPT = textwrap.dedent("""
 @pytest.mark.slow
 def test_sharded_retirement_gather_subprocess_4dev():
     """4 emulated host devices: through the retirement gather the
-    slot-sharded pool serves what it banked bit for bit (the ``step_batch``
-    oracle at its 16 slots per device) and matches the unsharded pool at
-    1e-5, compiles one retirement program for the pool, and that program
+    slot-sharded pool serves what it banked bit for bit (the one-frame
+    chunk oracle at its 16 slots per device) and matches the unsharded
+    pool at 1e-5, compiles one retirement program for the pool, and that program
     moves no copy of the bank between devices (GSPMD's masked local
     gather: at most a reduction of the gathered block)."""
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
